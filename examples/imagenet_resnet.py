@@ -18,9 +18,6 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), '..'))
 
-from scripts.utils import force_platform
-force_platform()
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -212,6 +209,8 @@ def get_data(args):
 
 def main():
     from kfac_pytorch_tpu.parallel import mesh as kmesh
+    from kfac_pytorch_tpu.utils.platform import enable_compile_cache
+    enable_compile_cache()
     kmesh.maybe_initialize_distributed()
     args = parse_args()
     from kfac_pytorch_tpu.utils.runlog import setup_run_logging
@@ -275,7 +274,8 @@ def main():
     sample = jnp.zeros((args.batch_size, args.img_size, args.img_size, 3),
                        dtype)
     state = training.init_train_state(model, tx, precond,
-                                      jax.random.PRNGKey(args.seed), sample)
+                                      jax.random.PRNGKey(args.seed), sample,
+                                      mesh=mesh, axis_name=axis)
     if use_kfac:
         scheduler = kfac.KFACParamScheduler(
             precond, damping_alpha=args.damping_alpha,

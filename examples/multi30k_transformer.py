@@ -20,9 +20,6 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), '..'))
 
-from scripts.utils import force_platform
-force_platform()
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -222,6 +219,8 @@ def synthetic_translation(n, vocab, max_len, seed=0):
 
 def main():
     from kfac_pytorch_tpu.parallel import mesh as kmesh
+    from kfac_pytorch_tpu.utils.platform import enable_compile_cache
+    enable_compile_cache()
     kmesh.maybe_initialize_distributed()
     args = parse_args()
     from kfac_pytorch_tpu.utils.runlog import setup_run_logging

@@ -19,9 +19,6 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), '..'))
 
-from scripts.utils import force_platform
-force_platform()
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -210,6 +207,8 @@ def squad_f1_em(pred_spans, gold_spans, token_seqs):
 
 def main():
     from kfac_pytorch_tpu.parallel import mesh as kmesh
+    from kfac_pytorch_tpu.utils.platform import enable_compile_cache
+    enable_compile_cache()
     kmesh.maybe_initialize_distributed()
     args = parse_args()
     from kfac_pytorch_tpu.utils.runlog import setup_run_logging

@@ -22,9 +22,6 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), '..'))
 
-from scripts.utils import force_platform
-force_platform()
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -179,6 +176,8 @@ def batchify(ids, batch_size):
 
 
 def main():
+    from kfac_pytorch_tpu.utils.platform import enable_compile_cache
+    enable_compile_cache()
     args = parse_args()
     from kfac_pytorch_tpu.utils.runlog import setup_run_logging
     log, _ = setup_run_logging(

@@ -21,7 +21,7 @@ TPU-first:
 """
 
 try:
-    from kfac_pytorch_tpu import compat as _compat
+    import jax as _jax
 except ModuleNotFoundError as _e:  # pragma: no cover - jax-less lanes
     if _e.name not in ('jax', 'jaxlib'):
         raise
@@ -30,11 +30,9 @@ except ModuleNotFoundError as _e:  # pragma: no cover - jax-less lanes
     # coord/, service/, resilience/, sim/, perfmodel — while the
     # optimizer surface stays absent and any use of it raises the
     # original, informative ModuleNotFoundError.
-    _compat = None
+    _jax = None
 
-if _compat is not None:
-    _compat.install()  # jax.shard_map on older jax (see compat.py)
-
+if _jax is not None:
     from kfac_pytorch_tpu.preconditioner import (
         KFAC, KFACHyperParams, KFACState)
     from kfac_pytorch_tpu.scheduler import KFACParamScheduler
@@ -62,7 +60,7 @@ def get_kfac_module(kfac='eigen_dp'):
     """
     if kfac not in KFAC_VARIANTS:
         raise KeyError(f"unknown kfac variant {kfac!r}; choose from {KFAC_VARIANTS}")
-    if _compat is None:
+    if _jax is None:
         raise ModuleNotFoundError(
             'jax is not installed: the K-FAC optimizer surface is '
             'unavailable (only the coordination/service/resilience/sim '
@@ -81,7 +79,7 @@ def DP_KFAC(*args, inv_type='eigen', **kwargs):
     Parity with ``kfac.DP_KFAC`` (reference: kfac/dp_kfac.py:4-39): selects the
     eigen or explicit-inverse DP variant by ``inv_type``.
     """
-    if _compat is None:
+    if _jax is None:
         raise ModuleNotFoundError(
             'jax is not installed: the K-FAC optimizer surface is '
             'unavailable (only the coordination/service/resilience/sim '

@@ -50,7 +50,6 @@ def _is_wrapper(func_node: ast.AST) -> bool:
         return False
     last = d.split('.')[-1]
     return last in _WRAPPERS and (d == last or d.startswith('jax.')
-                                  or d.startswith('compat.')
                                   or d.endswith('.' + last))
 
 

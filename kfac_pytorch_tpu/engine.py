@@ -26,6 +26,7 @@ accumulates into the factor state across inverse updates. Here damping is
 applied to a temporary — the mathematically intended semantics.
 """
 
+import contextlib
 from typing import Dict, List, Optional
 
 import jax
@@ -117,17 +118,20 @@ def compute_layer_stats(plan, acts, gs, batch_averaged=True,
     the reference by tests/test_pallas_capture.py."""
     back, kw = _capture_backend(capture_impl)
     a_list, g_list = [], []
-    for meta in plan.metas:
-        a = capture.layer_act(acts, meta)
-        g = capture.layer_g(gs, meta)
-        if meta.kind == 'dense':
-            a_list.append(back.compute_a_dense(a, meta.use_bias, **kw))
-            g_list.append(back.compute_g_dense(g, batch_averaged, **kw))
-        else:
-            a_list.append(back.compute_a_conv(
-                a, meta.kernel_size, meta.strides, meta.padding,
-                meta.use_bias, **kw))
-            g_list.append(back.compute_g_conv(g, batch_averaged, **kw))
+    with (back.routing_report() if back is not ops
+          else contextlib.nullcontext()):
+        for meta in plan.metas:
+            a = capture.layer_act(acts, meta)
+            g = capture.layer_g(gs, meta)
+            if meta.kind == 'dense':
+                a_list.append(back.compute_a_dense(a, meta.use_bias, **kw))
+                g_list.append(back.compute_g_dense(g, batch_averaged,
+                                                   **kw))
+            else:
+                a_list.append(back.compute_a_conv(
+                    a, meta.kernel_size, meta.strides, meta.padding,
+                    meta.use_bias, **kw))
+                g_list.append(back.compute_g_conv(g, batch_averaged, **kw))
     return a_list, g_list
 
 
@@ -168,8 +172,14 @@ def update_factors_fused(plan, factors_local, acts, gs, batch_averaged,
     deterministic across steps. Returns the new factors dict.
     """
     from kfac_pytorch_tpu.ops import pallas_capture as pc
-    interpret = pc.interpret_default()
-    kw = {'interpret': interpret}
+    with pc.routing_report():
+        return _update_factors_fused(pc, plan, factors_local, acts, gs,
+                                     batch_averaged, factor_decay)
+
+
+def _update_factors_fused(pc, plan, factors_local, acts, gs, batch_averaged,
+                          factor_decay):
+    kw = {'interpret': pc.interpret_default()}
     new = {}
     for bdim in plan.bucket_dims:
         key = _key(bdim)

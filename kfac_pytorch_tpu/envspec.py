@@ -100,12 +100,6 @@ ENV: Tuple[EnvVar, ...] = (
       'flash-attention backward kernel (trace-time; auto gates on '
       'block key length)', choices=('auto', 'pallas', 'recompute')),
     # -- platform / mesh ----------------------------------------------
-    E('KFAC_PLATFORM', 'str', 'scripts/utils.py',
-      'force the jax platform before first device use (cpu for the '
-      'simulated-mesh scripts)'),
-    E('KFAC_HOST_DEVICES', 'int', 'utils/platform.py',
-      'simulated per-host device count under KFAC_PLATFORM=cpu',
-      default='8'),
     E('KFAC_TPU_MULTIHOST', 'flag', 'parallel/mesh.py',
       'this process is one host of a real multi-host pod '
       '(launch_tpu.sh sets it; gates jax.distributed.initialize)'),
@@ -311,10 +305,6 @@ ENV: Tuple[EnvVar, ...] = (
     E('KFAC_AUTOTUNE_ASSERT', 'flag', 'scripts/autotune_smoke.py',
       'autotune smoke: fail (not just report) when the tuner loses to '
       'hand-tuned'),
-    E('KFAC_BENCH_PROBE_TIMEOUT', 'int', 'bench.py',
-      'per-probe subprocess timeout, seconds', default='180'),
-    E('KFAC_BENCH_PROBE_RETRIES', 'int', 'bench.py',
-      'per-probe retry budget', default='3'),
     E('KFAC_FLASH_TQ', 'int', 'scripts/bench_flash.py',
       'flash-attention bench: query tile override'),
     E('KFAC_FLASH_TK', 'int', 'scripts/bench_flash.py',
@@ -336,8 +326,9 @@ ENV: Tuple[EnvVar, ...] = (
       'total process count of a multi-process run'),
     E('JAX_COORDINATOR_ADDRESS', 'str', 'jax / parallel/mesh.py',
       'host:port of the jax.distributed coordinator'),
-    E('JAX_COMPILATION_CACHE_DIR', 'str', 'jax / bench.py',
-      'persistent XLA compilation cache directory'),
+    E('JAX_COMPILATION_CACHE_DIR', 'str', 'jax / utils/platform.py',
+      'persistent XLA compilation cache directory (unset: the '
+      'checkout\'s .jax_cache/)'),
 )
 
 DECLARED = frozenset(v.name for v in ENV)

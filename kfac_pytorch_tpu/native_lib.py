@@ -1,55 +1,81 @@
 """ctypes bindings for the native runtime library (native/kfac_native.cc).
 
-Builds lazily with cc if the shared object is missing (no pybind11 in
-this image; plain C linkage + ctypes). Every entry point has a numpy
-fallback in pure Python — the native path is an acceleration, not a
-requirement (mirrors how the reference keeps tcmm optional,
-kfac/utils.py:7).
+Built from the committed source: the shared object is keyed by a hash of
+``kfac_native.cc`` (``native/libkfac_native-<sha>.so``, git-ignored), so
+a checkout never loads a binary built from another source — edit the
+source and the next import builds anew (no pybind11 in this image; plain
+C linkage + ctypes). Every entry point has a NumPy twin in pure Python,
+used when — and only when — the machine has no C++ compiler (mirrors how
+the reference keeps tcmm optional, kfac/utils.py:7); a build or load
+that FAILS raises.
 """
 
 import ctypes
+import hashlib
 import os
+import shutil
 import subprocess
+import sys
 
 import numpy as np
 
 _DIR = os.path.join(os.path.dirname(__file__), '..', 'native')
-_LIB_PATH = os.path.join(_DIR, 'libkfac_native.so')
+_SRC = os.path.join(_DIR, 'kfac_native.cc')
 _lib = None
 _tried = False
 
 
-def _build():
-    src = os.path.join(_DIR, 'kfac_native.cc')
-    subprocess.run(['c++', '-O2', '-shared', '-fPIC', '-o', _LIB_PATH, src],
-                   check=True, capture_output=True)
+def _lib_path():
+    with open(_SRC, 'rb') as f:
+        sha = hashlib.sha1(f.read()).hexdigest()[:12]
+    return os.path.join(_DIR, f'libkfac_native-{sha}.so')
+
+
+def _build(path):
+    # build beside the target and rename: concurrent importers (xdist
+    # workers) each publish a complete file or none
+    tmp = f'{path}.{os.getpid()}.tmp'
+    try:
+        subprocess.run(['c++', '-O2', '-shared', '-fPIC', '-o', tmp, _SRC],
+                       check=True, capture_output=True)
+        os.replace(tmp, path)
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
 
 
 def get_lib():
-    """Load (building if needed) the native library, or None."""
+    """The native library, built if this source was never built here;
+    None (NumPy paths) only where no C++ compiler exists."""
     global _lib, _tried
     if _lib is not None or _tried:
         return _lib
     _tried = True
-    try:
-        if not os.path.exists(_LIB_PATH):
-            _build()
-        lib = ctypes.CDLL(_LIB_PATH)
-        lib.block_partition.restype = ctypes.c_double
-        lib.block_partition.argtypes = [
-            ctypes.POINTER(ctypes.c_double), ctypes.c_int64, ctypes.c_int64,
-            ctypes.POINTER(ctypes.c_int64)]
-        lib.lpt_assign.restype = ctypes.c_double
-        lib.lpt_assign.argtypes = lib.block_partition.argtypes
-        lib.augment_crop_flip.restype = None
-        lib.augment_crop_flip.argtypes = [
-            ctypes.POINTER(ctypes.c_float), ctypes.c_int64, ctypes.c_int64,
-            ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
-            ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_uint8),
-            ctypes.POINTER(ctypes.c_float)]
-        _lib = lib
-    except Exception:
-        _lib = None
+    path = _lib_path()
+    how = 'loaded'
+    if not os.path.exists(path):
+        if shutil.which('c++') is None:
+            print('kfac_pytorch_tpu: no C++ compiler — native/ stays '
+                  'unbuilt, NumPy paths in use', file=sys.stderr)
+            return None
+        _build(path)
+        how = 'built'
+    lib = ctypes.CDLL(path)
+    print(f'kfac_pytorch_tpu: native library {how}: '
+          f'{os.path.relpath(path)}', file=sys.stderr)
+    lib.block_partition.restype = ctypes.c_double
+    lib.block_partition.argtypes = [
+        ctypes.POINTER(ctypes.c_double), ctypes.c_int64, ctypes.c_int64,
+        ctypes.POINTER(ctypes.c_int64)]
+    lib.lpt_assign.restype = ctypes.c_double
+    lib.lpt_assign.argtypes = lib.block_partition.argtypes
+    lib.augment_crop_flip.restype = None
+    lib.augment_crop_flip.argtypes = [
+        ctypes.POINTER(ctypes.c_float), ctypes.c_int64, ctypes.c_int64,
+        ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
+        ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_uint8),
+        ctypes.POINTER(ctypes.c_float)]
+    _lib = lib
     return _lib
 
 

@@ -112,8 +112,9 @@ def sym_eig(x, impl=None, basis=None, sweeps=None):
     XLA when no basis exists yet), 'auto', or None to read
     KFAC_EIGH_IMPL from the environment (default 'xla').
 
-    'auto' resolves to 'subspace': real-chip measurements (2026-07-31,
-    logs/onchip/, NOTES.md fencing entry) show XLA QDWH eigh is
+    'auto' resolves to 'subspace' on a hypothesis ROADMAP S3 carries from
+    an earlier round's notes (not re-measured on today's v5e; what PR 21
+    did measure there is the eigh COMPILE time): XLA QDWH eigh is
     iteration-bound (seconds at K-FAC bucket dims: [4,2304] ~ 9.8 s) and
     the gather-bound matmul-form Jacobi loses to it from 512 dims up
     (~79 s/call at [4,1024]); the subspace tracker is the only
@@ -171,8 +172,8 @@ def subspace_eigh(x, basis, steps=None, tau=0.01, clip=0.5):
     Everything is batched matmuls plus one [n, n] Cholesky per step —
     the MXU-shaped replacement for QDWH/Jacobi in the warm path
     (KFAC_EIGH_IMPL=subspace|auto + warm_start_basis / basis_update_freq):
-    real-chip QDWH at K-FAC bucket dims costs seconds
-    (logs/onchip/manual_seq.log) while this costs ~6 matmuls.
+    QDWH at K-FAC bucket dims is expected to cost seconds (ROADMAP S3)
+    while this costs ~6 matmuls.
 
     Returns unsorted ``(eigvals, eigvecs)`` like :func:`jacobi_eigh`.
     """

@@ -73,8 +73,13 @@ def _fwd_kernel(meta_ref, q_ref, k_ref, v_ref, mask_ref,
         q = q_ref[0]
         qpos = (q_start + iq * tq
                 + lax.broadcasted_iota(jnp.int32, (tq, 1), 0))
+        # sub-f32 operands take the MXU's native pass whatever
+        # jax_default_matmul_precision says: Mosaic refuses an fp32
+        # contraction of bf16 operands ("Bad lhs type")
         s = jnp.dot(q, k_ref[0].T,
-                    preferred_element_type=jnp.float32) * scale
+                    preferred_element_type=jnp.float32,
+                    precision=(None if q.dtype == jnp.float32
+                               else lax.Precision.DEFAULT)) * scale
         kpos = (k_start + j * tk
                 + lax.broadcasted_iota(jnp.int32, (1, tk), 1))
         # additive bias, NOT replacement: masked entries must keep their
@@ -221,7 +226,7 @@ def _pallas_fwd(q, k, v, kv_mask, starts, scale, causal, interpret):
     # under shard_map the outputs vary over every axis the inputs do
     vma = frozenset()
     for x in (q, k, v):
-        vma = vma | getattr(jax.typeof(x), 'vma', frozenset())
+        vma = vma | jax.typeof(x).vma
     out_shape = [
         jax.ShapeDtypeStruct((BH, Lq, 1), jnp.float32, vma=vma),
         jax.ShapeDtypeStruct((BH, Lq, 1), jnp.float32, vma=vma),
@@ -230,8 +235,7 @@ def _pallas_fwd(q, k, v, kv_mask, starts, scale, causal, interpret):
     params = {}
     if not interpret:
         # the j grid dim carries the scratch recurrence → must stay serial
-        cp = getattr(pltpu, 'CompilerParams', None) or pltpu.TPUCompilerParams
-        params['compiler_params'] = cp(
+        params['compiler_params'] = pltpu.CompilerParams(
             dimension_semantics=('parallel', 'parallel', 'arbitrary'))
     m, l, pv = pl.pallas_call(kernel, grid_spec=grid_spec,
                               out_shape=out_shape, interpret=interpret,
@@ -352,11 +356,10 @@ def _pallas_bwd(q, k, v, kv_mask, m, dl, dpv, starts, scale, causal,
     dl3 = dl[..., None]
     vma = frozenset()
     for x in (q, k, v, dl, dpv):
-        vma = vma | getattr(jax.typeof(x), 'vma', frozenset())
+        vma = vma | jax.typeof(x).vma
     params = {}
     if not interpret:
-        cp = getattr(pltpu, 'CompilerParams', None) or pltpu.TPUCompilerParams
-        params['compiler_params'] = cp(
+        params['compiler_params'] = pltpu.CompilerParams(
             dimension_semantics=('parallel', 'parallel', 'arbitrary'))
 
     if causal and not interpret:
@@ -536,12 +539,12 @@ def _flash_fwd(q, k, v, kv_mask, starts, scale, causal, interpret):
     return (m, l, pv), (q, k, v, kv_mask, starts, m)
 
 
-#: 'auto' backward crossover: measured on a real v5e chip (2026-07-31,
-#: B=1 H=8 D=64 causal, logs/onchip/queue_0731_0346.flash_bwd_ab.log) the
-#: blockwise recompute wins below this key length (8k: 45 ms vs 62 ms
-#: fused) and the fused Pallas backward wins 15x above it (32k: 0.66 s vs
-#: 9.9 s — the recompute's full-array dk/dv tile updates are O(Lk^2) HBM
-#: traffic). Lk is a static shape, so the choice is made at trace time.
+#: 'auto' backward crossover — a HYPOTHESIS carried in ROADMAP S7 from an
+#: earlier round's notes (B=1 H=8 D=64 causal; not re-measured on
+#: today's v5e): the blockwise recompute wins below this key length (8k:
+#: 45 ms vs 62 ms fused) and the fused Pallas backward wins 15x above it
+#: (32k: 0.66 s vs 9.9 s — the recompute's full-array dk/dv tile updates
+#: are O(Lk^2) HBM traffic). Lk is a static shape, so the choice is made at trace time.
 AUTO_BWD_PALLAS_MIN_LK = 32768
 
 

@@ -47,6 +47,8 @@ Implementation selection follows the repo convention ('xla' | 'pallas' |
 traced program runs under the interpreter in the CPU test tier.
 """
 
+import collections
+import contextlib
 import functools
 import os
 
@@ -58,17 +60,29 @@ from jax.experimental.pallas import tpu as pltpu
 
 from kfac_pytorch_tpu.ops import factors as _ref
 
-#: fp32 elements a row tile may occupy (~1 MiB) — one tile plus the
-#: [F, F] accumulator must double-buffer inside the ~16 MiB VMEM.
+#: fp32 elements a row tile may occupy (~1 MiB unpadded).
 _TILE_ELEMS = 1 << 18
 
 #: largest fused factor dimension: the kernels keep the full [F, F]
 #: fp32 accumulator in VMEM scratch (F=1024 -> 4 MiB); a wider factor
-#: falls back to the XLA reference per layer. KFAC_CAPTURE_MAX_F
+#: is routed to the XLA reference (see routing_report). KFAC_CAPTURE_MAX_F
 #: overrides (an on-chip sweep knob, like KFAC_FLASH_TQ/TK).
 _MAX_FUSED_F = 1024
 
+#: VMEM one kernel may plan for, handed to Mosaic as the scoped limit
+#: (v5e's default scope is 16 MiB of its 128 MiB): the F=1024 kernels
+#: hold the [F, F] fp32 accumulator, the double-buffered output and EMA
+#: operand (20 MiB) beside the row tile.
+_VMEM_LIMIT = 64 << 20
+
 _WARNED = set()
+
+#: per-reason routing counts of the capture pass being traced (engine
+#: opens one :func:`routing_report` around its per-layer loop)
+_TALLY = None
+
+_ROUTE_REASONS = {'cap': 'factor dim over the fused cap',
+                  'vmem': 'image tile over the VMEM limit'}
 
 
 def _warn_once(key, msg):
@@ -79,6 +93,60 @@ def _warn_once(key, msg):
         # value flows through it
         print(f'kfac_pytorch_tpu: {msg}',  # kfac-lint: disable=trace-purity
               file=sys.stderr)
+
+
+def _route_fused():
+    if _TALLY is not None:
+        _TALLY['fused'] += 1
+
+
+def _route_xla(key, reason, msg):
+    """A statistic that asked for the fused kernel and cannot have it:
+    counted for the pass's one report, or — called outside a pass —
+    warned about once per shape. Never silent."""
+    if _TALLY is not None:
+        _TALLY[reason] += 1
+    else:
+        _warn_once(key, msg + ' — this statistic stays on the XLA path')
+
+
+@contextlib.contextmanager
+def routing_report():
+    """Count the fused / XLA-routed statistics of one capture pass and
+    report them in ONE stderr line per run (per distinct outcome — every
+    compiled step variant re-traces the same pass)."""
+    # trace-time bookkeeping of STATIC routing decisions (shapes only):
+    # no traced value flows in or out, nothing bakes into the program
+    # kfac-lint: disable=trace-purity -- host-side routing tally
+    global _TALLY
+    outer, _TALLY = _TALLY, collections.Counter()
+    try:
+        yield
+    finally:
+        tally, _TALLY = _TALLY, outer
+        fused = tally.pop('fused', 0)
+        if tally:
+            why = ', '.join(f'{n} x {_ROUTE_REASONS[r]}'
+                            for r, n in sorted(tally.items()))
+            msg = (f'capture_impl=pallas: {fused} factor statistics fused, '
+                   f'{sum(tally.values())} on the XLA path ({why})')
+            _warn_once(msg, msg)
+
+
+def _vmem_bytes(blocks):
+    """VMEM bytes of ``(shape, dtype, copies)`` blocks as Mosaic lays
+    them out: the minor dim padded to 128 lanes, the second-minor to the
+    dtype's sublane tile (8 rows of 32 bits)."""
+    total = 0
+    for shape, dtype, copies in blocks:
+        item = jnp.dtype(dtype).itemsize
+        sub = 8 * max(1, 4 // item)
+        *major, rows, lanes = shape
+        n = -(-rows // sub) * sub * -(-lanes // 128) * 128 * item
+        for d in major:
+            n *= d
+        total += n * copies
+    return total
 
 
 def interpret_default():
@@ -136,28 +204,24 @@ def _vma(*arrays):
     pallas_attention.py idiom)."""
     vma = frozenset()
     for x in arrays:
-        vma = vma | getattr(jax.typeof(x), 'vma', frozenset())
+        vma = vma | jax.typeof(x).vma
     return vma
-
-
-try:  # vma landed with the varying-axis shard_map type system; older
-    jax.ShapeDtypeStruct((1,), jnp.float32, vma=frozenset())
-    _HAS_VMA = True
-except TypeError:  # jax (the CPU test container) has no kwarg — and no
-    _HAS_VMA = False  # vma-typed avals to propagate either
-
-
-def _sds(shape, dtype, vma):
-    if _HAS_VMA and vma:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
 
 
 def _params(interpret, semantics):
     if interpret:
         return {}
-    cp = getattr(pltpu, 'CompilerParams', None) or pltpu.TPUCompilerParams
-    return {'compiler_params': cp(dimension_semantics=semantics)}
+    return {'compiler_params': pltpu.CompilerParams(
+        dimension_semantics=semantics, vmem_limit_bytes=_VMEM_LIMIT)}
+
+
+def _precision(dtype):
+    """Sub-f32 operands take the MXU's native pass whatever
+    ``jax_default_matmul_precision`` says — Mosaic refuses an fp32
+    contraction of bf16 operands ("Bad lhs type"), and bf16 x bf16
+    products are exact in the f32 accumulator anyway. f32 operands keep
+    the ambient precision, like the ops/factors.py reference."""
+    return None if dtype == jnp.float32 else lax.Precision.DEFAULT
 
 
 def _pin(v, strict):
@@ -227,6 +291,7 @@ def _stat_kernel(*refs, denom, mults, append_ones, nsteps, ema_alpha,
     acc_ref[...] += lax.dot_general(
         t, _pin(_div(t, denom, strict), strict),
         dimension_numbers=(((0,), (0,)), ((), ())),
+        precision=_precision(t.dtype),
         preferred_element_type=jnp.float32).astype(jnp.float32)
 
     @pl.when(i == nsteps - 1)
@@ -276,7 +341,8 @@ def _stat_rows(rows, denom, *, mults=(), append_ones=False, ema=None,
             out_specs=pl.BlockSpec((f, f), lambda i: (0, 0)),
             scratch_shapes=[pltpu.VMEM((f, f), jnp.float32)],
         ),
-        out_shape=_sds((f, f), jnp.float32, _vma(*vma_args)),
+        out_shape=jax.ShapeDtypeStruct((f, f), jnp.float32,
+                                       vma=_vma(*vma_args)),
         interpret=interpret,
         # the row-tile grid carries the accumulator recurrence in
         # scratch -> must stay serial
@@ -311,8 +377,8 @@ def _canon_padding(h, w, kernel_size, strides, padding):
     return tuple(tuple(p) for p in padding)
 
 
-def _conv_a_kernel(*refs, kh, kw, sh, sw, oh, ow, n, spatial,
-                   append_ones, nsteps, ema_alpha, has_ema, strict):
+def _conv_a_kernel(*refs, taps, oh, ow, n, spatial, append_ones, nsteps,
+                   ema_alpha, has_ema, strict):
     if has_ema:
         x_ref, cur_ref, o_ref, acc_ref = refs
     else:
@@ -323,20 +389,17 @@ def _conv_a_kernel(*refs, kh, kw, sh, sw, oh, ow, n, spatial,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...]                       # [tn, Hp, Wp, C] (zero-padded)
-    tn, _, _, c = x.shape
-    # im2col built in VMEM: one strided slice per (ki, kj) tap,
-    # concatenated feature-last -> (kh, kw, c) feature order, matching
-    # HWIO kernel flattening (factors.extract_patches)
-    cols = []
-    for ki in range(kh):
-        for kj in range(kw):
-            cols.append(lax.slice(
-                x, (0, ki, kj, 0),
-                (tn, ki + (oh - 1) * sh + 1, kj + (ow - 1) * sw + 1, c),
-                (1, sh, sw, 1)))         # [tn, oh, ow, c]
+    tn, _, _, _, c = x_ref.shape    # [tn, phases, Hq, Wq, C] (zero-padded)
+    # im2col built in VMEM: one unit-stride window per (ki, kj) tap out
+    # of the tap's stride phase (compute_a_conv de-interleaved the
+    # strides away — Mosaic lowers neither a strided slice of a loaded
+    # value nor a strided ref load of bf16 / C != 128), concatenated
+    # feature-last -> (kh, kw, c) feature order, matching HWIO kernel
+    # flattening (factors.extract_patches)
+    cols = [x_ref[:, ph, pl.ds(r0, oh), pl.ds(c0, ow), :]
+            for ph, r0, c0 in taps]      # each [tn, oh, ow, c]
     rows = jnp.concatenate(cols, axis=-1).reshape(tn * oh * ow,
-                                                  kh * kw * c)
+                                                  len(taps) * c)
     if append_ones:
         rows = jnp.concatenate(
             [rows, jnp.ones(rows.shape[:-1] + (1,), rows.dtype)], axis=-1)
@@ -344,6 +407,7 @@ def _conv_a_kernel(*refs, kh, kw, sh, sw, oh, ow, n, spatial,
     acc_ref[...] += lax.dot_general(
         rows, _pin(_div(rows, n, strict), strict),
         dimension_numbers=(((0,), (0,)), ((), ())),
+        precision=_precision(rows.dtype),
         preferred_element_type=jnp.float32).astype(jnp.float32)
 
     @pl.when(i == nsteps - 1)
@@ -370,10 +434,10 @@ def compute_a_dense(a, use_bias, *, ema=None, interpret=False):
     n = a.shape[0]
     f = a.shape[1] + (1 if use_bias else 0)
     if f > _max_fused_f():
-        _warn_once(f'a_dense:{f}',
-                   f'capture: dense A factor dim {f} exceeds the fused '
-                   'VMEM cap — this layer stays on the XLA path')
+        _route_xla(f'a_dense:{f}', 'cap',
+                   f'capture: dense A factor dim {f} exceeds the fused cap')
         return _apply_ema(_ref.compute_a_dense(a, use_bias), ema)
+    _route_fused()
     return _stat_rows(a, n, append_ones=use_bias, ema=ema,
                       interpret=interpret)
 
@@ -385,10 +449,11 @@ def compute_g_dense(g, batch_averaged=True, *, ema=None, interpret=False):
         g = g.mean(axis=tuple(range(1, g.ndim - 1)))
     n = g.shape[0]
     if g.shape[1] > _max_fused_f():
-        _warn_once(f'g_dense:{g.shape[1]}',
+        _route_xla(f'g_dense:{g.shape[1]}', 'cap',
                    f'capture: dense G factor dim {g.shape[1]} exceeds '
-                   'the fused VMEM cap — this layer stays on the XLA path')
+                   'the fused cap')
         return _apply_ema(_ref.compute_g_dense(g, batch_averaged), ema)
+    _route_fused()
     return _stat_rows(g, n, mults=((n,) if batch_averaged else ()),
                       ema=ema, interpret=interpret)
 
@@ -400,10 +465,11 @@ def compute_g_conv(g, batch_averaged=True, *, ema=None, interpret=False):
     spatial = g.shape[1] * g.shape[2]
     rows = g.reshape(-1, g.shape[-1])
     if rows.shape[1] > _max_fused_f():
-        _warn_once(f'g_conv:{rows.shape[1]}',
+        _route_xla(f'g_conv:{rows.shape[1]}', 'cap',
                    f'capture: conv G factor dim {rows.shape[1]} exceeds '
-                   'the fused VMEM cap — this layer stays on the XLA path')
+                   'the fused cap')
         return _apply_ema(_ref.compute_g_conv(g, batch_averaged), ema)
+    _route_fused()
     mults = (n, spatial) if batch_averaged else (spatial,)
     return _stat_rows(rows, rows.shape[0], mults=mults, ema=ema,
                       interpret=interpret)
@@ -422,36 +488,60 @@ def compute_a_conv(a, kernel_size, strides, padding, use_bias, *,
     sh, sw = strides
     f = kh * kw * c + (1 if use_bias else 0)
     if f > _max_fused_f():
-        _warn_once(f'a_conv:{f}',
-                   f'capture: conv A factor dim {f} exceeds the fused '
-                   'VMEM cap — this layer stays on the XLA path')
+        _route_xla(f'a_conv:{f}', 'cap',
+                   f'capture: conv A factor dim {f} exceeds the fused cap')
         return _apply_ema(
             _ref.compute_a_conv(a, kernel_size, strides, padding,
                                 use_bias), ema)
     (pt, pb), (pl_, pr) = _canon_padding(h, w, kernel_size, strides,
                                          padding)
-    # zero-pad once host-side (cheap; identical values to the reference's
-    # conv_general_dilated_patches padding) so the kernel taps are plain
-    # strided slices
-    xpad = jnp.pad(a, ((0, 0), (pt, pb), (pl_, pr), (0, 0)))
     hp, wp = h + pt + pb, w + pl_ + pr
     oh = (hp - kh) // sh + 1
     ow = (wp - kw) // sw + 1
     spatial = oh * ow
+    # stride phases the taps touch, in first-use order: tap (ki, kj)
+    # reads rows ki + sh*i = phase ki % sh at offset ki // sh — a
+    # unit-stride window once the phases are de-interleaved
+    phases = list(dict.fromkeys(
+        (ki % sh, kj % sw) for ki in range(kh) for kj in range(kw)))
+    taps = tuple((phases.index((ki % sh, kj % sw)), ki // sh, kj // sw)
+                 for ki in range(kh) for kj in range(kw))
+    hq, wq = -(-hp // sh), -(-wp // sw)
     has_ema = _ema_static(ema)
+    blocks = [((1, len(phases), hq, wq, c), a.dtype, 2),    # input tile
+              ((len(taps), oh, ow, c), a.dtype, 1),         # live taps
+              ((spatial, f), a.dtype, 2),                   # patch rows
+              ((f, f), jnp.float32, 5 if has_ema else 3)]   # acc/out/ema
+    if _vmem_bytes(blocks) > _VMEM_LIMIT:
+        _route_xla(f'a_conv:{h}x{w}x{c}:{kh}x{kw}', 'vmem',
+                   f'capture: conv A {kh}x{kw}/{sh} on [{h},{w},{c}] needs '
+                   f'{_vmem_bytes(blocks) >> 20} MiB of VMEM per image')
+        return _apply_ema(
+            _ref.compute_a_conv(a, kernel_size, strides, padding,
+                                use_bias), ema)
+    _route_fused()
+    # zero-pad (identical values to the reference's
+    # conv_general_dilated_patches padding) up to whole strides, then
+    # de-interleave the stride phases — both plain XLA data movement
+    # over the INPUT, never the kh*kw-times-larger patch matrix
+    xpad = jnp.pad(a, ((0, 0), (pt, pb + hq * sh - hp),
+                       (pl_, pr + wq * sw - wp), (0, 0)))
+    xpad = xpad.reshape(n, hq, sh, wq, sw, c)
+    xph = jnp.stack([xpad[:, :, p, :, q, :] for p, q in phases], axis=1)
     two_pass_ema = ema if (ema is not None and not has_ema) else None
     # per-image VMEM footprint: the padded input tile + the in-flight
     # patch rows
-    tn = _row_tile(n, hp * wp * c + spatial * f)
+    tn = _row_tile(n, len(phases) * hq * wq * c + spatial * f)
     nsteps = n // tn
     kernel = functools.partial(
-        _conv_a_kernel, kh=kh, kw=kw, sh=sh, sw=sw, oh=oh, ow=ow, n=n,
+        _conv_a_kernel, taps=taps, oh=oh, ow=ow, n=n,
         spatial=spatial, append_ones=use_bias, nsteps=nsteps,
         ema_alpha=(float(ema[1]) if has_ema else 0.0), has_ema=has_ema,
         strict=interpret)
-    in_specs = [pl.BlockSpec((tn, hp, wp, c), lambda i: (i, 0, 0, 0))]
-    operands = [xpad]
-    vma_args = [xpad]
+    in_specs = [pl.BlockSpec((tn, len(phases), hq, wq, c),
+                             lambda i: (i, 0, 0, 0, 0))]
+    operands = [xph]
+    vma_args = [xph]
     if has_ema:
         in_specs.append(pl.BlockSpec((f, f), lambda i: (0, 0)))
         operands.append(ema[0])
@@ -465,7 +555,8 @@ def compute_a_conv(a, kernel_size, strides, padding, use_bias, *,
             out_specs=pl.BlockSpec((f, f), lambda i: (0, 0)),
             scratch_shapes=[pltpu.VMEM((f, f), jnp.float32)],
         ),
-        out_shape=_sds((f, f), jnp.float32, _vma(*vma_args)),
+        out_shape=jax.ShapeDtypeStruct((f, f), jnp.float32,
+                                       vma=_vma(*vma_args)),
         interpret=interpret,
         **_params(interpret, ('arbitrary',)))(*operands)
     return _apply_ema(out, two_pass_ema)
@@ -510,8 +601,8 @@ def ef_quantize(x, residual, *, interpret=False):
             out_specs=[pl.BlockSpec(blk, idx), pl.BlockSpec(blk, idx)],
         ),
         out_shape=[
-            _sds(x.shape, jnp.bfloat16, vma),
-            _sds(x.shape, x.dtype, vma),
+            jax.ShapeDtypeStruct(x.shape, jnp.bfloat16, vma=vma),
+            jax.ShapeDtypeStruct(x.shape, x.dtype, vma=vma),
         ],
         interpret=interpret,
         **_params(interpret, ('parallel',)))(x, residual)

@@ -208,8 +208,16 @@ def pmean_scatter_ef(x, axis_name, comm_precision, residual, fused=False):
             x, residual, interpret=_pc.interpret_default())
     else:
         xc = x + residual
-        wire = xc.astype(jnp.bfloat16)
-        new_residual = xc - wire.astype(x.dtype)
+        # the bf16 rounding is taken with reduce_precision, which XLA
+        # never elides. Written as xc - f32(bf16(xc)), the TPU
+        # compiler's excess-precision pass folds the f32->bf16->f32
+        # round trip away and the residual comes out as exactly 0 (seen
+        # on a v5e, PERF.md PR 21): an error feedback feeding back
+        # nothing. Same round-to-nearest-even bits as the cast.
+        rounded = lax.reduce_precision(xc, exponent_bits=8,
+                                       mantissa_bits=7)
+        wire = rounded.astype(jnp.bfloat16)
+        new_residual = xc - rounded
     red = lax.psum_scatter(wire, axis_name, scatter_dimension=0,
                            tiled=True).astype(x.dtype)
     return red / n, new_residual

@@ -32,11 +32,11 @@ from jax import lax
 _NEG_INF = -1e30
 
 
-#: 'auto' forward crossover: measured on a real v5e chip (2026-07-31,
-#: B=1 H=8 D=64 causal fwd+bwd, logs/onchip/queue_0731_0346.summary) the
-#: XLA blockwise path wins below this key length (8k: 43.5 ms vs 59.4;
-#: 16k: 103.6 vs 180.9) while at 32k the Pallas kernel is the only path
-#: that compiles at all (XLA: remote-compile failure; Pallas: 657 ms).
+#: 'auto' forward crossover — a HYPOTHESIS carried in ROADMAP S7 from an
+#: earlier round's notes (B=1 H=8 D=64 causal fwd+bwd; not re-measured
+#: on today's v5e): the XLA blockwise path wins below this key length
+#: (8k: 43.5 ms vs 59.4; 16k: 103.6 vs 180.9) while at 32k the [L, L]
+#: scores no longer fit and the Pallas kernel is the only path (657 ms).
 #: Lk is a static shape, so the choice is made at trace time — the same
 #: policy shape as ops.pallas_attention.AUTO_BWD_PALLAS_MIN_LK.
 AUTO_FWD_PALLAS_MIN_LK = 32768

@@ -653,7 +653,8 @@ def _saved_comm_err_zeros(path):
     if not _HAS_ORBAX or not os.path.isdir(path):
         return None
     try:
-        meta = ocp.StandardCheckpointer().metadata(path)
+        # orbax 0.11: StepMetadata whose item_metadata is the saved tree
+        meta = ocp.StandardCheckpointer().metadata(path).item_metadata
         err = (meta.get('kfac_state') or {}).get('comm_err')
         if not isinstance(err, dict) or not err:
             return None

@@ -40,7 +40,7 @@ if [ -n "$pod" ]; then
     exit 1
   fi
 fi
-export JAX_COMPILATION_CACHE_DIR XLA_PYTHON_CLIENT_PREALLOCATE
+export XLA_PYTHON_CLIENT_PREALLOCATE
 
 # Observability: KFAC_TRACE_DIR=<shared dir> turns on structured trace
 # spans in every process of the run (trainers AND supervisors each

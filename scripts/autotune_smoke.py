@@ -32,7 +32,7 @@ Five legs, each writing its decision log as a JSONL artifact:
    (default 1.10x) of the hand-tuned best.
 
 Usage:
-  KFAC_PLATFORM=cpu KFAC_AUTOTUNE_ASSERT=1 AUTOTUNE_SMOKE_MEASURED=1 \
+  JAX_PLATFORMS=cpu KFAC_AUTOTUNE_ASSERT=1 AUTOTUNE_SMOKE_MEASURED=1 \
       python scripts/autotune_smoke.py
 
 Env knobs:

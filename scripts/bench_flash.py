@@ -16,8 +16,7 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), '..'))
 
-from scripts.utils import force_platform, timeit
-force_platform()
+from scripts.utils import timeit
 
 import jax
 import jax.numpy as jnp

@@ -7,7 +7,8 @@ sharded over the available mesh, plus the dense replicated baseline while
 it still fits.
 
 Usage:
-  KFAC_PLATFORM=cpu KFAC_HOST_DEVICES=8 python scripts/bench_ring.py \
+  JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+      python scripts/bench_ring.py \
       [--seq-lens 4096 16384] [--heads 8] [--d-head 64] [--impl ring ulysses]
 """
 
@@ -18,8 +19,7 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), '..'))
 
-from scripts.utils import force_platform, timeit
-force_platform()
+from scripts.utils import timeit
 
 import jax
 import jax.numpy as jnp

@@ -12,7 +12,9 @@ collective-permute ops XLA actually emitted. MPD variants ('eigen',
 ('eigen_dp', 'inverse_dp') must show NONE beyond the gradient allreduce
 + preconditioned-output gather; SGD is the gradient-allreduce floor.
 
-Usage: KFAC_PLATFORM=cpu KFAC_HOST_DEVICES=8 python scripts/comm_count.py
+Usage: JAX_PLATFORMS=cpu \
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+    python scripts/comm_count.py
 
 Env knobs:
   COMM_COUNT_VARIANTS   space-separated variant specs; a ':bf16'/':int8'
@@ -39,9 +41,6 @@ import re
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), '..'))
-from scripts.utils import force_platform
-
-force_platform()
 
 import jax
 import jax.numpy as jnp
@@ -272,8 +271,8 @@ def collective_ledger(variant, ndev=8, model_name='resnet20', model=None,
         raise SystemExit(
             f'need a >=2-device mesh (have {len(jax.devices())}, asked '
             f'{ndev}): on one device XLA elides every collective and the '
-            'ledger would read all-zero. Run with KFAC_PLATFORM=cpu '
-            'KFAC_HOST_DEVICES=8.')
+            'ledger would read all-zero. Run with JAX_PLATFORMS=cpu '
+            'XLA_FLAGS=--xla_force_host_platform_device_count=8.')
     mesh = Mesh(np.array(jax.devices()[:ndev]), ('batch',))
     rng = np.random.RandomState(0)
     batch = {'input': jnp.asarray(rng.randn(2 * ndev, hw, hw, 3),
@@ -423,8 +422,8 @@ def composed_ledger(base_variant, mesh_spec, comm_precision='fp32',
     if len(jax.devices()) < need:
         raise SystemExit(
             f'mesh {mesh_spec!r} needs {need} devices (have '
-            f'{len(jax.devices())}) — run with KFAC_PLATFORM=cpu '
-            f'KFAC_HOST_DEVICES={need}')
+            f'{len(jax.devices())}) — run with JAX_PLATFORMS=cpu '
+            f'XLA_FLAGS=--xla_force_host_platform_device_count={need}')
     mesh, _ = meshlib.make_composed_mesh(mesh_spec)
     names = tuple(a.name for a in axes)
     shape = axes_mod.mesh_shape(axes)
@@ -643,7 +642,7 @@ def check_floor(ledgers):
 
 
 def main():
-    ndev = int(os.environ.get('KFAC_HOST_DEVICES', '8'))
+    ndev = min(len(jax.devices()), 8)
     model_name = os.environ.get('COMM_COUNT_MODEL', 'resnet20')
     print(f'model={model_name} ndev={ndev} (counts from the compiled '
           'SPMD module)')

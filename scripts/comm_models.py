@@ -31,8 +31,7 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), '..'))
 
-from scripts.utils import fit_linear, force_platform, timeit
-force_platform()
+from scripts.utils import fit_linear, timeit
 
 import jax
 import jax.numpy as jnp

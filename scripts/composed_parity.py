@@ -25,7 +25,8 @@ them; CI documents the pending surface the same way the comm-ledger job
 documents bytes it cannot measure).
 
 Usage:
-  KFAC_PLATFORM=cpu KFAC_HOST_DEVICES=8 COMPOSED_PARITY_ASSERT=1 \
+  JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+  COMPOSED_PARITY_ASSERT=1 \
       python scripts/composed_parity.py [--leg dp2xtp2 --leg dp2xep2]
 
 Env knobs:
@@ -42,10 +43,6 @@ import os
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), '..'))
-
-from utils import force_platform  # noqa: E402  (scripts/utils.py)
-
-force_platform()
 
 import jax                                    # noqa: E402
 import jax.numpy as jnp                       # noqa: E402

@@ -17,7 +17,7 @@ the perf model and bench.py's `predicted` block read it — regenerate
 only when the engine's per-step math changes).
 
 Usage:
-  KFAC_PLATFORM=cpu python scripts/derive_perf_inputs.py          # official
+  JAX_PLATFORMS=cpu python scripts/derive_perf_inputs.py          # official
   DERIVE_MODEL=resnet20 DERIVE_IMG=32 DERIVE_BATCH=8 ... --out X  # smoke
 """
 
@@ -28,9 +28,6 @@ import sys
 import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), '..'))
-
-from scripts.utils import force_platform
-force_platform()
 
 import jax
 import jax.numpy as jnp

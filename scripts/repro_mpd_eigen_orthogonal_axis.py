@@ -25,8 +25,10 @@ print('check_vma=False probes) — divergent numbers in this output are the')
 print('EXPECTED broken-harness signature, NOT an engine bug. The correct-')
 print('convention invariance passes in tests/test_moe.py.')
 print('=' * 72)
-from kfac_pytorch_tpu.utils.platform import force_host_platform
-force_host_platform("cpu", 8)
+import os
+os.environ.setdefault('JAX_PLATFORMS', 'cpu')
+os.environ.setdefault(
+    'XLA_FLAGS', '--xla_force_host_platform_device_count=8')
 print('importing test_moe', flush=True)
 import test_moe as m
 print('imported', flush=True)

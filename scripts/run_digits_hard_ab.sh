@@ -37,7 +37,8 @@ leg() {  # leg <name> <env...> -- <extra trainer args...>
   while [ "$1" != "--" ]; do envs+=("$1"); shift; done
   shift
   echo "=== leg $name seed=$SEED $(date +%H:%M:%S)"
-  env "${common[@]}" "${envs[@]}" KFAC_PLATFORM=cpu KFAC_HOST_DEVICES=4 \
+  env "${common[@]}" "${envs[@]}" JAX_PLATFORMS=cpu \
+      XLA_FLAGS=--xla_force_host_platform_device_count=4 \
       bash train_cifar10.sh --tb-dir "$TB/$name" --seed "$SEED" "$@" \
     || echo "=== leg $name FAILED rc=$?"
 }

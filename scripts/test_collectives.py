@@ -24,9 +24,6 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), '..'))
 
-from scripts.utils import force_platform
-force_platform()
-
 import jax
 import jax.numpy as jnp
 import numpy as np

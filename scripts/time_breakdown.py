@@ -25,8 +25,7 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), '..'))
 
-from scripts.utils import build_vision_model, force_platform
-force_platform()
+from scripts.utils import build_vision_model
 
 import jax
 import jax.numpy as jnp

@@ -1,46 +1,9 @@
 """Shared test helpers."""
 
-import functools
 import socket
 import subprocess
 
 from kfac_pytorch_tpu.models.tiny import TinyCNN  # noqa: F401 (re-export)
-
-
-@functools.lru_cache(maxsize=1)
-def shard_map_body_autodiff_broken():
-    """True when this backend mis-transposes autodiff taken INSIDE a
-    shard_map body: under the compat shim's legacy shard_map
-    (``check_rep=False``, no vma tracking) a replicated operand's
-    cotangent never receives its cross-axis psum, so in-body grads of
-    replicated inputs come back rank-local (and forward psums double
-    replicated cotangents instead).
-
-    Probed once per session with a 2-device reduction: the grad of
-    ``psum((w * x).sum())`` w.r.t. replicated ``w`` must be the GLOBAL
-    x-sum. K-FAC's own step path never differentiates inside shard_map
-    (capture feeds explicit operands and its collectives are forward-
-    only), so only in-body-autodiff ORACLE tests key off this probe.
-    """
-    import kfac_pytorch_tpu  # noqa: F401 — installs the jax.shard_map shim
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from jax.sharding import Mesh, PartitionSpec as P
-
-    if len(jax.devices()) < 2:
-        return True
-    mesh = Mesh(np.array(jax.devices()[:2]), ('probe',))
-
-    @functools.partial(jax.shard_map, mesh=mesh,
-                       in_specs=(P(), P('probe')), out_specs=P())
-    def g(w, x):
-        return jax.grad(
-            lambda w: jax.lax.psum((w * x).sum(), 'probe'))(w)
-
-    x = jnp.arange(6, dtype=jnp.float32).reshape(2, 3)
-    got = np.asarray(g(jnp.ones((3,), jnp.float32), x))
-    return not np.allclose(got, np.asarray(x.sum(0)))
 
 
 def free_port():

@@ -501,7 +501,7 @@ def test_drift_veto_on_modeled_chip():
 def test_drift_gate_advisory_off_the_modeled_chip():
     """The SAME feed on an unmodeled platform commits: the band is
     advisory (violations counted, knob applied)."""
-    pre, ctl = _veto_harness('cpu_fallback')
+    pre, ctl = _veto_harness('cpu')
     assert ctl.vetoes == 0 and ctl.commits == 1
     assert ctl.advisory_violations >= 1
     assert pre.kfac_update_freq != 4          # the probe value stuck

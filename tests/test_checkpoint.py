@@ -77,7 +77,8 @@ def test_preemption_guard_saves_and_exits(tmp_path):
     import sys
     import time as _time
 
-    env = dict(os.environ, KFAC_PLATFORM='cpu', KFAC_HOST_DEVICES='1')
+    env = dict(os.environ, JAX_PLATFORMS='cpu',
+               XLA_FLAGS='--xla_force_host_platform_device_count=1')
     logf = tmp_path / 'out.log'
     with open(logf, 'w') as f:
         proc = subprocess.Popen(

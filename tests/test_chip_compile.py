@@ -1,0 +1,133 @@
+"""Does the chip's compiler take the kernels of the main path?
+
+The TPU compiler is installed beside the CPU backend and compiles for a
+chip that is DESCRIBED, not attached: each case lowers one Pallas kernel
+at a ResNet-50 / BERT-base / long-context shape for one device of a
+``v5e:2x2`` topology and asserts the kernel is in the executable
+(``tpu_custom_call``). Interpret-mode tests cannot see what this sees —
+a strided slice Mosaic refuses, a tile over VMEM. Nothing runs; a
+compile that passes is not a chip run (``chip_smoke.py`` is).
+
+The only file in the repo that describes a topology, and only inside a
+fixture: libtpu is loaded by the one xdist worker that runs this file,
+after collection.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from kfac_pytorch_tpu.ops import pallas_attention, pallas_capture as pc
+
+
+@pytest.fixture(scope='module')
+def one_chip():
+    os.environ.setdefault('TPU_LOG_DIR', 'disabled')
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform='tpu',
+                                            topology_name='v5e:2x2')
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f'no v5e:2x2 topology can be described here: {e}')
+    # a compile for a described chip can be written to the persistent
+    # cache but not read back without one: keep the cache out of it
+    jax.config.update('jax_enable_compilation_cache', False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update('jax_enable_compilation_cache', True)
+    compilation_cache.reset_cache()
+
+
+def _compile(one_chip, fn, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+F32, BF16 = jnp.float32, jnp.bfloat16
+N = 32          # train_imagenet.sh batch per chip
+
+# (id, kernel under its ResNet-50 / BERT-base call, operand shapes)
+CAPTURE_CASES = [
+    ('a_conv-3x3s1-C64',
+     lambda a: pc.compute_a_conv(a, (3, 3), (1, 1), ((1, 1), (1, 1)),
+                                 False),
+     [((N, 56, 56, 64), BF16)]),
+    ('a_conv-1x1s2-C256-downsample',
+     lambda a: pc.compute_a_conv(a, (1, 1), (2, 2), 'VALID', False),
+     [((N, 56, 56, 256), BF16)]),
+    ('a_conv-1x1s2-C1024-downsample-f32',
+     lambda a: pc.compute_a_conv(a, (1, 1), (2, 2), 'VALID', False),
+     [((N, 14, 14, 1024), F32)]),
+    ('a_conv-3x3s2-C64',
+     lambda a: pc.compute_a_conv(a, (3, 3), (2, 2), ((1, 1), (1, 1)),
+                                 False),
+     [((N, 56, 56, 64), BF16)]),
+    ('g_conv-C256',
+     lambda g: pc.compute_g_conv(g, True), [((N, 56, 56, 256), BF16)]),
+    ('g_dense-1000',
+     lambda g: pc.compute_g_dense(g, True), [((N, 1000), BF16)]),
+    ('a_dense-768+bias-f32',
+     lambda a: pc.compute_a_dense(a, True), [((4, 384, 768), F32)]),
+    ('a_dense-768+bias-bf16',
+     lambda a: pc.compute_a_dense(a, True), [((4, 384, 768), BF16)]),
+    ('g_conv-C1024-fused-ema',
+     lambda g, cur: pc.compute_g_conv(g, True, ema=(cur, 0.95)),
+     [((N, 14, 14, 1024), BF16), ((1024, 1024), F32)]),
+    ('a_conv-1x1-C1024-fused-ema',
+     lambda a, cur: pc.compute_a_conv(a, (1, 1), (1, 1), 'VALID', False,
+                                      ema=(cur, 0.95)),
+     [((N, 14, 14, 1024), BF16), ((1024, 1024), F32)]),
+    ('ef_quantize',
+     lambda x, r: pc.ef_quantize(x, r),
+     [((8, 512, 512), F32), ((8, 512, 512), F32)]),
+]
+
+
+@pytest.mark.parametrize('fn,shapes',
+                         [c[1:] for c in CAPTURE_CASES],
+                         ids=[c[0] for c in CAPTURE_CASES])
+def test_capture_kernel_compiles_for_v5e(one_chip, fn, shapes):
+    assert 'tpu_custom_call' in _compile(one_chip, fn, *shapes)
+
+
+def test_conv1_is_routed_to_xla_and_says_so(one_chip, capsys):
+    """ResNet-50's conv1 (7x7/2 on 3 channels) pads 3 -> 128 lanes in
+    VMEM and cannot be tiled per image: it is routed to the XLA
+    reference EXPLICITLY — the program compiles with no kernel in it and
+    the routing is reported, never silent (ROADMAP S4)."""
+    pc._WARNED.clear()
+    text = _compile(
+        one_chip,
+        lambda a: pc.compute_a_conv(a, (7, 7), (2, 2), ((3, 3), (3, 3)),
+                                    False),
+        ((N, 224, 224, 3), BF16))
+    assert 'tpu_custom_call' not in text
+    assert 'stays on the XLA path' in capsys.readouterr().err
+
+
+@pytest.mark.parametrize('length', [2048, 32768])
+def test_flash_block_attn_fwd_bwd_compiles_for_v5e(one_chip, length,
+                                                   monkeypatch):
+    # the fused backward, whatever the auto policy picks at this length
+    monkeypatch.setenv('KFAC_ATTN_BWD_IMPL', 'pallas')
+    bh, d = 8, 64
+    scale = d ** -0.5
+
+    def loss(q, k, v, mask):
+        starts = jnp.zeros((2,), jnp.int32)
+        _, l, pv = pallas_attention.flash_block_attn(
+            q, k, v, mask, starts, scale, True, False)
+        return (l ** 2).sum() + (pv.astype(F32) ** 2).sum()
+
+    qkv = ((bh, length, d), BF16)
+    text = _compile(one_chip,
+                    jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                    qkv, qkv, qkv, ((bh, length), F32))
+    # forward + dq + dkv kernels
+    assert text.count('tpu_custom_call') >= 3

@@ -589,3 +589,28 @@ def test_analytic_comm_model_cli_helper():
     t32 = sum(vols['fp32'].values())
     t16 = sum(vols['bf16'].values())
     assert 0.4 <= t16 / t32 <= 0.55   # ~half, modulo the evals vector
+
+
+def test_ef_rounding_is_reduce_precision_and_matches_the_bf16_cast():
+    """pmean_scatter_ef takes the wire rounding with lax.reduce_precision
+    — the form XLA never elides (on the TPU it folded
+    ``xc - f32(bf16(xc))`` to exactly 0: PERF.md, PR 21) — and that form
+    has the cast's bits, ties and edge values included."""
+    r = np.random.RandomState(3)
+    x = np.concatenate([
+        r.randn(4096).astype(np.float32) * 10.0 ** r.randint(-30, 30, 4096),
+        np.float32([0.0, -0.0, 1.00390625, 1.01171875, 3.3895314e38,
+                    1e-40, np.inf, -np.inf])])
+    x = jnp.asarray(x)
+    rounded = jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7)
+    cast = x.astype(jnp.bfloat16).astype(jnp.float32)     # eager: kept
+    assert np.array_equal(np.asarray(rounded), np.asarray(cast))
+    # and under jit, where the cast form is at the compiler's mercy
+    jitted = jax.jit(lambda v: v - jax.lax.reduce_precision(
+        v, exponent_bits=8, mantissa_bits=7))(x[:4096])
+    assert np.array_equal(np.asarray(jitted),
+                          np.asarray(x[:4096] - cast[:4096]))
+    import inspect
+    from kfac_pytorch_tpu.parallel import collectives
+    assert 'reduce_precision' in inspect.getsource(
+        collectives.pmean_scatter_ef)

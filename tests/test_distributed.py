@@ -166,3 +166,41 @@ def test_dp_uses_owner_local_stats(variant):
     want_row0 = decay * np.asarray(ops.identity_pad(jnp.asarray(A0), 16)) \
         + (1 - decay) * np.eye(16, dtype=np.float32)
     np.testing.assert_allclose(b16[0], want_row0, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize('variant', ['eigen_dp', None])
+def test_init_train_state_on_mesh_is_born_sharded(variant):
+    """init_train_state(mesh=, axis_name=) builds the state ON the mesh:
+    same values as the one-device build, every leaf already in the
+    sharding the mesh step takes — no device ever holds every factor
+    (four v5e chips: 5.4 GB on device 0 against 1.6, PERF.md PR 21) and
+    the first step call has nothing to reshard."""
+    from kfac_pytorch_tpu import training
+    from kfac_pytorch_tpu.models.tiny import TinyCNN
+    nd = 4
+    mesh = Mesh(np.array(jax.devices()[:nd]), ('batch',))
+    model = TinyCNN(batch_norm=True)
+    x = jnp.zeros((2 * nd, 8, 8, 3), jnp.float32)
+    tx = training.sgd(0.1, momentum=0.9)
+
+    def make():
+        return None if variant is None else kfac.KFAC(
+            variant=variant, num_devices=nd, axis_name='batch')
+
+    plain = training.init_train_state(model, tx, make(),
+                                      jax.random.PRNGKey(0), x)
+    pre = make()
+    born = training.init_train_state(model, tx, pre, jax.random.PRNGKey(0),
+                                     x, mesh=mesh, axis_name='batch')
+    assert (jax.tree.structure(born) == jax.tree.structure(plain))
+    jax.tree.map(lambda a, b: np.testing.assert_array_equal(
+        np.asarray(a), np.asarray(b)), born, plain)
+    everywhere = set(mesh.devices.flat)
+    for leaf in jax.tree.leaves(born):
+        assert {s.device for s in leaf.addressable_shards} == everywhere
+    if variant is not None:
+        # factor rows are split over the axis, not replicated
+        for leaf in jax.tree.leaves(born.kfac_state.factors):
+            assert leaf.sharding.spec == P('batch')
+            assert leaf.addressable_shards[0].data.shape[0] * nd \
+                == leaf.shape[0]

@@ -218,9 +218,14 @@ def _mesh_step(pre, mesh, n_extra, grads, acts, gs):
     lead = len(names)
     io_spec = P(*names)
 
+    # on a composed mesh the state comes out replicated over the
+    # non-data axes BY VALUE (duplicated operands; the tests assert it
+    # bitwise) but varying BY TYPE — the operands are sharded over every
+    # axis — so the kspecs out_specs cannot be statically inferred there
     @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(kspecs, io_spec, io_spec, io_spec),
-                       out_specs=(io_spec, kspecs))
+                       out_specs=(io_spec, kspecs),
+                       check_vma=n_extra == 0)
     def step(kstate, grads, acts, gs):
         def sq(t):
             return jax.tree.map(

@@ -12,19 +12,6 @@ import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
 from kfac_pytorch_tpu.parallel.moe import ExpertFFN, SwitchMoE
-from tests import helpers
-
-# See tests/test_tp.py: these oracles take grads INSIDE the shard_map
-# body, which the legacy shard_map shim (check_rep=False) mis-transposes
-# for replicated operands. Live probe, not a version pin; the owner-
-# local expert K-FAC path is covered backend-independently by
-# tests/test_meshplan.py with oracle capture operands.
-requires_body_autodiff = pytest.mark.skipif(
-    helpers.shard_map_body_autodiff_broken(),
-    reason='legacy shard_map shim (check_rep=False) mis-transposes '
-           'in-body autodiff: replicated-operand cotangents miss their '
-           'cross-axis psum (probe: tests/helpers.py'
-           '::shard_map_body_autodiff_broken)')
 
 NE, TL, D, DH = 4, 8, 10, 16     # experts/ranks, tokens per rank, dims
 
@@ -60,7 +47,6 @@ def _dense_oracle(gate, experts, x):
     return y * p[:, None]
 
 
-@requires_body_autodiff
 def test_switch_moe_matches_dense_mixture():
     x = jnp.asarray(np.random.RandomState(0).randn(NE * TL, D),
                     jnp.float32)
@@ -133,7 +119,6 @@ def test_switch_moe_capacity_drops_zero():
     np.testing.assert_array_equal(np.asarray(y[1:]), 0)
     assert np.abs(np.asarray(y[0])).max() > 0
 
-@requires_body_autodiff
 def test_moe_kfac_dp_ep_invariance():
     """One K-FAC step (MPD 'eigen' over the data axis) on a 2x2
     ('data', 'expert') mesh matches the expert-mesh-only full-batch run

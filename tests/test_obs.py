@@ -551,7 +551,7 @@ def test_drift_ratios_pinned_on_synthetic_pair():
     assert bad['gate']['verdict'] == 'drift'
     assert bad['gate']['violations'] == ['Model']
     # same numbers on CPU: advisory, never chip evidence
-    adv = drift.drift_block({'Model': 0.5}, pred, platform='cpu_fallback')
+    adv = drift.drift_block({'Model': 0.5}, pred, platform='cpu')
     assert adv['comparable'] is False
     assert adv['gate']['verdict'] == 'advisory'
     # tolerance widens the band

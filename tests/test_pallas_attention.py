@@ -223,9 +223,8 @@ def test_pallas_bwd_matches_recompute_bwd(monkeypatch):
 
 def test_bwd_impl_auto_policy():
     """'auto' resolves by static block key length: blockwise recompute
-    below the measured v5e crossover, fused Pallas backward at/above it
-    (logs/onchip/queue_0731_0346.flash_bwd_ab.log: 8k recompute 45 ms vs
-    fused 62 ms; 32k fused 0.66 s vs recompute 9.9 s)."""
+    below the hypothesized v5e crossover (ROADMAP S7), fused Pallas
+    backward at/above it."""
     from kfac_pytorch_tpu.ops.pallas_attention import (
         AUTO_BWD_PALLAS_MIN_LK, _bwd_impl_for)
     assert _bwd_impl_for('auto', 1024) == 'recompute'
@@ -241,10 +240,8 @@ def test_bwd_impl_auto_policy():
 
 def test_fwd_impl_auto_policy(monkeypatch):
     """'auto' forward resolves by static block key length, mirroring the
-    backward policy: XLA blockwise below the measured v5e crossover
-    (fwd+bwd 8k: XLA 43.5 ms vs Pallas 59.4; 16k: 103.6 vs 180.9), the
-    Pallas kernel at/above it (32k: only Pallas compiles,
-    logs/onchip/queue_0731_0346.summary) — VERDICT r2 #3."""
+    backward policy: XLA blockwise below the hypothesized v5e crossover
+    (ROADMAP S7), the Pallas kernel at/above it."""
     from kfac_pytorch_tpu.parallel.ring_attention import (
         AUTO_FWD_PALLAS_MIN_LK, _default_block_impl, _fwd_impl_for)
     assert _fwd_impl_for('auto', 1024) == 'xla'

@@ -279,7 +279,11 @@ def test_ef_quantize_bitwise_under_shard_map():
 
     kw = dict(mesh=mesh, in_specs=(P('x'), P('x')),
               out_specs=(P('x'), P('x')))
-    w1, r1 = jax.jit(jax.shard_map(fused, **kw))(x, res)
+    # the Pallas INTERPRETER seeds its output loop carries without the
+    # varying axes the out_shape declares (jax 0.9 hlo_interpreter), so
+    # an interpreted kernel needs check_vma=False — the Mosaic lowering
+    # on the chip does not (tests/test_chip_compile.py)
+    w1, r1 = jax.jit(jax.shard_map(fused, check_vma=False, **kw))(x, res)
     w2, r2 = jax.jit(jax.shard_map(two_pass, **kw))(x, res)
     assert np.array_equal(np.asarray(w1, dtype=np.float32),
                           np.asarray(w2, dtype=np.float32))
@@ -341,12 +345,13 @@ def test_step_world1_pallas_matches_legacy(variant):
         # FMA-contract (the documented one-rounding exception), so the
         # factor state tracks within ulp-level tolerance — and the
         # damped eigendecomposition amplifies that ulp into ~1e-4
-        # relative on the preconditioned gradient (condition ~1/damping)
+        # relative on the preconditioned gradient (condition ~1/damping),
+        # i.e. ~1e-5 of the gradient's scale on its near-zero entries
         for k in st_x.factors:
             np.testing.assert_allclose(
                 np.asarray(st_p.factors[k]), np.asarray(st_x.factors[k]),
                 rtol=1e-6, atol=1e-7)
-        g_rtol, g_atol = 5e-4, 1e-6
+        g_rtol, g_atol = 5e-4, 1e-5
     else:
         # stat kernels + two-pass EMA: fully bitwise
         assert _tree_equal(st_x.factors, st_p.factors)
@@ -423,3 +428,42 @@ def test_capture_ladder_switch_compile_count():
         state, _ = step(state, batch, lr=0.05, damping=0.003)
     assert set(step.variants) == committed, (
         sorted(map(str, set(step.variants) - committed)))
+
+
+def test_xla_routing_is_counted_and_reported_once(monkeypatch, capsys):
+    """A statistic that asked for the fused kernel and cannot have it is
+    never routed silently: inside a capture pass the routing is counted
+    on both sides and reported in ONE line per run (every step variant
+    re-traces the same pass); outside a pass it warns per shape."""
+    monkeypatch.setenv('KFAC_CAPTURE_MAX_F', '8')
+    monkeypatch.setattr(pallas_capture, '_WARNED', set())
+    small = jnp.ones((4, 6), jnp.float32)
+    wide = jnp.ones((4, 16), jnp.float32)
+    for _ in range(2):
+        with pallas_capture.routing_report():
+            pallas_capture.compute_a_dense(small, True, interpret=True)
+            pallas_capture.compute_g_dense(wide, True, interpret=True)
+    err = capsys.readouterr().err
+    assert err.count('capture_impl=pallas: 1 factor statistics fused, '
+                     '1 on the XLA path (1 x factor dim over the fused '
+                     'cap)') == 1
+    pallas_capture.compute_g_dense(wide, True, interpret=True)
+    assert 'stays on the XLA path' in capsys.readouterr().err
+
+
+@pytest.mark.parametrize('strides,kernel,padding', [
+    ((2, 2), (1, 1), 'VALID'),              # ResNet downsample
+    ((2, 2), (3, 3), ((1, 1), (1, 1))),     # strided 3x3
+    ((2, 2), (7, 7), ((3, 3), (3, 3))),     # conv1 geometry (small C ok)
+    ((2, 1), (3, 2), 'SAME'),               # mixed strides, odd sizes
+    ((3, 2), (2, 3), ((0, 1), (2, 0))),     # stride > kernel on one axis
+])
+def test_conv_a_strided_phases_bitwise(strides, kernel, padding):
+    """The stride phases are de-interleaved before the kernel (Mosaic
+    lowers no strided slice): pure data movement, so every stride keeps
+    the reference's bits."""
+    a = jnp.asarray(_rng(21).randn(3, 11, 9, 5), jnp.float32)
+    got = pallas_capture.compute_a_conv(a, kernel, strides, padding, True,
+                                        interpret=True)
+    want = factors.compute_a_conv(a, kernel, strides, padding, True)
+    assert np.array_equal(np.asarray(got), np.asarray(want))

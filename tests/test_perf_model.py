@@ -1,8 +1,7 @@
-"""Pins the analytic perf model (VERDICT r4 #1): the committed
-cost-analysis inputs, the fenced-constant eigh fit, the scenario
-arithmetic, and the predicted block's shape — so the `predicted`
-numbers BENCH_r05.json carries are reproducible and a silent change to
-any ingredient fails loudly here."""
+"""Pins the analytic perf model: the committed cost-analysis inputs,
+the eigh-time fit, the scenario arithmetic, and the predicted block's
+shape — so the `predicted` numbers a bench JSON carries are
+reproducible and a silent change to any ingredient fails loudly here."""
 
 import json
 import os
@@ -118,7 +117,7 @@ def test_derivation_script_smoke(tmp_path):
     out = tmp_path / 'inputs.json'
     env = {k: v for k, v in os.environ.items()
            if k not in ('XLA_FLAGS', 'JAX_PLATFORMS')}
-    env.update(KFAC_PLATFORM='cpu', DERIVE_MODEL='resnet20',
+    env.update(JAX_PLATFORMS='cpu', DERIVE_MODEL='resnet20',
                DERIVE_IMG='32', DERIVE_BATCH='8')
     subprocess.run([sys.executable, 'scripts/derive_perf_inputs.py',
                     '--out', str(out)], cwd=REPO, env=env, check=True,

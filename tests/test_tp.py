@@ -18,21 +18,6 @@ import kfac_pytorch_tpu as kfac
 from kfac_pytorch_tpu import capture
 from kfac_pytorch_tpu import nn as knn
 from kfac_pytorch_tpu.parallel import tp
-from tests import helpers
-
-# These oracles differentiate INSIDE the shard_map body; the legacy
-# shard_map shim (check_rep=False) drops the cross-axis psum on
-# replicated-operand cotangents there, so they cannot run on this
-# backend. The guard is a live probe, not a version pin — the tests
-# come back automatically on a backend with vma-tracked shard_map.
-# K-FAC's own composed-mesh step path is covered backend-independently
-# by tests/test_meshplan.py (oracle capture operands, no in-body grads).
-requires_body_autodiff = pytest.mark.skipif(
-    helpers.shard_map_body_autodiff_broken(),
-    reason='legacy shard_map shim (check_rep=False) mis-transposes '
-           'in-body autodiff: replicated-operand cotangents miss their '
-           'cross-axis psum (probe: tests/helpers.py'
-           '::shard_map_body_autodiff_broken)')
 
 B, DIN, DH, DOUT, NM = 8, 6, 8, 5, 2     # NM model ranks; DH_local = DH/NM
 DH_L = DH // NM
@@ -93,7 +78,6 @@ def _model_mesh():
     return Mesh(np.array(jax.devices()[:NM]), ('model',))
 
 
-@requires_body_autodiff
 def test_tp_forward_backward_exact():
     """The sharded column->row computation IS the full dense math: outputs
     match the unsharded model exactly, and every rank's parameter grads
@@ -157,7 +141,6 @@ def _make_precond(variant, num_devices=1, axis_name=None):
 
 
 @pytest.mark.parametrize('variant', ['eigen_dp', 'inverse_dp'])
-@requires_body_autodiff
 def test_tp_kfac_matches_per_slice_oracle(variant):
     """Each model-rank's preconditioned update equals the exact oracle:
     the SAME local module on one device, with the other ranks' partial
@@ -231,7 +214,6 @@ def test_tp_kfac_matches_per_slice_oracle(variant):
                                    rtol=1e-4, atol=1e-5)
 
 
-@requires_body_autodiff
 def test_dp_tp_kfac_matches_model_only_full_batch():
     """2x2 ('data', 'model') mesh with the K-FAC world on the data axis
     (MPD 'eigen': pmean-reduced stats) == the model-only mesh run on the
@@ -380,7 +362,6 @@ def test_tp_encoder_block_matches_dense_block():
         grads_tp, flat_tp)
 
 
-@requires_body_autodiff
 def test_tp_encoder_block_kfac_dp_tp_invariance():
     """One K-FAC step on the Megatron block over a 2x2 ('data', 'model')
     mesh (MPD 'eigen' over the data axis) equals the model-only mesh run
@@ -454,7 +435,6 @@ def test_tp_encoder_block_kfac_dp_tp_invariance():
         got, want)
 
 
-@requires_body_autodiff
 def test_tp_sp_block_3axis_matches_dense_block():
     """The FULL 3-D mesh: ('data', 'seq', 'model') 2x2x2 — batch sharded
     over data, tokens over seq (exact ring attention rotates K/V per
