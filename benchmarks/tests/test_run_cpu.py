@@ -1,0 +1,161 @@
+"""``run.py`` end to end on the CPU with the rehearsal cells under
+``benchmarks/tests/`` (tiny sizes: nothing here is a device number).
+Run with ``python3 -m pytest benchmarks/tests -q``; not part of tier-1."""
+
+import json
+import os
+import shutil
+import subprocess
+import sys
+
+import pytest
+
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROOT = os.path.dirname(BENCH)
+KEYS = {'correct', 'attempted', 'failed', 'metrics', 'device'}
+
+
+def run_cell(workload, trace=0, devices=1, extra=(), cwd=ROOT, seconds=2):
+    env = dict(os.environ, JAX_PLATFORMS='cpu')
+    env['XLA_FLAGS'] = f'--xla_force_host_platform_device_count={devices}'
+    env['JAX_COMPILATION_CACHE_DIR'] = '/nonexistent/overridden-by-run.py'
+    proc = subprocess.run(
+        [sys.executable, os.path.join(cwd, 'benchmarks', 'run.py'),
+         '--workload', workload, '--seed', '2147483659', '--seconds',
+         str(seconds), '--trace', str(trace), *extra],
+        cwd=cwd, env=env, capture_output=True, text=True, timeout=900)
+    rows = [json.loads(line) for line in proc.stdout.splitlines()
+            if line.startswith('{')]
+    return proc, rows
+
+
+def by_phase(rows, phase):
+    return [r for r in rows if r.get('phase') == phase]
+
+
+@pytest.mark.parametrize('workload,seconds', [('tiny-bert-freq10', 2),
+                                              # seconds a step on the CPU:
+                                              # room for both window parts
+                                              ('tiny-resnet-freq10', 40)])
+def test_untraced_run(workload, seconds):
+    proc, rows = run_cell(workload, seconds=seconds)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    last = rows[-1]
+    assert set(last) == KEYS
+    assert last['correct'] is True and last['failed'] == 0
+    assert set(last['metrics']) == {'samples_per_s', 'step_ms_p95',
+                                    'setup_s'}
+    assert last['device']['platform'] == 'cpu'
+    win, = by_phase(rows, 'window')
+    assert win['compiles_in_window'] == 0
+    assert win['steps'] == last['attempted']
+    # whole periods in both parts of the window
+    assert win['chunks'] > 0 and win['fenced_steps'] > 0
+    assert (win['steps'] - win['fenced_steps']) % 10 == 0
+    assert win['fenced_steps'] % 10 == 0
+    # every number compared is printed beside its limit
+    checks = {r['check']: r for r in by_phase(rows, 'check') if 'check' in r}
+    assert set(checks) == {'loss_gap', 'first_update_norm_gap',
+                           'param_change_norm_gap', 'factor_gap'}
+    assert all(r['value'] <= r['limit'] for r in checks.values())
+    # the K-FAC state is stored in the dtype the configuration states
+    summary, = [r for r in by_phase(rows, 'check') if 'reference_s' in r]
+    assert summary['kfac_state_dtypes'] == ['float32']
+    assert summary['kfac_state_dtype_stated'] == 'float32'
+    # the cache sits in the checkout whatever the environment names
+    setup, = by_phase(rows, 'setup')
+    assert setup['cache_dir'] == os.path.join(ROOT, '.jax_cache')
+
+
+def test_traced_run_and_pieces_that_exist_only_under_tests():
+    proc, rows = run_cell('tiny-bert-freq1', trace=1)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    last = rows[-1]
+    assert last['correct'] is True
+    # cadence 1: all steps alike, no fenced part, no tail
+    win, = by_phase(rows, 'window')
+    assert win['fenced_steps'] == 0 and win['steps'] % 10 == 0
+    m = last['metrics']
+    assert m['compiles_in_window']['value'] == 0
+    assert {'sgd_step_ms', 'kfac_over_sgd', 'hbm_plan_gb'} <= set(m)
+    # metric, reducer and workload found only under benchmarks/tests/
+    assert m['steps_in_window']['value'] == win['steps']
+    # a CPU trace has no device plane: no device metric carries a number
+    assert not {'device_idle_pct', 'inverse_ms_per_step',
+                'model_ms_per_step'} & set(m)
+    assert 'busy_s' not in last['device']
+
+
+def test_four_virtual_devices_rehearse_the_mesh_only():
+    """The mesh path builds and steps on four devices. The reference
+    cannot follow a sharded K-FAC step yet, so this rehearsal compares the
+    first loss alone -- which is why ``run.py`` refuses such a cell when it
+    comes from ``BENCHMARK.json`` (next test)."""
+    proc, rows = run_cell('tiny-bert-dp4-freq10', devices=4)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert rows[-1]['device']['count'] == 4
+    assert rows[-1]['failed'] == 0
+    checks = [r['check'] for r in by_phase(rows, 'check') if 'check' in r]
+    assert checks == ['loss_gap']
+    win, = by_phase(rows, 'window')
+    assert win['compiles_in_window'] == 0
+
+
+def test_multi_chip_cell_of_benchmark_json_is_refused(tmp_path):
+    with open(os.path.join(ROOT, 'BENCHMARK.json')) as f:
+        bench = json.load(f)
+    bench['workloads'][0]['chips'] = 4
+    (tmp_path / 'BENCHMARK.json').write_text(json.dumps(bench))
+    shutil.copytree(BENCH, tmp_path / 'benchmarks',
+                    ignore=shutil.ignore_patterns('__pycache__'))
+    proc, rows = run_cell(bench['workloads'][0]['name'], devices=4,
+                          cwd=str(tmp_path))
+    assert proc.returncode != 0
+    assert not any(KEYS <= set(r) for r in rows)
+    assert 'asks for 4 chips' in proc.stderr
+
+
+@pytest.mark.parametrize('mode', ['kfac', 'all'])
+def test_lower_precision_control_fails(mode):
+    """The reference one precision step down, in the program's place:
+    the K-FAC state and arithmetic alone, and the activations too."""
+    proc, rows = run_cell('tiny-bert-freq1', extra=['--lower', mode])
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert rows[-1]['correct'] is False
+    checks = {r['check']: r for r in by_phase(rows, 'check') if 'check' in r}
+    assert not checks['first_update_norm_gap']['ok']
+
+
+def test_stuck_step_is_not_correct():
+    """The timed path broken underneath: parameters never change."""
+    proc, rows = run_cell('tiny-bert-stuck-freq1')
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert rows[-1]['correct'] is False
+    checks = {r['check']: r for r in by_phase(rows, 'check') if 'check' in r}
+    assert not checks['param_change_norm_gap']['ok']
+
+
+def test_kfac_state_stored_in_a_lower_dtype_shows():
+    import types
+    import jax.numpy as jnp
+    from harness import program
+    f32, bf16 = jnp.zeros((2, 2)), jnp.zeros((2, 2), jnp.bfloat16)
+    state = types.SimpleNamespace(kfac_state=types.SimpleNamespace(
+        factors={'0': f32}, decomp={'0': bf16}))
+    assert program.kfac_state_dtypes(state) == ['bfloat16', 'float32']
+
+
+def test_no_tpu_no_result():
+    proc, rows = run_cell('resnet50-freq10')
+    assert proc.returncode != 0
+    assert not any(KEYS <= set(r) for r in rows)
+    assert 'needs a TPU' in proc.stderr
+
+
+def test_benchmark_files_alone_give_no_result(tmp_path):
+    shutil.copy(os.path.join(ROOT, 'BENCHMARK.json'), tmp_path)
+    shutil.copytree(BENCH, tmp_path / 'benchmarks',
+                    ignore=shutil.ignore_patterns('__pycache__'))
+    proc, rows = run_cell('tiny-bert-freq1', cwd=str(tmp_path))
+    assert proc.returncode != 0
+    assert not any(KEYS <= set(r) for r in rows)
