@@ -28,8 +28,9 @@ none compose. This package is the common layer they all report through:
   times vs ``perfmodel.py``'s ``predicted`` block, emitted as per-phase
   drift ratios in every ``bench.py`` JSON (even on CPU rounds).
 
-Everything here is dependency-free stdlib (jax is touched only through
-optional, lazily-imported bridges), so the supervisor/aggregator side
+Everything here is dependency-free stdlib (jax is touched only where the
+process has already loaded it: ``trace.annotation``, the span's second
+sink in the profiler's own trace), so the supervisor/aggregator side
 stays importable on machines with no accelerator stack at all.
 """
 

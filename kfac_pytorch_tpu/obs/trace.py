@@ -16,22 +16,26 @@ timestamps so files from different hosts merge on a common axis:
   a paired (wall, monotonic) reading for post-hoc clock alignment.
 
 Span names reuse the engine's ``jax.named_scope`` taxonomy
-(``kfac.ComputeFactor`` etc. — the ``exclude_parts`` ledger names), and
-:meth:`TraceRecorder.span` can *bridge* into ``jax.named_scope`` so the
-same label shows up in host traces AND in XLA/Perfetto device profiles
-(``utils.profiling.trace``).
+(``kfac.ComputeFactor`` etc. — the ``exclude_parts`` ledger names).
+One ``span`` call has two sinks: the recorder's JSONL (wall clock) and
+the profiler's own trace (:func:`annotation` —
+``jax.profiler.TraceAnnotation``, a host event on the clock of the
+device events beside it), so a ``jax.profiler`` session shows what the
+host was doing in every gap between device operations.
 
 Durability: the ring buffer is flushed through the run log's
 SIGTERM/atexit chain (``utils.runlog.register_flusher``) — the same
 guarantee the log tail has, so a watchdog abort or preemption cannot
 lose the trace of the steps that led up to it.
 
-Zero dependencies; ``jax`` is imported only inside the optional bridge.
+Zero dependencies; :func:`annotation` uses ``jax`` only where the process
+has already imported it.
 """
 
 import contextlib
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -47,6 +51,26 @@ DEFAULT_MAXLEN = 65536
 
 _DEFAULT = None
 _DEFAULT_LOCK = threading.Lock()
+
+
+def annotation(name):
+    """A host span in the profiler's own trace: enters
+    ``jax.profiler.TraceAnnotation(name)``. With no profiler session
+    open it costs the inactive check. ``jax`` is never imported from
+    here: a process that has not loaded it (the stdlib-only tools that
+    import ``obs``) has no profiler to write to, and passes through."""
+    jax = sys.modules.get('jax')
+    if jax is None:
+        return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation(name)
+
+
+def _profiler_side(name, annotate):
+    """The profiler-side context of a span (``annotate``: see
+    :meth:`TraceRecorder.span`)."""
+    if annotate is False:
+        return contextlib.nullcontext()
+    return annotation(annotate or name)
 
 
 class TraceRecorder:
@@ -102,23 +126,17 @@ class TraceRecorder:
     # -- the public event shapes ------------------------------------------
 
     @contextlib.contextmanager
-    def span(self, name, cat='kfac', xla=False, **args):
-        """Record a complete span around the with-block.
+    def span(self, name, cat='kfac', annotate=None, **args):
+        """Record a complete span around the with-block, and enter the
+        same span in the profiler's trace (:func:`annotation`).
 
-        ``xla=True`` additionally enters ``jax.named_scope(name)`` so
-        code traced inside the block carries the same label in the
-        compiled program's metadata (the bridge between host spans and
-        the on-chip profiler trace). The bridge is best-effort: no jax,
-        or a context where named_scope is invalid, degrades to the host
-        span alone.
+        ``annotate``: the span's name in the profiler's trace when it
+        differs from the recorded one (``training.step_fn`` puts the
+        dispatched phase set in it: a trace reader keeps names, not
+        args); ``False`` keeps the span out of the profiler's trace (a
+        drawing of a schedule, not an interval of work).
         """
-        cm = contextlib.nullcontext()
-        if xla:
-            try:
-                import jax
-                cm = jax.named_scope(name)
-            except Exception:  # noqa: BLE001 — bridge is best-effort
-                pass
+        cm = _profiler_side(name, annotate)
         t_wall = self._clock()
         t0 = self._perf()
         try:
@@ -235,7 +253,8 @@ class TraceRecorder:
 #
 # The resilience modules (and anything else that wants to narrate) call
 # the module-level instant()/span() below; with no recorder installed
-# they are near-free no-ops, so tracing stays strictly opt-in.
+# instant() is a near-free no-op and span() only the profiler's inactive
+# check, so tracing stays strictly opt-in.
 
 def get():
     """The installed process-default recorder, or None."""
@@ -314,16 +333,14 @@ def instant(name, cat='resilience', **args):
         return None
 
 
-@contextlib.contextmanager
-def span(name, cat='kfac', xla=False, **args):
-    """Module-level span on the default recorder (plain pass-through
-    with-block without one)."""
+def span(name, cat='kfac', annotate=None, **args):
+    """Module-level span: always a span in the profiler's trace
+    (:func:`annotation`), and a recorded one on the default recorder
+    when one is installed (:meth:`TraceRecorder.span`)."""
     rec = _DEFAULT
     if rec is None:
-        yield
-        return
-    with rec.span(name, cat=cat, xla=xla, **args):
-        yield
+        return _profiler_side(name, annotate)
+    return rec.span(name, cat=cat, annotate=annotate, **args)
 
 
 def flush():

@@ -237,9 +237,9 @@ def _pallas_fwd(q, k, v, kv_mask, starts, scale, causal, interpret):
         # the j grid dim carries the scratch recurrence → must stay serial
         params['compiler_params'] = pltpu.CompilerParams(
             dimension_semantics=('parallel', 'parallel', 'arbitrary'))
-    m, l, pv = pl.pallas_call(kernel, grid_spec=grid_spec,
-                              out_shape=out_shape, interpret=interpret,
-                              **params)(
+    m, l, pv = pl.pallas_call(kernel, name='kfac_flash_fwd',
+                              grid_spec=grid_spec, out_shape=out_shape,
+                              interpret=interpret, **params)(
                                   meta, q, k, v, kv_mask[:, None, :])
     return m[..., 0], l[..., 0], pv
 
@@ -391,6 +391,7 @@ def _pallas_bwd(q, k, v, kv_mask, m, dl, dpv, starts, scale, causal,
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           nk=nk),
+        name='kfac_flash_bwd_dq',
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(BH, nq, nk),
@@ -417,6 +418,7 @@ def _pallas_bwd(q, k, v, kv_mask, m, dl, dpv, starts, scale, causal,
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           nq=nq),
+        name='kfac_flash_bwd_dkv',
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(BH, nk, nq),

@@ -334,6 +334,7 @@ def _stat_rows(rows, denom, *, mults=(), append_ones=False, ema=None,
         vma_args.append(ema[0])
     out = pl.pallas_call(
         kernel,
+        name='kfac_stat_rows',
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=0,
             grid=(nsteps,),
@@ -548,6 +549,7 @@ def compute_a_conv(a, kernel_size, strides, padding, use_bias, *,
         vma_args.append(ema[0])
     out = pl.pallas_call(
         kernel,
+        name='kfac_conv_a',
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=0,
             grid=(nsteps,),
@@ -594,6 +596,7 @@ def ef_quantize(x, residual, *, interpret=False):
     vma = _vma(x, residual)
     wire, new_residual = pl.pallas_call(
         _ef_kernel,
+        name='kfac_ef_quantize',
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=0,
             grid=(nsteps,),

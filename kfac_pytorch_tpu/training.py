@@ -16,6 +16,7 @@ param grads psummed by autodiff (the gradient allreduce), K-FAC engine
 collectives over the same axis.
 """
 
+import contextlib
 import functools
 from typing import Any, Callable, NamedTuple, Optional
 
@@ -28,6 +29,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from kfac_pytorch_tpu import capture, faults
 from kfac_pytorch_tpu import health as health_lib
+from kfac_pytorch_tpu.obs import trace as obs_trace
 from kfac_pytorch_tpu.parallel import collectives as coll
 from kfac_pytorch_tpu.preconditioner import KFACHyperParams
 
@@ -261,15 +263,23 @@ def build_train_step(model, tx, precond, loss_fn, axis_name=None, mesh=None,
         compiled variant cache, a ``comm_precision`` change clears it
         (the arbiter invalidator registered below) so no stale program
         can keep the old wire dtype.
-      tracer: an ``obs.trace.TraceRecorder`` (or None). When set, every
-        dispatch is recorded as a ``kfac.dispatch`` span carrying the
-        step index and the dispatched phase set in the exclude-parts
-        ledger taxonomy. This span covers dispatch only (the call
-        returns before the device finishes under async dispatch); the
-        full host-side step span — including the blocking metric read —
-        is ``PhaseTimers(tracer=...)``'s ``kfac.step``, so a trace
-        shows both how long the host spent submitting and how long the
-        step really took.
+      tracer: an ``obs.trace.TraceRecorder`` (or None). With or without
+        it, step_fn writes its host spans into the profiler's own trace
+        (``obs.trace.annotation``: ``kfac.step`` and its children
+        ``.read_step`` / ``.hooks`` / ``.select`` / ``.build/<variant>`` /
+        ``.dispatch/<phases>``; an inactive check each when no
+        ``jax.profiler`` session is open). When a recorder is given, the
+        dispatch span has two sinks from its one call: the profiler's
+        trace, and the recorder under its own name ``kfac.dispatch``
+        with the step index and the dispatched phase set in the
+        exclude-parts ledger taxonomy as args. That span covers dispatch
+        only (the call returns before the device finishes under async
+        dispatch); the full host-side step span — including the
+        blocking metric read — is ``PhaseTimers(tracer=...)``'s
+        ``kfac.step``, so a recorder's file shows both how long the host
+        spent submitting and how long the step really took. The
+        ``kfac.sched`` spans of a prefetched gather draw a schedule and
+        stay the recorder's alone.
 
     Returns ``step_fn(state, batch, lr, damping) -> (state, metrics)``;
     dispatches between up to four compiled variants using the
@@ -324,11 +334,18 @@ def build_train_step(model, tx, precond, loss_fn, axis_name=None, mesh=None,
                 key = jax.random.fold_in(key, coll.axis_index(axis_name))
             rngs = {'dropout': key}
 
+        # device scopes for what runs outside the engine's kfac.* ones
+        # (train., NOT kfac.: a trace reader takes "outside kfac." for
+        # the model's and optimizer's time). Inside train.grad JAX's own
+        # path tells the passes apart: a backward operation carries
+        # transpose(jvp(, a forward one jvp( without it
         if use_capture:
-            loss, out, grads, acts, gs, mutated = \
-                capture.value_and_grad_with_capture(
-                    model, lambda o: loss_fn(o, batch), variables, x,
-                    mutable=extra_mutable, axis_name=axis_name, rngs=rngs)
+            with jax.named_scope('train.grad'):
+                loss, out, grads, acts, gs, mutated = \
+                    capture.value_and_grad_with_capture(
+                        model, lambda o: loss_fn(o, batch), variables, x,
+                        mutable=extra_mutable, axis_name=axis_name,
+                        rngs=rngs)
             # trace-time convention guard (free): the capture loss must
             # be the LOCAL mean, or every G factor scales with the
             # shard count (the round-3 postmortem bug)
@@ -339,17 +356,20 @@ def build_train_step(model, tx, precond, loss_fn, axis_name=None, mesh=None,
                 # the parameter update keeps the real-loss grads above.
                 # 0xF15C domain tag keeps this stream distinct from the
                 # dropout stream even when dropout_seed == fisher_seed.
-                key = jax.random.fold_in(jax.random.PRNGKey(fisher_seed),
-                                         0xF15C)
-                key = jax.random.fold_in(key, state.step)
-                if axis_name is not None:
-                    key = jax.random.fold_in(key, coll.axis_index(axis_name))
-                pseudo = fisher_sample_fn(key, jax.lax.stop_gradient(out))
-                floss, _, _, acts, gs, _ = \
-                    capture.value_and_grad_with_capture(
-                        model, lambda o: fisher_loss_fn(o, pseudo),
-                        variables, x, mutable=extra_mutable,
-                        axis_name=axis_name, rngs=rngs)
+                with jax.named_scope('train.grad.fisher'):
+                    key = jax.random.fold_in(
+                        jax.random.PRNGKey(fisher_seed), 0xF15C)
+                    key = jax.random.fold_in(key, state.step)
+                    if axis_name is not None:
+                        key = jax.random.fold_in(
+                            key, coll.axis_index(axis_name))
+                    pseudo = fisher_sample_fn(key,
+                                              jax.lax.stop_gradient(out))
+                    floss, _, _, acts, gs, _ = \
+                        capture.value_and_grad_with_capture(
+                            model, lambda o: fisher_loss_fn(o, pseudo),
+                            variables, x, mutable=extra_mutable,
+                            axis_name=axis_name, rngs=rngs)
                 capture.check_local_mean_loss(floss, pseudo, axis_name)
         else:
             def plain_loss(params):
@@ -358,8 +378,9 @@ def build_train_step(model, tx, precond, loss_fn, axis_name=None, mesh=None,
                     mutable=list(extra_mutable), rngs=rngs)
                 return loss_fn(out, batch), (out, mutated)
 
-            (loss, (out, mutated)), grads = jax.value_and_grad(
-                plain_loss, has_aux=True)(state.params)
+            with jax.named_scope('train.grad'):
+                (loss, (out, mutated)), grads = jax.value_and_grad(
+                    plain_loss, has_aux=True)(state.params)
             acts = gs = None
             # same convention on the SGD path: average_grads below
             # divides the psummed grads by world size, so a pre-pmean'd
@@ -372,8 +393,9 @@ def build_train_step(model, tx, precond, loss_fn, axis_name=None, mesh=None,
         acts, gs = faults.corrupt_captured(fault_cfg, state.step, acts, gs)
 
         loss_local = loss
-        grads = coll.average_grads(grads, axis_name)
-        loss = coll.pmean(loss, axis_name)
+        with jax.named_scope('train.grad_reduce'):
+            grads = coll.average_grads(grads, axis_name)
+            loss = coll.pmean(loss, axis_name)
 
         def apply_update(hstate):
             """The normal K-FAC + optimizer update (the only path when
@@ -403,30 +425,33 @@ def build_train_step(model, tx, precond, loss_fn, axis_name=None, mesh=None,
                     # a non-finite preconditioner output (or the ladder's
                     # top rung) degrades THIS step to raw SGD gradients;
                     # factor statistics above still accumulated
-                    precond_ok = capture.all_finite(pgrads)
-                    use_precond = jnp.logical_and(
-                        precond_ok,
-                        jnp.logical_not(
-                            health_lib.degraded(hstate, health_cfg)))
-                    new_grads = jax.tree.map(
-                        lambda p, r: jnp.where(use_precond, p, r),
-                        pgrads, grads)
+                    with jax.named_scope('train.health_screen'):
+                        precond_ok = capture.all_finite(pgrads)
+                        use_precond = jnp.logical_and(
+                            precond_ok,
+                            jnp.logical_not(
+                                health_lib.degraded(hstate, health_cfg)))
+                        new_grads = jax.tree.map(
+                            lambda p, r: jnp.where(use_precond, p, r),
+                            pgrads, grads)
 
-            updates, opt_state = tx.update(new_grads, state.opt_state,
-                                           state.params)
-            params = optax.apply_updates(state.params, updates)
+            with jax.named_scope('train.optimizer'):
+                updates, opt_state = tx.update(new_grads, state.opt_state,
+                                               state.params)
+                params = optax.apply_updates(state.params, updates)
 
-            extra_vars = dict(state.extra_vars)
-            for k in extra_mutable:
-                if k in mutated:
-                    v = mutated[k]
-                    if sync_extra_vars:
-                        v = coll.pmean(v, axis_name)
-                    extra_vars[k] = v
+                extra_vars = dict(state.extra_vars)
+                for k in extra_mutable:
+                    if k in mutated:
+                        v = mutated[k]
+                        if sync_extra_vars:
+                            v = coll.pmean(v, axis_name)
+                        extra_vars[k] = v
 
             if health_cfg is not None:
-                hstate = health_lib.on_good_batch(hstate, health_cfg,
-                                                  precond_ok)
+                with jax.named_scope('train.health_screen'):
+                    hstate = health_lib.on_good_batch(hstate, health_cfg,
+                                                      precond_ok)
             return state.replace(step=state.step + 1, params=params,
                                  opt_state=opt_state,
                                  kfac_state=kfac_state,
@@ -444,238 +469,310 @@ def build_train_step(model, tx, precond, loss_fn, axis_name=None, mesh=None,
                 # keep KFACState.step in lockstep with TrainState.step so
                 # in-engine fault steps stay aligned with trainer steps
                 kfac_state = kfac_state.replace(step=kfac_state.step + 1)
-            return state.replace(
-                step=state.step + 1, kfac_state=kfac_state,
-                health=health_lib.on_bad_batch(hstate, health_cfg))
+            with jax.named_scope('train.health_screen'):
+                hstate = health_lib.on_bad_batch(hstate, health_cfg)
+            return state.replace(step=state.step + 1,
+                                 kfac_state=kfac_state, health=hstate)
 
         # one replicated scalar decides the branch — no host sync, and
         # every device agrees (batch_ok psums the per-shard bad flags)
-        ok = health_lib.batch_ok(axis_name, grads, loss_local, acts, gs)
+        with jax.named_scope('train.health_screen'):
+            ok = health_lib.batch_ok(axis_name, grads, loss_local, acts, gs)
         new_state = jax.lax.cond(ok, apply_update, skip_update,
                                  state.health)
         mets = {'loss': loss}
-        mets.update({'health/' + k: v for k, v in
-                     health_lib.metrics(new_state.health, ok).items()})
+        with jax.named_scope('train.health_screen'):
+            mets.update({'health/' + k: v for k, v in
+                         health_lib.metrics(new_state.health, ok).items()})
         return new_state, mets
 
     state_specs_cache = {}
 
+    def variant_phases(update_factors, update_inverse, factors_only=False,
+                       stagger_update=False, **_):
+        """The K-FAC phases a variant (``make_variant``'s arguments) runs
+        ('pred'/'stats'/'decomp'/'gather'): what ``step_fn.last_phases``
+        reports, what the dispatch span carries and what the step
+        program is named after."""
+        if precond is None:
+            return ()
+        if factors_only:
+            return ('stats',) if update_factors else ()
+        ph = ['pred']
+        if update_factors:
+            ph.append('stats')
+        if update_inverse or stagger_update:
+            ph.append('decomp')
+            if precond.comm_mode == 'inverse':
+                ph.append('gather')
+        return tuple(ph)
+
+    def variant_name(update_factors, update_inverse, update_basis=True,
+                     warm_basis=False, factors_only=False,
+                     stagger_update=False, prefetch=False):
+        """The step program's name (the device's ``XLA Modules`` line
+        shows ``jit_<name>``): ``sgd_step`` without a preconditioner,
+        else ``kfac_step_<phases>`` plus whatever the phase set does not
+        say (an eigenvalue-only ``refresh``, a ``warm`` basis, the
+        ``stagger`` cohort step, a ``prefetch``-ed gather)."""
+        if precond is None:
+            return 'sgd_step'
+        phases = variant_phases(update_factors, update_inverse,
+                                factors_only, stagger_update)
+        tags = [t for t, on in (
+            ('refresh', update_inverse and not update_basis),
+            ('warm', warm_basis), ('stagger', stagger_update),
+            ('prefetch', prefetch)) if on]
+        return '_'.join(['kfac_step', *(phases or ('none',)), *tags])
+
     def make_variant(update_factors, update_inverse, update_basis=True,
                      warm_basis=False, factors_only=False,
                      stagger_update=False, prefetch=False):
-        fn = functools.partial(one_step, update_factors=update_factors,
-                               update_inverse=update_inverse,
-                               update_basis=update_basis,
-                               warm_basis=warm_basis,
-                               factors_only=factors_only,
-                               stagger_update=stagger_update,
-                               prefetch=prefetch)
-        if axis_name is None:
-            return jax.jit(fn, donate_argnums=(0,) if donate else ())
-        sspecs = _state_specs(precond, axis_name)
-        bspecs = P(axis_name) if batch_specs is None else batch_specs
-        vma = (not _interpreted_kernels(precond) if check_vma is None
-               else check_vma)
-        sharded = jax.shard_map(
-            fn, mesh=mesh,
-            in_specs=(sspecs, bspecs, P()),
-            out_specs=(sspecs, P()),
-            check_vma=vma)
-        return jax.jit(sharded, donate_argnums=(0,) if donate else ())
+        static = dict(update_factors=update_factors,
+                      update_inverse=update_inverse,
+                      update_basis=update_basis, warm_basis=warm_basis,
+                      factors_only=factors_only,
+                      stagger_update=stagger_update, prefetch=prefetch)
+
+        def fn(state, batch, hyper):
+            return one_step(state, batch, hyper, **static)
+
+        if axis_name is not None:
+            sspecs = _state_specs(precond, axis_name)
+            bspecs = P(axis_name) if batch_specs is None else batch_specs
+            vma = (not _interpreted_kernels(precond) if check_vma is None
+                   else check_vma)
+            fn = jax.shard_map(
+                fn, mesh=mesh,
+                in_specs=(sspecs, bspecs, P()),
+                out_specs=(sspecs, P()),
+                check_vma=vma)
+        # jit names the program after the function: a stable name per
+        # variant, so a trace tells the step programs apart
+        fn.__name__ = fn.__qualname__ = variant_name(**static)
+        return jax.jit(fn, donate_argnums=(0,) if donate else ())
 
     variants = {}
     seen_inverse = {}  # host-side: does a decomposition exist yet?
+    annotation = obs_trace.annotation
 
     def step_fn(state, batch, lr=None, damping=None):
-        step = int(state.step)
-        # straggler governor: measure the inter-arrival of host steps
-        # (tick BEFORE the fault hooks so an injected slow step lands in
-        # the NEXT tick's interval, like any real stall would)
-        if straggler is not None:
-            straggler.tick(step)
-        if autotune is not None:
-            # the interval that just ended covered the PREVIOUS
-            # dispatch's phase set — attribute it there, like the
-            # PhaseTimers wall-time bucketing
-            autotune.tick(step, step_fn.last_phases)
-        if heartbeat is not None:
-            heartbeat.tick(step)
-        # host-side chaos drills (all no-ops unless env-configured):
-        # SIGTERM (PreemptionGuard), crash (supervisor restart), hang
-        # (step watchdog), slow (straggler governor)
-        faults.maybe_sigterm(fault_cfg, step)
-        faults.maybe_crash(fault_cfg, step)
-        faults.maybe_hang(fault_cfg, step)
-        faults.maybe_slow(fault_cfg, step,
-                          sleep=(straggler.sleep if straggler is not None
-                                 else None))
-        if (precond is not None
-                and getattr(precond, 'pending_replan', None)):
-            # a queued live replan (the arbiter's applied comm_mode
-            # switch, or a direct request_replan): apply it HERE — the
-            # between-steps boundary where no traced program is running
-            # — before anything below reads the preconditioner's config
-            # or retraces against the (already-invalidated) variant
-            # cache. A pure comm-mode switch carries the state verbatim;
-            # a layout change transports it host-side.
-            state = state.replace(
-                kfac_state=precond.apply_pending_replan(state.kfac_state))
-        if health_cfg is not None and state.health is None:
-            # one-time upgrade of a pre-health TrainState (old checkpoint
-            # or a hand-built state): done host-side BEFORE the jitted
-            # call so every variant only ever sees one state structure
-            state = state.replace(health=health_lib.HealthState.init())
-        if (precond is not None and state.kfac_state is not None
-                and getattr(precond, '_tracks_comm_err', False)
-                and state.kfac_state.comm_err is None):
-            # same one-time upgrade for the EF residual: a checkpoint
-            # taken before comm_precision was enabled (or at fp32)
-            # carries no residual — seed zeros host-side so every
-            # variant sees one state structure
-            state = state.replace(kfac_state=state.kfac_state.replace(
-                comm_err=precond._zero_comm_err()))
-        if (precond is not None and state.kfac_state is not None
-                and not getattr(precond, '_tracks_comm_err', False)
-                and state.kfac_state.comm_err is not None):
-            # the DOWNGRADE direction of the same upgrade: the autotuner
-            # (or a restart at fp32) switched the wire dtype off a lossy
-            # mode mid-run — drop the EF residual host-side so every
-            # variant sees one state structure; the residual is a
-            # correction term, never load-bearing (discarding it costs
-            # one reduce's worth of feedback, the same contract the
-            # lossy-checkpoint-into-fp32 restore already accepts)
-            state = state.replace(kfac_state=state.kfac_state.replace(
-                comm_err=None))
-        if 'yes' not in seen_inverse:
-            # one-time: a restored checkpoint may already carry a
-            # decomposition (utils/checkpoint.py include_kfac=True)
-            seen_inverse['yes'] = bool(
-                state.kfac_state is not None
-                and any(bool(jnp.any(x != 0))
-                        for x in jax.tree.leaves(state.kfac_state.decomp)))
-        st = False
-        pf = False
-        if precond is None:
-            uf = ui = False
-            ub, warm = True, False
-        else:
-            # hook_enabled=False freezes factor capture/updates (reference
-            # set_hook_enabled, kfac_preconditioner_base.py:117-130); the
-            # existing decomposition keeps preconditioning. Before ANY
-            # decomposition exists the gradients pass through unmodified
-            # while factor statistics still accumulate on schedule (the
-            # reference would have no factors to read at all here).
-            enabled = getattr(precond, 'hook_enabled', True)
-            uf = enabled and precond.should_update_factors(step)
-            st = (getattr(precond, 'stagger', False) and enabled
-                  and seen_inverse['yes'])
-            if st:
-                # staggered refresh: after the first (full) decomposition
-                # EVERY step decomposes one cost-balanced cohort — the
-                # cohort index is traced, so this is ONE compiled variant
-                # per uf setting, not one per cohort
-                ui, ub, warm = False, True, False
+        # the step's host spans go into the profiler's own trace
+        # (obs.trace.annotation), one per part of this function, so every
+        # gap between device operations can be put to what the host was
+        # doing in it; with no profiler session each is an inactive check
+        with contextlib.ExitStack() as spans:
+            spans.enter_context(annotation('kfac.step'))
+            with annotation('kfac.step.read_step'):
+                step = int(state.step)
+            with annotation('kfac.step.hooks'):
+                # straggler governor: measure the inter-arrival of host
+                # steps (tick BEFORE the fault hooks so an injected slow
+                # step lands in the NEXT tick's interval, like any real
+                # stall would)
+                if straggler is not None:
+                    straggler.tick(step)
+                if autotune is not None:
+                    # the interval that just ended covered the PREVIOUS
+                    # dispatch's phase set — attribute it there, like the
+                    # PhaseTimers wall-time bucketing
+                    autotune.tick(step, step_fn.last_phases)
+                if heartbeat is not None:
+                    heartbeat.tick(step)
+                # host-side chaos drills (all no-ops unless
+                # env-configured): SIGTERM (PreemptionGuard), crash
+                # (supervisor restart), hang (step watchdog), slow
+                # (straggler governor)
+                faults.maybe_sigterm(fault_cfg, step)
+                faults.maybe_crash(fault_cfg, step)
+                faults.maybe_hang(fault_cfg, step)
+                faults.maybe_slow(fault_cfg, step,
+                                  sleep=(straggler.sleep
+                                         if straggler is not None
+                                         else None))
+                if (precond is not None
+                        and getattr(precond, 'pending_replan', None)):
+                    # a queued live replan (the arbiter's applied
+                    # comm_mode switch, or a direct request_replan):
+                    # apply it HERE — the between-steps boundary where no
+                    # traced program is running — before anything below
+                    # reads the preconditioner's config or retraces
+                    # against the (already-invalidated) variant cache. A
+                    # pure comm-mode switch carries the state verbatim; a
+                    # layout change transports it host-side.
+                    state = state.replace(
+                        kfac_state=precond.apply_pending_replan(
+                            state.kfac_state))
+                if health_cfg is not None and state.health is None:
+                    # one-time upgrade of a pre-health TrainState (old
+                    # checkpoint or a hand-built state): done host-side
+                    # BEFORE the jitted call so every variant only ever
+                    # sees one state structure
+                    state = state.replace(
+                        health=health_lib.HealthState.init())
+                if (precond is not None and state.kfac_state is not None
+                        and getattr(precond, '_tracks_comm_err', False)
+                        and state.kfac_state.comm_err is None):
+                    # same one-time upgrade for the EF residual: a
+                    # checkpoint taken before comm_precision was enabled
+                    # (or at fp32) carries no residual — seed zeros
+                    # host-side so every variant sees one state structure
+                    state = state.replace(
+                        kfac_state=state.kfac_state.replace(
+                            comm_err=precond._zero_comm_err()))
+                if (precond is not None and state.kfac_state is not None
+                        and not getattr(precond, '_tracks_comm_err', False)
+                        and state.kfac_state.comm_err is not None):
+                    # the DOWNGRADE direction of the same upgrade: the
+                    # autotuner (or a restart at fp32) switched the wire
+                    # dtype off a lossy mode mid-run — drop the EF
+                    # residual host-side so every variant sees one state
+                    # structure; the residual is a correction term, never
+                    # load-bearing (discarding it costs one reduce's
+                    # worth of feedback, the same contract the
+                    # lossy-checkpoint-into-fp32 restore already accepts)
+                    state = state.replace(
+                        kfac_state=state.kfac_state.replace(comm_err=None))
+                if 'yes' not in seen_inverse:
+                    # one-time: a restored checkpoint may already carry a
+                    # decomposition (utils/checkpoint.py include_kfac=True)
+                    seen_inverse['yes'] = bool(
+                        state.kfac_state is not None
+                        and any(bool(jnp.any(x != 0)) for x in
+                                jax.tree.leaves(state.kfac_state.decomp)))
+            with annotation('kfac.step.select'):
+                st = False
+                pf = False
+                if precond is None:
+                    uf = ui = False
+                    ub, warm = True, False
+                else:
+                    # hook_enabled=False freezes factor capture/updates
+                    # (reference set_hook_enabled,
+                    # kfac_preconditioner_base.py:117-130); the existing
+                    # decomposition keeps preconditioning. Before ANY
+                    # decomposition exists the gradients pass through
+                    # unmodified while factor statistics still accumulate
+                    # on schedule (the reference would have no factors to
+                    # read at all here).
+                    enabled = getattr(precond, 'hook_enabled', True)
+                    uf = enabled and precond.should_update_factors(step)
+                    st = (getattr(precond, 'stagger', False) and enabled
+                          and seen_inverse['yes'])
+                    if st:
+                        # staggered refresh: after the first (full)
+                        # decomposition EVERY step decomposes one
+                        # cost-balanced cohort — the cohort index is
+                        # traced, so this is ONE compiled variant per uf
+                        # setting, not one per cohort
+                        ui, ub, warm = False, True, False
+                    else:
+                        ui = enabled and precond.should_update_inverse(step)
+                        # eigenvalue-only refresh needs a basis to
+                        # refresh: the first inverse update of this run
+                        # is always a full decomposition (no last_full
+                        # yet — covers fresh starts, resumes, and the
+                        # stagger cold start alike)
+                        ub = (not seen_inverse['yes']
+                              or precond.should_update_basis(
+                                  step, seen_inverse.get('last_full')))
+                        warm = _warm_basis_gate(precond, seen_inverse,
+                                                step, ui, ub)
+                        # cross-step prefetch: publish this inverse
+                        # update's gathered table for the NEXT step —
+                        # only once a prior table exists (the first
+                        # decomposition must be consumed same-step or the
+                        # pred would read zeros)
+                        pf = (getattr(precond, 'comm_prefetch', False)
+                              and ui and seen_inverse['yes'])
+                        seen_inverse['yes'] = seen_inverse['yes'] or ui
+                        if not ui:
+                            # unused w/o an inverse update
+                            ub, warm = True, False
+                        if not ub:
+                            # refresh path has no eigh to warm
+                            warm = False
+                key = (uf, ui, ub, warm, pf)
+                build = dict(update_factors=uf, update_inverse=ui,
+                             update_basis=ub, warm_basis=warm, prefetch=pf)
+                if st:
+                    # the cohort layout derives from kfac_update_freq: a
+                    # scheduler/straggler rescale rebases it here, and
+                    # the cohort count rides in the cache key so the
+                    # rebuilt (static) tables get a fresh trace — same
+                    # freq back again reuses the old one
+                    layout = precond.rebase_cohorts()
+                    key = (uf, 'stagger', layout.num_cohorts)
+                    build = dict(update_factors=uf, update_inverse=False,
+                                 stagger_update=True)
+                if precond is not None and not seen_inverse['yes']:
+                    key = (uf, False, 'factors_only')
+                    build = dict(update_factors=uf, update_inverse=False,
+                                 factors_only=True)
+                # host-visible phase set of THIS dispatch (consumed by
+                # utils.metrics.PhaseTimers for the kfac_phase_ms epoch
+                # suffix)
+                step_fn.last_phases = variant_phases(**build)
+                hyper = KFACHyperParams(
+                    lr=jnp.float32(lr if lr is not None
+                                   else getattr(precond, 'lr', 0.0)),
+                    damping=jnp.float32(
+                        damping if damping is not None
+                        else getattr(precond, 'damping', 0.0)))
+            if key not in variants:
+                # a cache miss: jit builds (traces, compiles) at the
+                # first call, so the build span stays open over it
+                spans.enter_context(annotation(
+                    'kfac.step.build/' + variant_name(**build)))
+                variants[key] = make_variant(**build)
+            # the phase set goes in the span's NAME: a trace reader
+            # keeps a host event's name, not its args ('/' before it,
+            # not ':': the profiler's converter takes 'name:word' for a
+            # TensorFlow 'op:type' and keeps 'word' alone)
+            dispatch = 'kfac.step.dispatch/' + (
+                '+'.join(step_fn.last_phases)
+                or ('sgd' if precond is None else 'none'))
+            if tracer is None:
+                spans.enter_context(annotation(dispatch))
             else:
-                ui = enabled and precond.should_update_inverse(step)
-                # eigenvalue-only refresh needs a basis to refresh: the
-                # first inverse update of this run is always a full
-                # decomposition (no last_full yet — covers fresh starts,
-                # resumes, and the stagger cold start alike)
-                ub = (not seen_inverse['yes']
-                      or precond.should_update_basis(
-                          step, seen_inverse.get('last_full')))
-                warm = _warm_basis_gate(precond, seen_inverse, step, ui, ub)
-                # cross-step prefetch: publish this inverse update's
-                # gathered table for the NEXT step — only once a prior
-                # table exists (the first decomposition must be consumed
-                # same-step or the pred would read zeros)
-                pf = (getattr(precond, 'comm_prefetch', False) and ui
-                      and seen_inverse['yes'])
-                seen_inverse['yes'] = seen_inverse['yes'] or ui
-                if not ui:
-                    ub, warm = True, False  # unused w/o an inverse update
-                if not ub:
-                    warm = False        # refresh path has no eigh to warm
-        key = (uf, ui, ub, warm, pf)
-        if st:
-            # the cohort layout derives from kfac_update_freq: a
-            # scheduler/straggler rescale rebases it here, and the cohort
-            # count rides in the cache key so the rebuilt (static) tables
-            # get a fresh trace — same freq back again reuses the old one
-            layout = precond.rebase_cohorts()
-            key = (uf, 'stagger', layout.num_cohorts)
-            if key not in variants:
-                variants[key] = make_variant(uf, False, stagger_update=True)
-        if precond is not None and not seen_inverse['yes']:
-            key = (uf, False, 'factors_only')
-            if key not in variants:
-                variants[key] = make_variant(uf, False, factors_only=True)
-        if key not in variants:
-            variants[key] = make_variant(uf, ui, ub, warm, prefetch=pf)
-        # host-visible phase set of THIS dispatch (consumed by
-        # utils.metrics.PhaseTimers for the kfac_phase_ms epoch suffix)
-        if precond is None:
-            step_fn.last_phases = ()
-        elif not seen_inverse['yes']:
-            step_fn.last_phases = ('stats',) if uf else ()
-        else:
-            ph = ['pred']
-            if uf:
-                ph.append('stats')
-            if ui or st:
-                ph.append('decomp')
-                if precond.comm_mode == 'inverse':
-                    ph.append('gather')
-            step_fn.last_phases = tuple(ph)
-        hyper = KFACHyperParams(
-            lr=jnp.float32(lr if lr is not None
-                           else getattr(precond, 'lr', 0.0)),
-            damping=jnp.float32(damping if damping is not None
-                                else getattr(precond, 'damping', 0.0)))
-        # does THIS dispatch publish a gathered table for the NEXT step?
-        # (stagger's double-buffered cohort gather, or comm_prefetch on a
-        # full inverse update) — recorded as overlapping schedule spans
-        # so a trace shows the CommunicateInverse gather riding under the
-        # pred einsums with no same-step consumer
-        prefetched_gather = (pf or st) and (
-            precond is not None and precond.comm_mode == 'inverse'
-            and 'gather' in step_fn.last_phases)
-        try:
-            if tracer is not None:
-                from kfac_pytorch_tpu.obs.trace import taxonomy_phases
-                with tracer.span('kfac.dispatch', cat='kfac.step',
-                                 step=step,
-                                 phases=taxonomy_phases(
-                                     step_fn.last_phases)):
-                    if prefetched_gather:
-                        cohort = (step % layout.num_cohorts if st
-                                  else None)
-                        with tracer.span(
-                                'kfac.Precondition', cat='kfac.sched',
-                                step=step, table='stored'), \
-                             tracer.span(
-                                'kfac.CommunicateInverse.prefetch',
-                                cat='kfac.sched', step=step,
-                                cohort=cohort,
-                                consumer_step=step + 1):
-                            return variants[key](state, batch, hyper)
-                    return variants[key](state, batch, hyper)
-            return variants[key](state, batch, hyper)
-        except Exception as e:
-            # per-call block_impl='pallas_interpret' cannot be seen by the
-            # check_vma auto-detection (it only reads KFAC_ATTN_IMPL), and
-            # the resulting shard_map trace error is cryptic — point at
-            # the escape hatch
-            msg = str(e)
-            if check_vma is None and ('vma' in msg or 'Varying' in msg
-                                      or 'varying' in msg):
-                raise RuntimeError(
-                    msg + '\n[kfac_pytorch_tpu] If this model routes '
-                    'attention through the Pallas interpreter per-call '
-                    "(block_impl='pallas_interpret') rather than via "
-                    'KFAC_ATTN_IMPL, pass check_vma=False to '
-                    'build_train_step.') from e
-            raise
+                spans.enter_context(tracer.span(
+                    'kfac.dispatch', cat='kfac.step', annotate=dispatch,
+                    step=step,
+                    phases=obs_trace.taxonomy_phases(step_fn.last_phases)))
+                # does THIS dispatch publish a gathered table for the
+                # NEXT step? (stagger's double-buffered cohort gather, or
+                # comm_prefetch on a full inverse update) — recorded as
+                # overlapping schedule spans so a trace shows the
+                # CommunicateInverse gather riding under the pred einsums
+                # with no same-step consumer. A drawing of the schedule,
+                # not an interval of work: the recorder's alone
+                if (pf or st) and 'gather' in step_fn.last_phases:
+                    spans.enter_context(tracer.span(
+                        'kfac.Precondition', cat='kfac.sched',
+                        annotate=False, step=step, table='stored'))
+                    spans.enter_context(tracer.span(
+                        'kfac.CommunicateInverse.prefetch',
+                        cat='kfac.sched', annotate=False, step=step,
+                        cohort=(step % layout.num_cohorts if st
+                                else None),
+                        consumer_step=step + 1))
+            try:
+                return variants[key](state, batch, hyper)
+            except Exception as e:
+                # per-call block_impl='pallas_interpret' cannot be seen
+                # by the check_vma auto-detection (it only reads
+                # KFAC_ATTN_IMPL), and the resulting shard_map trace
+                # error is cryptic — point at the escape hatch
+                msg = str(e)
+                if check_vma is None and ('vma' in msg or 'Varying' in msg
+                                          or 'varying' in msg):
+                    raise RuntimeError(
+                        msg + '\n[kfac_pytorch_tpu] If this model routes '
+                        'attention through the Pallas interpreter per-call '
+                        "(block_impl='pallas_interpret') rather than via "
+                        'KFAC_ATTN_IMPL, pass check_vma=False to '
+                        'build_train_step.') from e
+                raise
 
     # Warm-tracking host state, exposed for checkpoint/resume: three
     # scalars ('yes', 'last_full', 'warm_streak') that are per-process

@@ -4,7 +4,10 @@ The TPU compiler is installed beside the CPU backend and compiles for a
 chip that is DESCRIBED, not attached: each case lowers one Pallas kernel
 at a ResNet-50 / BERT-base / long-context shape for one device of a
 ``v5e:2x2`` topology and asserts the kernel is in the executable
-(``tpu_custom_call``). Interpret-mode tests cannot see what this sees —
+(``tpu_custom_call``) under the name its ``pallas_call`` gives it (what a
+profiler trace of the chip shows, and what the benchmark's
+``kernel_ms_per_step`` looks for). Interpret-mode tests cannot see what
+this sees —
 a strided slice Mosaic refuses, a tile over VMEM. Nothing runs; a
 compile that passes is not a chip run (``chip_smoke.py`` is).
 
@@ -89,11 +92,24 @@ CAPTURE_CASES = [
 ]
 
 
-@pytest.mark.parametrize('fn,shapes',
-                         [c[1:] for c in CAPTURE_CASES],
+def _kernel_of(case_id):
+    """The ``pallas_call`` name a capture case compiles to."""
+    if case_id.startswith('a_conv'):
+        return 'kfac_conv_a'
+    if case_id == 'ef_quantize':
+        return 'kfac_ef_quantize'
+    return 'kfac_stat_rows'     # dense A / G and conv G: _stat_rows
+
+
+@pytest.mark.parametrize('fn,shapes,kernel',
+                         [c[1:] + (_kernel_of(c[0]),)
+                          for c in CAPTURE_CASES],
                          ids=[c[0] for c in CAPTURE_CASES])
-def test_capture_kernel_compiles_for_v5e(one_chip, fn, shapes):
-    assert 'tpu_custom_call' in _compile(one_chip, fn, *shapes)
+def test_capture_kernel_compiles_for_v5e(one_chip, fn, shapes, kernel):
+    text = _compile(one_chip, fn, *shapes)
+    assert 'tpu_custom_call' in text
+    # the instruction and its op_name path both carry the kernel's name
+    assert f'%{kernel}' in text and f'/{kernel}/pallas_call' in text
 
 
 def test_conv1_is_routed_to_xla_and_says_so(one_chip, capsys):
@@ -129,5 +145,9 @@ def test_flash_block_attn_fwd_bwd_compiles_for_v5e(one_chip, length,
     text = _compile(one_chip,
                     jax.value_and_grad(loss, argnums=(0, 1, 2)),
                     qkv, qkv, qkv, ((bh, length), F32))
-    # forward + dq + dkv kernels
+    # forward + dq + dkv kernels, each under its name (autodiff wraps
+    # it: jvp(kfac_flash_fwd), transpose(jvp(kfac_flash_bwd_dq)))
     assert text.count('tpu_custom_call') >= 3
+    for kernel in ('kfac_flash_fwd', 'kfac_flash_bwd_dq',
+                   'kfac_flash_bwd_dkv'):
+        assert f'({kernel})' in text, kernel
