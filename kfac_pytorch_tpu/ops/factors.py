@@ -5,7 +5,8 @@ kfac/utils.py:33-140) but laid out for TPU: NHWC activations, HWIO conv
 kernels, im2col via ``lax.conv_general_dilated_patches`` (one fused XLA op
 instead of unfold+transpose chains), and all covariance GEMMs emitted as
 single ``dot_general`` calls with fp32 accumulation so XLA tiles them onto
-the MXU.
+the MXU. Conv factor A never reorders or rescales its patch tensor: it is
+built once and contracted with itself (:func:`compute_a_conv`).
 
 Conventions
 -----------
@@ -95,22 +96,126 @@ def compute_a_dense(a, use_bias):
     return _stat_gemm(a, n)
 
 
+#: Conv A builds its patch tensor one of two ways (``_conv_a_form``). Below
+#: this many input channels: the raw channel-major output of
+#: ``conv_general_dilated_patches``; from it up: ``kh*kw`` shifted strided
+#: slices concatenated on the channel axis. Set from two measurements
+#: (TPU v5e, bf16, batch 128, 3x3 at 112^2, ms, raw / slices): C 24
+#: 3.47 / 4.19, C 32 6.36 / 5.42 (PERF.md, PR 26). Slices of a narrow
+#: input fill few of a vector's lanes; the raw form pays a convolution
+#: that grows with C.
+_RAW_PATCH_BELOW_CHANNELS = 32
+
+
+def _self_gram(x, scale):
+    """``scale * x^T x`` over every leading axis, accumulated in fp32 — the
+    conv statistics' contraction: one tensor with itself, the scale applied
+    to the ``f x f`` product instead of to a copy of ``x``."""
+    lead = tuple(range(x.ndim - 1))
+    gram = lax.dot_general(x, x, ((lead, lead), ((), ())),
+                           preferred_element_type=_FACTOR_DTYPE)
+    return gram.astype(_FACTOR_DTYPE) * jnp.asarray(scale, _FACTOR_DTYPE)
+
+
+def explicit_pads(padding, in_hw, kernel_size, strides):
+    """``((lo, hi), (lo, hi))`` zero padding for each padding form
+    ``extract_patches`` accepts, as ``conv_general_dilated_patches`` reads
+    it (shared with the Pallas conv A kernel)."""
+    if isinstance(padding, str):
+        return tuple(lax.padtype_to_pads(in_hw, kernel_size, strides,
+                                         padding))
+    if len(padding) == 2 and not isinstance(padding[0], (tuple, list)):
+        return ((padding[0], padding[0]), (padding[1], padding[1]))
+    return tuple(tuple(p) for p in padding)
+
+
+def _raw_patches(a, kernel_size, strides, pads):
+    """``[N, OH, OW, C*kh*kw]`` patches, features channel-major
+    ``(c, kh, kw)``, as ``conv_general_dilated_patches`` emits them."""
+    return lax.conv_general_dilated_patches(
+        a, filter_shape=tuple(kernel_size), window_strides=tuple(strides),
+        padding=list(pads), dimension_numbers=('NHWC', 'HWIO', 'NHWC'))
+
+
+def _tap_patches(a, kernel_size, strides, pads):
+    """``[N, OH, OW, kh*kw*C]`` patches in ``(kh, kw, c)`` order: one
+    strided slice of the once-padded activation per kernel tap. A 1x1
+    kernel's patches are the (strided) activation itself."""
+    (kh, kw), (sh, sw) = kernel_size, strides
+    if any(p != (0, 0) for p in pads):
+        a = lax.pad(a, jnp.zeros((), a.dtype),
+                    ((0, 0, 0), (*pads[0], 0), (*pads[1], 0), (0, 0, 0)))
+    n, h, w, c = a.shape
+    oh, ow = (h - kh) // sh + 1, (w - kw) // sw + 1
+    if (kh, kw, oh, ow) == (1, 1, h, w):
+        return a
+    taps = [lax.slice(a, (0, i, j, 0),
+                      (n, i + (oh - 1) * sh + 1, j + (ow - 1) * sw + 1, c),
+                      (1, sh, sw, 1))
+            for i in range(kh) for j in range(kw)]
+    return taps[0] if len(taps) == 1 else jnp.concatenate(taps, axis=-1)
+
+
+def _conv_a_form(kernel_size, channels):
+    """How conv A builds its patch tensor for a layer of this shape:
+    ``'1x1'`` (no patches), ``'raw'`` or ``'taps'``."""
+    if tuple(kernel_size) == (1, 1):
+        return '1x1'
+    return 'raw' if channels < _RAW_PATCH_BELOW_CHANNELS else 'taps'
+
+
+def _conv_a(form, a, kernel_size, strides, padding, use_bias):
+    """:func:`compute_a_conv` with the patch form given: ``'raw'``, or
+    ``'taps'`` (of which ``'1x1'`` is the one-tap case)."""
+    n, c = a.shape[0], a.shape[-1]
+    taps = kernel_size[0] * kernel_size[1]
+    pads = explicit_pads(padding, a.shape[1:3], kernel_size, strides)
+    build = _raw_patches if form == 'raw' else _tap_patches
+    with jax.named_scope(f'conv_a.{form}'):
+        patches = build(a, kernel_size, strides, pads)
+        spatial = patches.shape[1] * patches.shape[2]
+        scale = 1.0 / (spatial * spatial * n)
+        cov = _self_gram(patches, scale)
+        if use_bias:
+            # the homogeneous coordinate: the patch rows' sum, contracted
+            # like the product above (no ones column on the patch tensor)
+            lead = (0, 1, 2)
+            col = lax.dot_general(
+                patches, jnp.ones(patches.shape[:3], patches.dtype),
+                ((lead, lead), ((), ())),
+                preferred_element_type=_FACTOR_DTYPE).astype(_FACTOR_DTYPE)
+            col = col * jnp.asarray(scale, _FACTOR_DTYPE)
+        if form == 'raw' and taps > 1:
+            # (c, t) -> (t, c) on the product's rows and columns; the patch
+            # tensor itself is never reordered
+            cov = cov.reshape(c, taps, c, taps).transpose(1, 0, 3, 2)
+            cov = cov.reshape(c * taps, c * taps)
+            if use_bias:
+                col = col.reshape(c, taps).T.reshape(-1)
+        if use_bias:
+            corner = jnp.full((1,), 1.0 / spatial, _FACTOR_DTYPE)
+            cov = jnp.concatenate([
+                jnp.concatenate([cov, col[:, None]], axis=1),
+                jnp.concatenate([col, corner])[None, :]], axis=0)
+        return cov
+
+
 def compute_a_conv(a, kernel_size, strides, padding, use_bias):
     """Factor A for a conv layer: ``[kh*kw*C(+1), kh*kw*C(+1)]``.
 
-    im2col rows are spatially normalized (each row divided by the number of
-    spatial positions) before the covariance GEMM; the bias ones column is
-    appended before that normalization. Parity: ``ComputeA.conv2d``
-    (reference: kfac/utils.py:86-94).
+    The covariance of the spatially normalized im2col rows (each row divided
+    by the number of spatial positions, the bias ones column appended before
+    that normalization). Parity: ``ComputeA.conv2d`` (reference:
+    kfac/utils.py:86-94).
+
+    One pass: the patch tensor is built once, in the activation's dtype,
+    and contracted with itself in fp32; ``1 / (spatial^2 N)`` scales the
+    product. How it is built follows from the layer's shape
+    (:func:`_conv_a_form`); the feature order is ``(kh, kw, c_in)`` either
+    way. The form taken shows in a trace as the scope ``conv_a.<form>``.
     """
-    n = a.shape[0]
-    patches = extract_patches(a, kernel_size, strides, padding)
-    spatial = patches.shape[1] * patches.shape[2]
-    rows = patches.reshape(-1, patches.shape[-1])
-    if use_bias:
-        rows = _append_ones_column(rows)
-    rows = rows / spatial
-    return _stat_gemm(rows, n)
+    form = _conv_a_form(kernel_size, a.shape[-1])
+    return _conv_a(form, a, kernel_size, strides, padding, use_bias)
 
 
 def compute_g_dense(g, batch_averaged=True):
@@ -134,14 +239,14 @@ def compute_g_conv(g, batch_averaged=True):
     Spatial positions are treated as extra samples, scaled by the spatial
     size to undo the conv-as-sum normalization. Parity: ``ComputeG.conv2d``
     (reference: kfac/utils.py:118-129).
+
+    ``g`` is contracted with itself as it stands; the row scalings (``N``
+    when batch-averaged, ``spatial``) and the ``1 / (N spatial)`` of the
+    covariance meet in one factor on the product.
     """
     n = g.shape[0]
     spatial = g.shape[1] * g.shape[2]
-    rows = g.reshape(-1, g.shape[-1])
-    if batch_averaged:
-        rows = rows * n
-    rows = rows * spatial
-    return _stat_gemm(rows, rows.shape[0])
+    return _self_gram(g, n * spatial if batch_averaged else spatial / n)
 
 
 def layer_rows_dense(a, g, use_bias, batch_averaged=True):

@@ -41,6 +41,10 @@ summation order). The EMA epilogue is the exception: its final
 do not stop LLVM contraction on CPU), so it is pinned as algebraically
 identical, deterministic across steps, and within one fp32 rounding of
 the unfused program — while the statistic feeding it stays bitwise.
+The conv A and conv G kernels scale their rows as ``factors.compute_a_conv``
+and ``compute_g_conv`` did before those became one contraction with the
+scale on the product (PR 26): their bits are pinned to that row-scaled
+form (kept as tests/factor_oracles.py), their value to the current one.
 
 Implementation selection follows the repo convention ('xla' | 'pallas' |
 'auto'): :func:`interpret_default` returns True off-TPU so the same
@@ -355,29 +359,6 @@ def _stat_rows(rows, denom, *, mults=(), append_ones=False, ema=None,
 # conv A: patch extraction fused into the covariance GEMM
 # ---------------------------------------------------------------------------
 
-def _canon_padding(h, w, kernel_size, strides, padding):
-    """((top, bottom), (left, right)) zero padding with the exact
-    semantics ``lax.conv_general_dilated_patches`` gives
-    ``factors.extract_patches`` for each accepted padding form."""
-    kh, kw = kernel_size
-    sh, sw = strides
-    if isinstance(padding, str):
-        p = padding.upper()
-        if p == 'VALID':
-            return (0, 0), (0, 0)
-        if p == 'SAME':
-            out = []
-            for size, k, st in ((h, kh, sh), (w, kw, sw)):
-                o = -(-size // st)
-                total = max((o - 1) * st + k - size, 0)
-                out.append((total // 2, total - total // 2))
-            return tuple(out[0]), tuple(out[1])
-        raise ValueError(f'unknown padding string {padding!r}')
-    if len(padding) == 2 and not isinstance(padding[0], (tuple, list)):
-        return ((padding[0], padding[0]), (padding[1], padding[1]))
-    return tuple(tuple(p) for p in padding)
-
-
 def _conv_a_kernel(*refs, taps, oh, ow, n, spatial, append_ones, nsteps,
                    ema_alpha, has_ema, strict):
     if has_ema:
@@ -494,8 +475,8 @@ def compute_a_conv(a, kernel_size, strides, padding, use_bias, *,
         return _apply_ema(
             _ref.compute_a_conv(a, kernel_size, strides, padding,
                                 use_bias), ema)
-    (pt, pb), (pl_, pr) = _canon_padding(h, w, kernel_size, strides,
-                                         padding)
+    (pt, pb), (pl_, pr) = _ref.explicit_pads(padding, (h, w), kernel_size,
+                                             strides)
     hp, wp = h + pt + pb, w + pl_ + pr
     oh = (hp - kh) // sh + 1
     ow = (wp - kw) // sw + 1

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from kfac_pytorch_tpu.ops import factors
 from kfac_pytorch_tpu.ops import pallas_attention, pallas_capture as pc
 
 
@@ -125,6 +126,43 @@ def test_conv1_is_routed_to_xla_and_says_so(one_chip, capsys):
         ((N, 224, 224, 3), BF16))
     assert 'tpu_custom_call' not in text
     assert 'stays on the XLA path' in capsys.readouterr().err
+
+
+# (id, activation shape at the benchmark's batch, kernel, strides, padding,
+#  the form the shape rule takes, patch tensors the compiler may plan)
+XLA_CONV_A_CASES = [
+    ('conv1-7x7s2-C3', (128, 224, 224, 3), (7, 7), (2, 2),
+     ((3, 3), (3, 3)), 'raw', 1),
+    ('layer1-3x3s1-C64', (128, 56, 56, 64), (3, 3), (1, 1),
+     ((1, 1), (1, 1)), 'taps', 1),
+    ('layer2-3x3s2-C128', (128, 56, 56, 128), (3, 3), (2, 2),
+     ((1, 1), (1, 1)), 'taps', 1),
+    ('layer2-1x1s2-C256-downsample', (128, 56, 56, 256), (1, 1), (2, 2),
+     ((0, 0), (0, 0)), '1x1', 1),
+    ('layer1-1x1s1-C256', (128, 56, 56, 256), (1, 1), (1, 1),
+     ((0, 0), (0, 0)), '1x1', 0),
+]
+
+
+@pytest.mark.parametrize('shape,kernel,strides,padding,form,patch_tensors',
+                         [c[1:] for c in XLA_CONV_A_CASES],
+                         ids=[c[0] for c in XLA_CONV_A_CASES])
+def test_xla_conv_a_plans_one_patch_tensor_on_v5e(
+        one_chip, shape, kernel, strides, padding, form, patch_tensors):
+    """The statistic ResNet-50's cells run (no kernel: ``capture_impl`` is
+    None): the chip's compiler keeps at most one patch tensor of
+    temporaries at the benchmark's batch. The row-scaled form it replaced
+    planned four to five (conv1 2,476 MB against 488 MB; PERF.md, PR 26)."""
+    assert factors._conv_a_form(kernel, shape[-1]) == form
+    a = jax.ShapeDtypeStruct(shape, BF16, sharding=one_chip)
+    compiled = jax.jit(lambda x: factors.compute_a_conv(
+        x, kernel, strides, padding, False)).lower(a).compile()
+    oh = (shape[1] + sum(padding[0]) - kernel[0]) // strides[0] + 1
+    ow = (shape[2] + sum(padding[1]) - kernel[1]) // strides[1] + 1
+    patch_bytes = 2 * shape[0] * oh * ow * kernel[0] * kernel[1] * shape[3]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= 1.1 * patch_tensors * patch_bytes + (1 << 20), (
+        temp / patch_bytes)
 
 
 @pytest.mark.parametrize('length', [2048, 32768])

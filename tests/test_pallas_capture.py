@@ -9,6 +9,9 @@ interpreter on the CPU tier:
    mode pins hold XLA's jit rewrites to the eager rounding sequence.
    Multi-tile runs (KFAC_CAPTURE_TR) stay value-equal; the VMEM cap
    (KFAC_CAPTURE_MAX_F) falls back to the reference exactly.
+   The conv kernels' bit reference is the row-scaled form they implement
+   (tests/factor_oracles.py: ops/factors.py before PR 26), their value
+   reference the one-pass form of today's ops/factors.py.
 2. The EMA epilogue is algebraically identical, DETERMINISTIC across
    repeated invocations, and within one fp32 rounding of the unfused
    two-pass program (its final combine FMA-contracts under jit — the
@@ -39,6 +42,8 @@ import kfac_pytorch_tpu as kfac
 from kfac_pytorch_tpu import autotune, capture, training
 from kfac_pytorch_tpu import nn as knn
 from kfac_pytorch_tpu.ops import factors, pallas_capture
+
+from tests import factor_oracles as oracle
 
 pytestmark = pytest.mark.core
 
@@ -82,10 +87,13 @@ def test_g_dense_bitwise(batch_averaged):
 @pytest.mark.parametrize('batch_averaged', [True, False])
 def test_g_conv_bitwise(batch_averaged):
     g = jnp.asarray(_rng(4).randn(4, 5, 5, 7), jnp.float32)
-    ref = factors.compute_g_conv(g, batch_averaged)
+    ref = oracle.compute_g_conv(g, batch_averaged)
     got = pallas_capture.compute_g_conv(g, batch_averaged,
                                         interpret=True)
     assert np.array_equal(np.asarray(got), np.asarray(ref))
+    now = factors.compute_g_conv(g, batch_averaged)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(now),
+                               rtol=1e-5, atol=1e-7)
 
 
 @pytest.mark.parametrize('use_bias', [True, False])
@@ -94,17 +102,23 @@ def test_g_conv_bitwise(batch_averaged):
                                      ((1, 2), (0, 1))])
 def test_a_conv_bitwise(use_bias, strides, padding):
     a = jnp.asarray(_rng(5).randn(4, 9, 9, 3), jnp.float32)
-    ref = factors.compute_a_conv(a, (3, 3), strides, padding, use_bias)
+    # the kernel scales rows as the row-scaled form does (the package's
+    # form before PR 26, kept as the oracle): its bits, and the one-pass
+    # form's value
+    ref = oracle.compute_a_conv(a, (3, 3), strides, padding, use_bias)
     got = pallas_capture.compute_a_conv(a, (3, 3), strides, padding,
                                         use_bias, interpret=True)
     assert got.shape == ref.shape
     assert np.array_equal(np.asarray(got), np.asarray(ref))
+    now = factors.compute_a_conv(a, (3, 3), strides, padding, use_bias)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(now),
+                               rtol=1e-5, atol=1e-7)
 
 
 def test_a_conv_rect_kernel_bitwise():
     # non-square taps exercise the (ki, kj) slice loop asymmetrically
     a = jnp.asarray(_rng(6).randn(3, 8, 10, 2), jnp.float32)
-    ref = factors.compute_a_conv(a, (1, 3), (1, 2), 'SAME', True)
+    ref = oracle.compute_a_conv(a, (1, 3), (1, 2), 'SAME', True)
     got = pallas_capture.compute_a_conv(a, (1, 3), (1, 2), 'SAME', True,
                                         interpret=True)
     assert np.array_equal(np.asarray(got), np.asarray(ref))
@@ -165,7 +179,7 @@ def test_ema_epilogue_within_one_rounding(kind):
         f = 11
     elif kind == 'a_conv':
         x = jnp.asarray(r.randn(3, 7, 7, 2), jnp.float32)
-        ref_stat = lambda: factors.compute_a_conv(
+        ref_stat = lambda: oracle.compute_a_conv(
             x, (3, 3), (1, 1), 'SAME', True)
         fused = lambda ema: pallas_capture.compute_a_conv(
             x, (3, 3), (1, 1), 'SAME', True, ema=ema, interpret=True)
@@ -178,7 +192,7 @@ def test_ema_epilogue_within_one_rounding(kind):
         f = 6
     else:
         x = jnp.asarray(r.randn(3, 5, 5, 4), jnp.float32)
-        ref_stat = lambda: factors.compute_g_conv(x, True)
+        ref_stat = lambda: oracle.compute_g_conv(x, True)
         fused = lambda ema: pallas_capture.compute_g_conv(
             x, True, ema=ema, interpret=True)
         f = 4
@@ -465,5 +479,5 @@ def test_conv_a_strided_phases_bitwise(strides, kernel, padding):
     a = jnp.asarray(_rng(21).randn(3, 11, 9, 5), jnp.float32)
     got = pallas_capture.compute_a_conv(a, kernel, strides, padding, True,
                                         interpret=True)
-    want = factors.compute_a_conv(a, kernel, strides, padding, True)
+    want = oracle.compute_a_conv(a, kernel, strides, padding, True)
     assert np.array_equal(np.asarray(got), np.asarray(want))

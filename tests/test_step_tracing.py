@@ -8,6 +8,7 @@ recording stand-in in ``jax.profiler.TraceAnnotation``'s place.
 
 import re
 
+import flax.linen as linen
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -16,6 +17,7 @@ import pytest
 from jax.sharding import Mesh
 
 import kfac_pytorch_tpu as kfac
+from kfac_pytorch_tpu import nn as knn
 from kfac_pytorch_tpu import training
 from kfac_pytorch_tpu.obs import trace
 
@@ -53,7 +55,16 @@ def _ce(outputs, batch):
         outputs, batch['label']).mean()
 
 
-def _trainer(with_kfac=True, tracer=None, mesh=None, **kfac_kw):
+class TinyMLP(linen.Module):
+    """Dense capture only, as BERT's: no conv layer."""
+
+    @linen.compact
+    def __call__(self, x, train=True):
+        x = linen.relu(knn.Dense(16, name='d1')(x.reshape(x.shape[0], -1)))
+        return knn.Dense(10, name='d2')(x)
+
+
+def _trainer(with_kfac=True, tracer=None, mesh=None, model=None, **kfac_kw):
     batch = _batch(8 if mesh is not None else 4)
     axis = 'batch' if mesh is not None else None
     precond = None
@@ -64,7 +75,7 @@ def _trainer(with_kfac=True, tracer=None, mesh=None, **kfac_kw):
                   axis_name=axis)
         kw.update(kfac_kw)
         precond = kfac.KFAC(**kw)
-    model, tx = TinyCNN(), training.sgd(0.05)
+    model, tx = model or TinyCNN(), training.sgd(0.05)
     state = training.init_train_state(model, tx, precond,
                                       jax.random.PRNGKey(0), batch['input'])
     step = training.build_train_step(model, tx, precond, _ce, tracer=tracer,
@@ -269,3 +280,34 @@ def test_sgd_and_mesh_variants_carry_the_train_scopes():
     for scope in ('train.grad', 'train.grad_reduce', 'train.optimizer',
                   'train.health_screen'):
         assert any(f'{scope}/' in n for n in names), scope
+
+
+# -- conv statistics name the form they took (PR 26) ---------------------------
+
+
+def _conv_a_scopes(names):
+    return {m for n in names for m in re.findall(r'conv_a\.\w+', n)}
+
+
+def test_conv_statistics_name_their_form_inside_compute_factor():
+    step, state, batch = _trainer()
+    _, names = _op_names(step.make_variant(True, True), state, batch)
+    # TinyCNN: 3x3 kernels on 3 and 8 channels, both under the raw form's
+    # channel bound
+    assert _conv_a_scopes(names) == {'conv_a.raw'}
+    assert all('kfac.ComputeFactor/conv_a.raw' in n for n in names
+               if 'conv_a.' in n)
+    # a step that takes no statistics builds no patches
+    _, names = _op_names(step.make_variant(False, False), state, batch)
+    assert not _conv_a_scopes(names)
+
+
+def test_dense_only_update_step_holds_no_conv_scope():
+    # a model without conv layers (BERT) runs none of the conv path: its
+    # step programs are what they were before that path changed
+    step, state, batch = _trainer(model=TinyMLP())
+    text, names = _op_names(step.make_variant(True, True), state, batch)
+    assert '@jit_kfac_step_pred_stats_decomp' in text
+    assert any('kfac.ComputeFactor' in n for n in names)
+    assert 'conv_a.' not in text
+    assert 'stablehlo.convolution' not in text
