@@ -11,7 +11,6 @@ step). The rate is taken over all steps and all time of the window.
 import math
 import time
 
-import jax
 import numpy as np
 
 CHUNK = 10
@@ -87,13 +86,12 @@ def measure(stepper, traffic, seconds):
     return out
 
 
-def step_health(stepper, first=0):
-    """-> (losses, bad): every loss since step ``first`` and the number
-    of steps whose loss is not finite or whose batch the health guard
-    refused."""
-    mets = jax.device_get(stepper.metrics[first:])
+def step_health(mets):
+    """``mets``: every dispatched step's metrics, on the host. -> (losses,
+    bad, first_bad): every loss, the number of steps whose loss is not
+    finite or whose batch the health guard refused, and the index of the
+    first such step (None: none)."""
     losses = [float(m['loss']) for m in mets]
-    bad = sum(1 for m in mets
-              if not np.isfinite(m['loss'])
-              or not bool(m.get('health/ok', True)))
-    return losses, bad
+    flags = [not np.isfinite(m['loss'])
+             or not bool(m.get('health/ok', True)) for m in mets]
+    return losses, sum(flags), flags.index(True) if True in flags else None

@@ -40,6 +40,23 @@ def say(**row):
     print(json.dumps(row, default=float), flush=True)
 
 
+class Laps:
+    """Where the process's seconds went: each ``lap(part)`` gives the time
+    since the last one (the first: since the process started) to ``part``,
+    so the parts add up to the time from the start to the last lap."""
+
+    PARTS = ('setup_s', 'window_s', 'traced_s', 'memory_plan_s',
+             'first_order_s', 'reference_s', 'reduce_s')
+
+    def __init__(self, t0):
+        self.last, self.parts = t0, dict.fromkeys(self.PARTS, 0.0)
+
+    def lap(self, part):
+        now = time.time()
+        self.parts[part] += now - self.last
+        self.last = now
+
+
 def die(msg, code=2):
     print(f'benchmarks/run.py: {msg}', file=sys.stderr, flush=True)
     sys.exit(code)
@@ -209,6 +226,7 @@ def main():
     cache_mb0 = dir_mb(cache_dir)
 
     # ---- set-up: the one object the window will drive -----------------
+    laps = Laps(_T0)
     prog = program.build(builder, plain, config, traffic, args.seed)
     stepper = window.Stepper(prog, host_fence)
     chk = config['check']
@@ -231,6 +249,7 @@ def main():
     populated = program.decomposition_populated(prog.state)
     stored = program.kfac_state_dtypes(prog.state)
     setup_s = time.time() - _T0
+    laps.lap('setup_s')
     say(phase='setup', setup_s=setup_s, warm_steps=warm,
         first_call_s=first_calls, compiles=counter.compiles,
         cache_hits=counter.hits, cache_dir=cache_dir,
@@ -246,14 +265,23 @@ def main():
         sum(fn._cache_size() for fn in prog.step_fn.variants.values())
         - traced0)
     win['setup_s'] = setup_s
-    losses, bad = window.step_health(stepper)
+    mets = jax.device_get(stepper.metrics)
+    losses, bad, first_bad = window.step_health(mets)
     mine['losses'] = losses[:chk['steps']]
-    counters = program.health_counters(jax.device_get(stepper.metrics[-1]))
+    counters = program.health_counters(mets[-1])
     failed = bad + int(sum(counters.values())) + win['compiles_in_window']
     attempted = win['steps']
+    # how the losses went over set-up and window together, and where the
+    # run's first refused or non-finite step was (detail only: a model
+    # that blows up inside the window makes `failed` turn on where the
+    # window ends, PERF.md section 7)
     say(phase='window', **{k: v for k, v in win.items()
                            if np.isscalar(v)},
-        loss_first=losses[0], loss_last=losses[-1], health=counters)
+        loss_first=losses[0], loss_last=losses[-1], loss_max=max(losses),
+        loss_mean_first_period=float(np.mean(losses[:per])),
+        loss_mean_last_period=float(np.mean(losses[-per:])),
+        first_bad_step=first_bad, health=counters)
+    laps.lap('window_s')
 
     ctx = {'window': win, 'cell': cell, 'config': config,
            'traffic': traffic, 'trace': None}
@@ -262,7 +290,9 @@ def main():
             stepper, traffic,
             os.path.join(ROOT, '.bench_trace', cell['name']),
             keep=args.keep_trace)
+        laps.lap('traced_s')
         ctx['plans'] = {'hbm_plan_gb': memory_plan_gb(prog)}
+        laps.lap('memory_plan_s')
     # on this backend the allocator counts a running program's
     # temporaries under "reserved", not "in use" (2.7 GB in use beside
     # 11.0 GB reserved for a 12.6 GB plan, PERF.md PR 23): the peak is both
@@ -280,6 +310,7 @@ def main():
                                      args.seed, host_fence, 2 * window.CHUNK)
         ctx['sgd']['kfac_over_sgd'] = (kfac_step_ms
                                        / ctx['sgd']['sgd_step_ms'])
+        laps.lap('first_order_s')
 
     t = time.perf_counter()
     key = weights.seed_key(args.seed)
@@ -304,6 +335,7 @@ def main():
         kfac_state_dtype_stated=config['dtype']['factors'])
     correct = bool(ok and populated and failed == 0
                    and stored == [config['dtype']['factors']])
+    laps.lap('reference_s')
 
     # ---- the result -----------------------------------------------------
     metrics = {}
@@ -321,6 +353,18 @@ def main():
         device['busy_s'] = ctx['trace']['busy_s']
         device['window_s'] = ctx['trace']['window_s']
         result['breakdown'] = ctx['trace']['breakdown']
+    # every number compared beside its limit: last in the result line and
+    # as the last lines of stderr (what the driver keeps of a run that is
+    # not correct)
+    result['check'] = {r['check']: {'value': float(r['value']),
+                                    'limit': r['limit']} for r in rows}
+    result['check']['failed'] = {'value': failed, 'limit': 0}
+    laps.lap('reduce_s')
+    say(phase='time', **laps.parts, total_s=sum(laps.parts.values()))
+    for name, pair in result['check'].items():
+        print(f'check {name} {pair["value"]!r} limit {pair["limit"]!r}',
+              file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
 
 

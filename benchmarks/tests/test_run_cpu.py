@@ -12,7 +12,9 @@ import pytest
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH)
-KEYS = {'correct', 'attempted', 'failed', 'metrics', 'device'}
+KEYS = {'correct', 'attempted', 'failed', 'metrics', 'device', 'check'}
+TIME_PARTS = ('setup_s', 'window_s', 'traced_s', 'memory_plan_s',
+              'first_order_s', 'reference_s', 'reduce_s')
 
 
 def run_cell(workload, trace=0, devices=1, extra=(), cwd=ROOT, seconds=2):
@@ -49,6 +51,7 @@ def test_untraced_run(workload, seconds):
     win, = by_phase(rows, 'window')
     assert win['compiles_in_window'] == 0
     assert win['steps'] == last['attempted']
+    assert win['first_bad_step'] is None and not any(win['health'].values())
     # whole periods in both parts of the window
     assert win['chunks'] > 0 and win['fenced_steps'] > 0
     assert (win['steps'] - win['fenced_steps']) % 10 == 0
@@ -58,6 +61,13 @@ def test_untraced_run(workload, seconds):
     assert set(checks) == {'loss_gap', 'first_update_norm_gap',
                            'param_change_norm_gap', 'factor_gap'}
     assert all(r['value'] <= r['limit'] for r in checks.values())
+    # ... in the result line too, as its last key, and at the end of stderr
+    assert list(last)[-1] == 'check'
+    assert set(last['check']) == set(checks) | {'failed'}
+    assert all(last['check'][k] == {'value': r['value'], 'limit': r['limit']}
+               for k, r in checks.items())
+    tail = proc.stderr.splitlines()[-len(last['check']):]
+    assert [line.split()[1] for line in tail] == list(last['check'])
     # the K-FAC state is stored in the dtype the configuration states
     summary, = [r for r in by_phase(rows, 'check') if 'reference_s' in r]
     assert summary['kfac_state_dtypes'] == ['float32']
@@ -65,6 +75,49 @@ def test_untraced_run(workload, seconds):
     # the cache sits in the checkout whatever the environment names
     setup, = by_phase(rows, 'setup')
     assert setup['cache_dir'] == os.path.join(ROOT, '.jax_cache')
+
+
+def test_tiny_bert_trains_for_twice_its_window():
+    """What ``bert-base-freq10`` has to do on the chip for three windows
+    (the rule of ``tools/lr_ladder.py``), rehearsed: no step refused, no
+    loss above 1.05 x the first, the last period's mean below the first's.
+    ``tiny-bert-trains`` starts every batch from ln 32 (a small span
+    head) as the cell starts every batch from ln 384; ``tiny-bert``'s
+    batches of 4 start 10 % apart, and at its 0.04 it does not train."""
+    proc, rows = run_cell('tiny-bert-trains-freq10', seconds=4)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    last = rows[-1]
+    assert last['correct'] is True and last['failed'] == 0
+    win, = by_phase(rows, 'window')
+    assert not any(win['health'].values())
+    assert win['loss_max'] <= 1.05 * win['loss_first']
+    assert win['loss_mean_last_period'] < win['loss_mean_first_period']
+    # the rule as the ladder applies it, on these rows and on broken ones
+    from harness import files
+    ladder = files.load_module('tools', 'lr_ladder')
+    ok, seen = ladder.trains(rows)
+    assert ok and seen['steps'] == last['attempted']
+    spiked = [dict(r, loss_max=2 * r['loss_first'])
+              if r.get('phase') == 'window' else r for r in rows]
+    assert not ladder.trains(spiked)[0]
+    refused = rows[:-1] + [dict(last, failed=1)]
+    assert not ladder.trains(refused)[0]
+    assert not ladder.trains(rows[:-1])[0]     # a run that printed no result
+
+
+@pytest.mark.parametrize('trace', [0, 1])
+def test_time_row_adds_up(trace):
+    proc, rows = run_cell('tiny-bert-freq1', trace=trace)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    row, = by_phase(rows, 'time')
+    assert set(row) == {'phase', 'total_s', *TIME_PARTS}
+    assert sum(row[p] for p in TIME_PARTS) == pytest.approx(row['total_s'])
+    setup, = by_phase(rows, 'setup')
+    assert row['setup_s'] == pytest.approx(setup['setup_s'], abs=0.05)
+    traced_only = ('traced_s', 'memory_plan_s', 'first_order_s')
+    assert all((row[p] > 0) == bool(trace) for p in traced_only)
+    # the row is the last before the result line
+    assert rows[-2] is row
 
 
 def test_traced_run_and_pieces_that_exist_only_under_tests():
@@ -133,6 +186,23 @@ def test_stuck_step_is_not_correct():
     assert rows[-1]['correct'] is False
     checks = {r['check']: r for r in by_phase(rows, 'check') if 'check' in r}
     assert not checks['param_change_norm_gap']['ok']
+
+
+@pytest.mark.parametrize('bad_at,bad', [(None, 0), (352, 8), (5, 355)])
+def test_step_health_counts_refused_and_non_finite_steps(bad_at, bad):
+    """``failed`` counts every step of set-up and window that the guard
+    refused or whose loss is not finite; the ``window`` row says where the
+    first was (``bert-base-squad`` at lr 0.04 blew up 274-356 steps in)."""
+    import numpy as np
+    from harness import window
+    mets = [{'loss': np.float32(5.9), 'health/ok': np.bool_(True)}
+            for _ in range(360)]
+    for i in range(bad_at or 360, 360):
+        mets[i] = ({'loss': np.float32(np.nan), 'health/ok': np.bool_(True)}
+                   if i % 2 else
+                   {'loss': np.float32(4.0), 'health/ok': np.bool_(False)})
+    losses, n_bad, first = window.step_health(mets)
+    assert len(losses) == 360 and first == bad_at and n_bad == bad
 
 
 def test_kfac_state_stored_in_a_lower_dtype_shows():

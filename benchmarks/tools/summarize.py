@@ -25,7 +25,8 @@ def main():
         if last is None:
             print(name, 'NO RESULT')
             continue
-        checks = {r['check']: r['value'] for r in rows if 'check' in r}
+        checks = {r['check']: r['value'] for r in rows
+                  if r.get('phase') == 'check' and 'check' in r}
         setup = next((r for r in rows if r.get('phase') == 'setup'), {})
         ref = next((r.get('reference_s') for r in rows
                     if 'reference_s' in r), None)
