@@ -27,6 +27,7 @@ applied to a temporary — the mathematically intended semantics.
 """
 
 import contextlib
+import functools
 from typing import Dict, List, Optional
 
 import jax
@@ -1130,6 +1131,46 @@ def _group_grad_stack(plan, pg, grad_mats):
                       for i in pg.layer_idx])
 
 
+def _group_rows(pg, side, x, axis_name=None, local=False):
+    """Pred group ``pg``'s rows of ``x``, a stored ``[rows, ...]``
+    decomposition component of its ``side`` ('a' | 'g') bucket
+    (``local``: this device's shard of it, and ``side='member'`` the
+    device's members of the group's gradient stack).
+
+    Where the plan laid the rows down as one run
+    (``PredGroup.run_starts``) this is a slice, which the GEMM reads
+    where it lies: static in the replicated layout and where every
+    device's run starts alike (always on one device), one dynamic slice
+    at this device's start otherwise. Anywhere else, a gather."""
+    table, starts = pg.row_table(side, local), pg.run_starts(side, local)
+    if not local:
+        if starts is None:
+            return jnp.take(x, jnp.asarray(table), axis=0)
+        return lax.slice_in_dim(x, starts, starts + len(table), axis=0)
+    if starts is None:
+        return jnp.take(x, _local_table(table, axis_name), axis=0)
+    k = table.shape[1]
+    if (starts == starts[0]).all():
+        return lax.slice_in_dim(x, int(starts[0]), int(starts[0]) + k,
+                                axis=0)
+    return lax.dynamic_slice_in_dim(x, _local_table(starts, axis_name), k,
+                                    axis=0)
+
+
+def _group_pred(pg, rows, decomp, gstack, damping, method, scales):
+    """One group's batched apply; ``rows(side, x)`` picks the group's
+    rows of a component of its ``side`` bucket."""
+    ka, kg = _key(pg.da), _key(pg.dg)
+    if method == 'eigh':
+        evecs, evals = decomp['evecs'], decomp['evals']
+        return _pred_eigh(rows('g', evecs[kg]), rows('g', evals[kg]),
+                          rows('a', evecs[ka]), rows('a', evals[ka]),
+                          gstack, damping, scales)
+    invs = decomp['invs']
+    return _pred_inv(rows('g', invs[kg]), rows('a', invs[ka]), gstack,
+                     damping)
+
+
 def compute_pred_replicated(plan, decomp, grad_mats, damping, method,
                             scales=None):
     """Preconditioning with replicated (gathered) decompositions — every
@@ -1140,17 +1181,9 @@ def compute_pred_replicated(plan, decomp, grad_mats, damping, method,
     preds = [None] * plan.num_layers
     for gi, pg in enumerate(plan.pred_groups):
         gstack = _group_grad_stack(plan, pg, grad_mats)
-        if method == 'eigh':
-            qa = decomp['evecs'][_key(pg.da)][pg.row_a]
-            da = decomp['evals'][_key(pg.da)][pg.row_a]
-            qg = decomp['evecs'][_key(pg.dg)][pg.row_g]
-            dg = decomp['evals'][_key(pg.dg)][pg.row_g]
-            pred = _pred_eigh(qg, dg, qa, da, gstack, damping,
-                              None if scales is None else scales[f'g{gi}'])
-        else:
-            inva = decomp['invs'][_key(pg.da)][pg.row_a]
-            invg = decomp['invs'][_key(pg.dg)][pg.row_g]
-            pred = _pred_inv(invg, inva, gstack, damping)
+        pred = _group_pred(pg, functools.partial(_group_rows, pg), decomp,
+                           gstack, damping, method,
+                           None if scales is None else scales[f'g{gi}'])
         for pos, i in enumerate(pg.layer_idx):
             meta = plan.metas[int(i)]
             preds[int(i)] = pred[pos, :meta.out_dim, :meta.in_dim]
@@ -1167,23 +1200,13 @@ def compute_pred_local(plan, decomp_local, grad_mats, damping, method,
     (update_ekfac_scales_local) replacing the Kronecker denominators."""
     preds = [None] * plan.num_layers
     for gi, pg in enumerate(plan.pred_groups):
-        gstack = _group_grad_stack(plan, pg, grad_mats)
-        members = _local_table(pg.local_member, axis_name)
-        g_loc = jnp.take(gstack, members, axis=0)
-        ra = _local_table(pg.local_row_a, axis_name)
-        rg = _local_table(pg.local_row_g, axis_name)
-        if method == 'eigh':
-            qa = jnp.take(decomp_local['evecs'][_key(pg.da)], ra, axis=0)
-            da = jnp.take(decomp_local['evals'][_key(pg.da)], ra, axis=0)
-            qg = jnp.take(decomp_local['evecs'][_key(pg.dg)], rg, axis=0)
-            dg = jnp.take(decomp_local['evals'][_key(pg.dg)], rg, axis=0)
-            pred_loc = _pred_eigh(qg, dg, qa, da, g_loc, damping,
-                                  None if scales is None
-                                  else scales[f'g{gi}'])
-        else:
-            inva = jnp.take(decomp_local['invs'][_key(pg.da)], ra, axis=0)
-            invg = jnp.take(decomp_local['invs'][_key(pg.dg)], rg, axis=0)
-            pred_loc = _pred_inv(invg, inva, g_loc, damping)
+        rows = functools.partial(_group_rows, pg, axis_name=axis_name,
+                                 local=True)
+        # this device's members of the stack: all of it, on one device
+        g_loc = rows('member', _group_grad_stack(plan, pg, grad_mats))
+        pred_loc = _group_pred(pg, rows, decomp_local, g_loc, damping,
+                               method,
+                               None if scales is None else scales[f'g{gi}'])
         if communicate:
             gathered = coll.all_gather_rows_compressed(pred_loc, axis_name,
                                                        comm_precision)

@@ -190,7 +190,7 @@ def stage_partition(metas: Dict[str, 'base_plan.LayerMeta'],
 def build_mesh_plan(metas, mesh_axes, *, comm_mode,
                     assignment='round_robin',
                     distribute_layer_factors=False,
-                    bucket_fn=base_plan.default_bucket_fn,
+                    bucket_fn=None,
                     rules=None) -> MeshFactorPlan:
     """Build the axis-aware plan: a plain data-world ``FactorPlan`` plus
     the per-axis role tables.

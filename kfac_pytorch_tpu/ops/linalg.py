@@ -11,7 +11,8 @@ All functions accept either a single matrix ``[D, D]`` or a stacked batch
 ``[L, D, D]``.
 
 Identity padding: factors are padded from their true dim ``d`` to a bucket
-dim ``D`` with an identity block. This is *exact* for both preconditioning
+dim ``D`` (the next multiple of the MXU tile, ``plan.default_bucket_fn``)
+with an identity block. This is *exact* for both preconditioning
 paths: padded eigenvectors live in the pad subspace, which is orthogonal to
 the zero-padded gradient, so their terms vanish; for the explicit inverse,
 blockdiag(A, I)^-1 = blockdiag(A^-1, I) and the pad block multiplies zero

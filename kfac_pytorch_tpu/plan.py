@@ -11,13 +11,18 @@ XLA wants one uniform program, so the plan instead fixes a *layout*:
   each factor on its owner and batched eigh/inverse on the local shard *is*
   the distributed computation;
 - preconditioning batches layers by their (G-bucket, A-bucket) pair so the
-  per-layer triple matmuls run as batched einsums on the MXU.
+  per-layer triple matmuls run as batched einsums on the MXU;
+- within a device's rows of a bucket, slots lie by (pred group, side,
+  layer): each group's rows are one contiguous run, so the apply reads the
+  stored decompositions where they lie (``PredGroup.run_starts``) instead
+  of gathering a copy of them every step.
 
 Identity padding is numerically exact (see ops/linalg.py). The stacked
 sharded-eigh layout is the TPU-idiomatic form of tcmm's multiBcast fused
 compute+broadcast (reference: packages/tcmm/src/communicator.cpp:75-117).
 """
 
+import collections
 import dataclasses
 from typing import Callable, Dict, List, Optional, Tuple
 
@@ -28,22 +33,58 @@ from kfac_pytorch_tpu.parallel.partition import (
     balanced_assign, round_robin_assign)
 
 
-def default_bucket_fn(dim, min_bucket=128):
-    """Pad dim → bucket: {min, 1.5·2^k, 2^k} steps up to 1024, then
-    multiples of 256. Keeps decomposition padding waste low (≤1.5³ small,
-    ≤~1.2³ large — e.g. ResNet-50's 4608 factor stays exactly 4608) while
-    staying lane-aligned (TPU tiles are 128 wide)."""
-    if dim <= min_bucket:
-        return min_bucket
-    if dim > 1024:
-        return -(-dim // 256) * 256
-    b = min_bucket
-    while True:
-        if dim <= b:
-            return b
-        if dim <= b + b // 2:
-            return b + b // 2
-        b *= 2
+#: the MXU's tile edge: a bucket dim is a whole number of these
+MXU_TILE = 128
+
+
+def default_bucket_fn(dim, min_bucket=MXU_TILE):
+    """Pad dim → bucket: the next multiple of the MXU tile (128), with
+    ``min_bucket`` the floor. A tile is the finest step a TPU layout can
+    take, so this is the least padding the stacked GEMMs and the batched
+    decompositions can run at: a dense layer's ``in + 1`` (the bias's
+    homogeneous coordinate) costs one tile, not the next rung of a ladder
+    (BERT-base: 769 → 896, 3,073 → 3,200; ResNet-50's 4,608 stays 4,608).
+    ``build_plan`` folds the odd bucket this leaves behind into its
+    neighbour (:func:`fold_buckets`)."""
+    return max(min_bucket, -(-dim // MXU_TILE) * MXU_TILE)
+
+
+def fold_buckets(rows_of: Dict[int, int]) -> Dict[int, int]:
+    """The default layout's second half: ``{bucket dim: dim it joins}``
+    over ``rows_of`` (``{bucket dim: factor rows in it}``, all devices).
+
+    Every bucket is one batched decomposition, and that is a sequential
+    chain of 128-wide panels however few rows ride it: the model is
+    ``cost(bucket) = (rows + 1) · D³``, one more row's work for the chain.
+    Visiting buckets smallest first, a bucket joins the next larger one
+    where the model's total falls, i.e. where padding its rows up costs
+    less than the chain it saves: ``rows · (D'³ − D³) < D³`` — in practice
+    one or two rows, a tile or so under a neighbour (ResNet-50's ``fc``
+    input, 2,049 → 2,176, joins the six 2,304s; BERT-base's 60 × 768 stay
+    out of 896). Dims and row counts alone: the same model has the same
+    buckets on every world size, which ``reshard_kfac_state``'s whole-row
+    transport of decompositions relies on."""
+    dims = sorted(rows_of)
+    rows = dict(rows_of)
+    joins = {d: d for d in dims}
+    for d, up in zip(dims, dims[1:]):
+        if rows[d] * (_slot_cost(up) - _slot_cost(d)) < _slot_cost(d):
+            joins[d] = up
+            rows[up] += rows.pop(d)
+    # a chain of folds lands on its last link
+    for d in dims:
+        while joins[joins[d]] != joins[d]:
+            joins[d] = joins[joins[d]]
+    return joins
+
+
+def _run_start(rows):
+    """First row of ``rows`` where they are one ascending run of step 1
+    (what ``lax.slice_in_dim`` can read in place), else None."""
+    rows = np.asarray(rows)
+    if rows.size and np.array_equal(rows, rows[0] + np.arange(rows.size)):
+        return int(rows[0])
+    return None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -87,6 +128,32 @@ class PredGroup:
     local_row_a: Optional[np.ndarray] = None    # [P, K] row in local da shard
     local_row_g: Optional[np.ndarray] = None    # [P, K] row in local dg shard
     gathered_row: Optional[np.ndarray] = None   # [M] row in all-gathered P*K
+
+    def row_table(self, side, local):
+        """The rows the apply reads for ``side`` ('a' | 'g'): global
+        ``[M]`` rows of the bucket (replicated layout), or with ``local``
+        the ``[P, K]`` rows of each device's shard — where
+        ``side='member'`` names ``local_member``, the device's rows of
+        the group's gradient stack."""
+        if not local:
+            return self.row_a if side == 'a' else self.row_g
+        return {'a': self.local_row_a, 'g': self.local_row_g,
+                'member': self.local_member}[side]
+
+    def run_starts(self, side, local):
+        """Where the apply reads :meth:`row_table`'s rows in place, or
+        None where it has to gather them (``jnp.take``): the first row,
+        if the replicated layout's rows are one run (always on one
+        device); with ``local`` the ``[P]`` first rows of each device's K
+        slots, if every slot of every device holds a member (runs of one
+        length) and each device's are one run."""
+        table = self.row_table(side, local)
+        if not local:
+            return _run_start(table)
+        if not self.local_valid.all():
+            return None
+        starts = [_run_start(r) for r in table]
+        return None if None in starts else np.asarray(starts, np.int32)
 
 
 @dataclasses.dataclass
@@ -219,6 +286,32 @@ class FactorPlan:
                     decomp += P * s_b * (bdim * wire + scale_b)
         return {'FactorComm': factor, 'InverseComm': inverse,
                 'PredComm': pred, 'DecompComm': decomp}
+
+
+def pred_layout_record(plan: 'FactorPlan'):
+    """What ``KFAC.setup`` records of the layout as the apply consumes it:
+
+    - ``pred_operand_slices`` / ``pred_operand_takes``: of the apply's
+      reads of stored decomposition rows (one per pred group and side),
+      how many are served in place and how many by a gather;
+    - ``pad_flop_share``: multiplied over needed flop of the apply's
+      GEMMs, ``dg²·da + dg·da²`` multiply-adds a row at the bucket dims
+      (dummy slots of the owner-local layout included) over the same at
+      the layers' true dims.
+    """
+    local = plan.comm_mode == 'pred'
+    reads = [pg.run_starts(side, local) is not None
+             for pg in plan.pred_groups for side in 'ag']
+    padded = true = 0
+    for pg in plan.pred_groups:
+        rows = plan.num_devices * pg.k_per_dev if local else len(pg.layer_idx)
+        padded += rows * (pg.dg ** 2 * pg.da + pg.dg * pg.da ** 2)
+        for i in pg.layer_idx:
+            m = plan.metas[int(i)]
+            true += m.out_dim ** 2 * m.in_dim + m.out_dim * m.in_dim ** 2
+    return {'pred_operand_slices': sum(reads),
+            'pred_operand_takes': len(reads) - sum(reads),
+            'pad_flop_share': round(padded / true, 4)}
 
 
 def _slot_cost(dim):
@@ -591,13 +684,17 @@ def same_row_layout(plan_a: 'FactorPlan', plan_b: 'FactorPlan') -> bool:
 def build_plan(metas: Dict[str, LayerMeta], num_devices: int, comm_mode: str,
                assignment: str = 'round_robin',
                distribute_layer_factors: bool = False,
-               bucket_fn: Callable[[int], int] = default_bucket_fn):
+               bucket_fn: Optional[Callable[[int], int]] = None):
     """Build the static layout.
 
     Ownership parity: round-robin layer→rank (kfac_preconditioner_inv.py:
     62-77); with ``distribute_layer_factors`` (comm_mode='inverse' only) the
     interleaved A/G slot round-robin of eigen.py:75-94; 'balanced' uses the
     LPT scheduler (the dp_block_partition.py upgrade).
+
+    ``bucket_fn=None`` is the default layout: :func:`default_bucket_fn`'s
+    tile rounding, then :func:`fold_buckets`. A caller's own ``bucket_fn``
+    states the buckets it wants and is taken as it is.
     """
     meta_list = list(metas.values())
     L = len(meta_list)
@@ -638,9 +735,28 @@ def build_plan(metas: Dict[str, LayerMeta], num_devices: int, comm_mode: str,
         slots.append(Slot(i, 'A', m.in_dim, oa))
         slots.append(Slot(i, 'G', m.out_dim, og))
 
+    if bucket_fn is None:
+        tiled = {s.dim: default_bucket_fn(s.dim) for s in slots}
+        joins = fold_buckets(
+            collections.Counter(tiled[s.dim] for s in slots))
+
+        def bucket_fn(dim):
+            return joins[tiled[dim]]
+
+    # pred groups: layers sharing (G-bucket, A-bucket), in key order
+    group_key = [(bucket_fn(m.out_dim), bucket_fn(m.in_dim))
+                 for m in meta_list]
+    group_idx = {k: g for g, k in enumerate(sorted(set(group_key)))}
+
     by_bucket: Dict[int, List[Slot]] = {}
     for s in slots:
         by_bucket.setdefault(bucket_fn(s.dim), []).append(s)
+    # a device's rows of a bucket lie by (pred group, side, layer): every
+    # group's rows are then one contiguous run, in the group's own member
+    # order, and the apply can read them in place
+    for members in by_bucket.values():
+        members.sort(key=lambda s: (group_idx[group_key[s.layer_idx]],
+                                    s.side, s.layer_idx))
 
     buckets: Dict[int, Bucket] = {}
     slot_row: Dict[Tuple[int, str], Tuple[int, int]] = {}  # → (bucket, row)
@@ -711,8 +827,7 @@ def build_plan(metas: Dict[str, LayerMeta], num_devices: int, comm_mode: str,
 
     # --- pred groups ----------------------------------------------------
     groups: Dict[Tuple[int, int], List[int]] = {}
-    for i, m in enumerate(meta_list):
-        key = (bucket_fn(m.out_dim), bucket_fn(m.in_dim))
+    for i, key in enumerate(group_key):
         groups.setdefault(key, []).append(i)
 
     pred_groups = []

@@ -23,6 +23,7 @@ without recompilation.
 """
 
 import dataclasses
+import logging
 from typing import Any, Dict, Optional
 
 import flax.struct
@@ -33,8 +34,9 @@ from jax.sharding import PartitionSpec as P
 
 from kfac_pytorch_tpu import engine, faults
 from kfac_pytorch_tpu import health as health_lib
+from kfac_pytorch_tpu.obs import trace as obs_trace
 from kfac_pytorch_tpu.plan import (build_cohorts, build_decomp_shard,
-                                   build_plan, default_bucket_fn)
+                                   build_plan, pred_layout_record)
 
 #: decomposition-implementation knob values (the autotuner's ladder
 #: restates this tuple in autotune.DECOMP_IMPLS — it must stay
@@ -384,7 +386,8 @@ class KFAC:
                              'to apply to — pass mesh_axes')
         self.assignment = assignment
         self.distribute_layer_factors = distribute_layer_factors
-        self.bucket_fn = bucket_fn or default_bucket_fn
+        # None: plan.build_plan's default layout (tile rounding + fold)
+        self.bucket_fn = bucket_fn
         self.eps = eps
         if basis_update_freq is not None and self.method != 'eigh':
             raise ValueError('basis_update_freq applies to eigh variants')
@@ -575,6 +578,15 @@ class KFAC:
         self._cohorts = None
         if self.stagger:
             self.rebase_cohorts()
+        # the layout as the apply consumes it: one record, in the
+        # recorder's trace and in the run's log
+        record = pred_layout_record(self.plan)
+        obs_trace.instant('kfac.precond.setup', cat='kfac.step',
+                          buckets=len(self.plan.bucket_dims), **record)
+        logging.getLogger(__name__).info(
+            'precond.setup: %d buckets %s, %s', len(self.plan.bucket_dims),
+            self.plan.bucket_dims,
+            ', '.join(f'{k} {v}' for k, v in record.items()))
         return self.plan
 
     def rebase_cohorts(self):

@@ -44,6 +44,38 @@ class CheckpointCorruptError(OSError):
     treats it like any unreadable checkpoint: log and scan down."""
 
 
+class KFACLayoutError(ValueError):
+    """The checkpoint's K-FAC state was written under another stacked-
+    bucket layout than the restoring preconditioner's plan lays down
+    (other bucket dims, or other row counts in a bucket): loading it
+    would put every factor on some other layer's row. Not corruption,
+    and no older epoch is any different: ``auto_resume`` raises it
+    instead of scanning down."""
+
+
+def _check_kfac_layout(saved_factors, target_state, epoch):
+    """Compare the saved K-FAC factors' bucket keys and row counts
+    (``{bucket key: array or shape-carrying metadata}``) with those of
+    ``target_state.kfac_state`` — which ``KFAC.init`` made from the
+    plan. A restore must not relabel rows silently: the buckets a plan
+    lays down follow from ``plan.default_bucket_fn`` / ``fold_buckets``
+    and the world size, and a row's layer from its place in them."""
+    target = getattr(target_state, 'kfac_state', None)
+    if target is None or not saved_factors:
+        return
+    saved = {k: int(v.shape[0]) for k, v in saved_factors.items()}
+    want = {k: int(v.shape[0]) for k, v in target.factors.items()}
+    if saved != want:
+        raise KFACLayoutError(
+            f'checkpoint-{epoch} holds K-FAC state of another layout: '
+            f'buckets {{dim: rows}} {saved} in the checkpoint, {want} in '
+            'this plan (a change of the bucket rule or of the world '
+            'size). Restore it into a preconditioner set up as it was '
+            'written (its bucket_fn, its num_devices) and carry it over '
+            'with utils.reshard_kfac_state, or restore without the '
+            'K-FAC state and let the factors rebuild.')
+
+
 def _store_for(base_dir):
     """The object-store stack for a checkpoint namespace (posix by
     default — byte-compatible with the pre-store file layout;
@@ -581,11 +613,37 @@ def _restore_checkpoint_once(base_dir, epoch, target_state):
     # legacy pre-manifest checkpoint: restore straight off the files
     path = _ckpt_dir(base_dir, epoch)
     if _HAS_ORBAX and os.path.isdir(path):
-        ckptr = ocp.StandardCheckpointer()
-        return ckptr.restore(path, target_state)
+        return _restore_orbax(path, epoch, target_state)
     import pickle
     with open(path + '.pkl', 'rb') as f:
-        return pickle.load(f)
+        return _checked_pickle(pickle.load(f), epoch, target_state)
+
+
+def _restore_orbax(path, epoch, target_state):
+    """Orbax restore into ``target_state``, the saved K-FAC layout
+    compared with the target's first (from the checkpoint's own
+    metadata: orbax's structure error names neither)."""
+    try:
+        saved = _saved_kfac_meta(path).get('factors')
+    except Exception:  # noqa: BLE001 — metadata unreadable: restore says
+        saved = None
+    _check_kfac_layout(saved, target_state, epoch)
+    return ocp.StandardCheckpointer().restore(path, target_state)
+
+
+def _saved_kfac_meta(path):
+    """The ``kfac_state`` subtree of an orbax checkpoint's own metadata
+    (orbax 0.11: ``StepMetadata.item_metadata`` is the saved tree, its
+    leaves carrying shape and dtype); {} where none was saved."""
+    meta = ocp.StandardCheckpointer().metadata(path).item_metadata
+    return meta.get('kfac_state') or {}
+
+
+def _checked_pickle(restored, epoch, target_state):
+    """A pickle restores without a target: compare what came back."""
+    k = getattr(restored, 'kfac_state', None)
+    _check_kfac_layout(getattr(k, 'factors', None), target_state, epoch)
+    return restored
 
 
 def _verified_blob(store, key, spec):
@@ -627,7 +685,7 @@ def _restore_manifested(base_dir, epoch, manifest, store, target_state):
     if manifest.get('kind') == 'pickle':
         import pickle
         (data,) = blobs.values()
-        return pickle.loads(data)
+        return _checked_pickle(pickle.loads(data), epoch, target_state)
     # orbax tree: materialize verified bytes locally when the store is
     # remote (orbax restores from a directory), then restore as usual
     if not local:
@@ -639,8 +697,7 @@ def _restore_manifested(base_dir, epoch, manifest, store, target_state):
             with open(tmp, 'wb') as f:
                 f.write(data)
             os.replace(tmp, target)
-    ckptr = ocp.StandardCheckpointer()
-    return ckptr.restore(_ckpt_dir(base_dir, epoch), target_state)
+    return _restore_orbax(_ckpt_dir(base_dir, epoch), epoch, target_state)
 
 
 def _saved_comm_err_zeros(path):
@@ -653,9 +710,7 @@ def _saved_comm_err_zeros(path):
     if not _HAS_ORBAX or not os.path.isdir(path):
         return None
     try:
-        # orbax 0.11: StepMetadata whose item_metadata is the saved tree
-        meta = ocp.StandardCheckpointer().metadata(path).item_metadata
-        err = (meta.get('kfac_state') or {}).get('comm_err')
+        err = _saved_kfac_meta(path).get('comm_err')
         if not isinstance(err, dict) or not err:
             return None
         import jax.numpy as jnp
@@ -689,6 +744,8 @@ def auto_resume(base_dir, max_epoch, target_state, retry=None):
         try:
             return (restore_checkpoint(base_dir, epoch, target_state,
                                        retry=retry), epoch)
+        except KFACLayoutError:
+            raise
         except Exception:  # noqa: BLE001 — any unreadable ckpt: scan on
             # NOT necessarily corruption: a structure mismatch from a
             # checkpoint taken before an OPTIONAL state subtree existed

@@ -333,3 +333,42 @@ def test_pickle_roundtrip_through_http_store(tmp_path, monkeypatch):
                                                                 None)
     finally:
         srv.stop()
+
+
+@pytest.mark.parametrize('orbax', [True, False], ids=['orbax', 'pickle'])
+def test_old_layout_kfac_state_does_not_load_silently(tmp_path, monkeypatch,
+                                                      orbax):
+    """A K-FAC state written under the bucket ladder the tile rule
+    replaced (769 -> 1,024; now 896) shares bucket key '768' with the new
+    plan and would otherwise restore (pickle: with no check at all) onto
+    rows that mean other layers. Restore compares bucket keys and row
+    counts with the plan's and says what to do; auto_resume raises it
+    too, since no older epoch is any different."""
+    from kfac_pytorch_tpu.capture import LayerMeta
+    if not orbax:
+        monkeypatch.setattr(checkpoint, '_HAS_ORBAX', False)
+    metas = [LayerMeta(name=f'l{i}', path=(f'l{i}',), kind='dense',
+                       use_bias=True, in_dim=257, out_dim=256,
+                       kernel_shape=(256, 256)) for i in range(2)]
+
+    def state(bucket_fn):
+        pre = kfac.KFAC(variant='inverse_dp', bucket_fn=bucket_fn)
+        pre.setup(metas)
+        return training.TrainState(
+            step=jnp.zeros((), jnp.int32), params={'w': jnp.ones(3)},
+            opt_state={}, kfac_state=pre.init(), extra_vars={})
+
+    old = state(lambda d: 256 if d <= 256 else 512)    # 257: a rung up
+    new = state(None)                                  # 257 -> 384
+    assert (set(old.kfac_state.factors) & set(new.kfac_state.factors)
+            == {'256'})
+    checkpoint.save_checkpoint(tmp_path, 2, old)
+    # the same layout restores
+    back = checkpoint.restore_checkpoint(tmp_path, 2, old)
+    assert set(back.kfac_state.factors) == {'256', '512'}
+    for fn in (lambda: checkpoint.restore_checkpoint(tmp_path, 2, new),
+               lambda: checkpoint.auto_resume(tmp_path, 5, new)):
+        with pytest.raises(checkpoint.KFACLayoutError,
+                           match='reshard_kfac_state') as e:
+            fn()
+        assert "'512': 2" in str(e.value) and "'384': 2" in str(e.value)

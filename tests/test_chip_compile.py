@@ -165,6 +165,51 @@ def test_xla_conv_a_plans_one_patch_tensor_on_v5e(
         temp / patch_bytes)
 
 
+def test_apply_reads_bert_base_inverses_in_place_on_v5e(one_chip):
+    """``kfac.Precondition`` as ``bert-base-freq10`` runs it
+    (``compute_pred_local``, one device, stored inverses of BERT-base's
+    plan): every pred group's rows are one static slice of the stored
+    ``[rows, D, D]`` bucket, so the compiler plans no gather and builds no
+    copy of an inverse stack (the ``jnp.take`` form planned 1.54 GB of
+    temporaries for 1.72 GB of arguments: chains of
+    ``dynamic-update-slice`` into fresh ``f32[12,3328,3328]``,
+    ``[48,1024,1024]``... buffers; PERF.md, PR 31). What is left under 0.6
+    GB is the padded gradient stacks. A compile, not a timing."""
+    import re
+
+    from kfac_pytorch_tpu import engine
+    from kfac_pytorch_tpu.capture import LayerMeta
+    from kfac_pytorch_tpu.plan import build_plan, pred_layout_record
+    dims = (([(769, 768)] * 4 + [(769, 3072), (3073, 768)]) * 12
+            + [(769, 2)])
+    plan = build_plan(
+        {f'l{i}': LayerMeta(name=f'l{i}', path=(f'l{i}',), kind='dense',
+                            use_bias=True, in_dim=a, out_dim=g,
+                            kernel_shape=(a - 1, g))
+         for i, (a, g) in enumerate(dims)}, 1, 'pred')
+    assert pred_layout_record(plan)['pred_operand_takes'] == 0
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, F32, sharding=one_chip)
+    invs = {str(d): spec(plan.buckets[d].n_rows, d, d)
+            for d in plan.bucket_dims}
+    grads = [spec(m.out_dim, m.in_dim) for m in plan.metas]
+    compiled = jax.jit(lambda invs, grads: engine.compute_pred_local(
+        plan, {'invs': invs}, grads, 0.003, 'cholesky', None)
+    ).lower(invs, grads).compile()
+    text = compiled.as_text()
+    assert not re.search(r'\bgather\(', text)
+    # no [rows, D, D] stack is assembled row by row
+    square = re.findall(
+        r'= f32\[\d+,(\d+),(\d+)\]\S* dynamic-update-slice\(', text)
+    assert not [d for d in square if d[0] == d[1]], square[:4]
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 0.6e9, mem.temp_size_in_bytes
+    # two GEMMs a group, over the three large groups and the span head
+    assert len(re.findall(r'kind=kOutput', text)) == 2 * len(
+        plan.pred_groups)
+
+
 @pytest.mark.parametrize('length', [2048, 32768])
 def test_flash_block_attn_fwd_bwd_compiles_for_v5e(one_chip, length,
                                                    monkeypatch):

@@ -69,25 +69,25 @@ def _setup(variant, batch=4, **kw):
 # ---------------------------------------------------------------------------
 
 def test_default_bucket_fn_boundaries():
-    # {min, 1.5·2^k, 2^k} ladder up to 1024, multiples of 256 above
+    # the next multiple of the MXU tile (128), 128 the floor
     assert default_bucket_fn(1) == 128
     assert default_bucket_fn(128) == 128
-    assert default_bucket_fn(129) == 192
-    assert default_bucket_fn(192) == 192
-    assert default_bucket_fn(193) == 256
+    assert default_bucket_fn(129) == 256
+    assert default_bucket_fn(256) == 256
+    assert default_bucket_fn(257) == 384
     assert default_bucket_fn(1024) == 1024
-    assert default_bucket_fn(1025) == 1280   # first step past the ladder
-    # large multiples of 256 stay exact (ResNet-50's 4608 case)
+    assert default_bucket_fn(1025) == 1152   # one tile, not a ladder rung
+    # multiples of the tile stay exact (ResNet-50's 4608 case)
     assert default_bucket_fn(4608) == 4608
-    # large non-multiple rounds UP to the next multiple of 256
+    # a non-multiple rounds UP to the next multiple of 128
     assert default_bucket_fn(5000) == 5120
-    assert default_bucket_fn(2304 + 1) == 2560
-    # monotone, and never below the input
+    assert default_bucket_fn(2304 + 1) == 2432
+    # monotone, never below the input, never a whole tile above it
     prev = 0
     for d in (1, 64, 128, 129, 191, 192, 193, 767, 768, 769, 1024, 1025,
               1279, 1280, 4608, 5000):
         b = default_bucket_fn(d)
-        assert b >= d and b >= prev
+        assert b >= d and b >= prev and (b - d < 128 or b == 128)
         prev = b
 
 
