@@ -137,9 +137,9 @@ def parse_args():
                    help='closed-loop autotuning: one online controller '
                         'hill-climbs kfac/fac_update_freq and the comm '
                         'wire dtype from measured step times through '
-                        'the knob arbiter, with perf-model drift-band '
-                        'vetoes (defaults on when $KFAC_AUTOTUNE=1; '
-                        'see README "Closed-loop autotuning")')
+                        'the knob arbiter (defaults on when '
+                        '$KFAC_AUTOTUNE=1; see README "Closed-loop '
+                        'autotuning")')
     p.add_argument('--kfac-cov-update-freq', type=int, default=1)
     p.add_argument('--kfac-type', '--fisher-type', default='Femp',
                    choices=['Femp', 'F1mc'],
@@ -372,14 +372,12 @@ def main():
     watchdog = None
     if args.step_deadline > 0:
         watchdog = resilience.StepWatchdog(args.step_deadline, log=log)
-    # closed-loop autotuner: proposes knob changes to the same arbiter
-    # the scheduler/governor feed (no predicted block here — the perf
-    # model describes the imagenet resnet50 anchor, not cifar: the
-    # drift gate stays out of the loop, decisions are measurement-only)
+    # closed-loop autotuner: proposes knob changes, from measured step
+    # times, to the same arbiter the scheduler/governor feed
     from kfac_pytorch_tpu import autotune
     tuner = autotune.controller_from_args(
         precond, enabled=args.kfac_autotune, trace_dir=args.trace,
-        variant=args.kfac_name, log=log)
+        log=log)
 
     # observability: trace recorder (per-step spans + resilience
     # instants, flushed on the runlog SIGTERM/atexit chain) and the

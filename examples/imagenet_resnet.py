@@ -131,9 +131,7 @@ def parse_args():
                    help='closed-loop autotuning: one online controller '
                         'hill-climbs kfac/fac_update_freq and the comm '
                         'wire dtype from measured step times through '
-                        'the knob arbiter; on the modeled workload '
-                        '(resnet50 bs32) every commit is vetoed by the '
-                        'perf-model drift band (defaults on when '
+                        'the knob arbiter (defaults on when '
                         '$KFAC_AUTOTUNE=1; see README "Closed-loop '
                         'autotuning")')
     p.add_argument('--kfac-cov-update-freq', type=int, default=1)
@@ -295,19 +293,12 @@ def main():
     watchdog = None
     if args.step_deadline > 0:
         watchdog = resilience.StepWatchdog(args.step_deadline, log=log)
-    # closed-loop autotuner: THIS trainer is the workload the analytic
-    # perf model describes (resnet50 bs32, perf_inputs_resnet50_bs32),
-    # so when the config matches the anchor the tuner runs drift-GATED —
-    # on the modeled chip a knob change whose measured phase ratios
-    # leave the [optimistic, conservative] band is vetoed, elsewhere
-    # the band is advisory; any other config tunes ungated
-    from kfac_pytorch_tpu import autotune, perfmodel
-    predicted = (perfmodel.predict_block()
-                 if args.model == 'resnet50'
-                 and args.batch_size == perfmodel.BATCH else None)
+    # closed-loop autotuner: proposes knob changes, from measured step
+    # times, to the same arbiter the scheduler/governor feed
+    from kfac_pytorch_tpu import autotune
     tuner = autotune.controller_from_args(
         precond, enabled=args.kfac_autotune, trace_dir=args.trace,
-        predicted=predicted, variant=args.kfac_name, log=log)
+        log=log)
 
     # auto-resume (reference: pytorch_imagenet_resnet.py:162-167,305-312),
     # hardened: an unreadable newest checkpoint (truncated write, storage

@@ -335,13 +335,11 @@ def main():
     # old hand-plumbed health_suffix) — same bootstrap as cifar/imagenet
     from kfac_pytorch_tpu import obs
     # closed-loop autotuner: proposes knob changes to the single knob
-    # arbiter from measured step times (no predicted block — the perf
-    # model describes the imagenet resnet50 anchor, not this workload:
-    # decisions are measurement-only, the drift gate stays out)
+    # arbiter from measured step times
     from kfac_pytorch_tpu import autotune
     tuner = autotune.controller_from_args(
         precond, enabled=args.kfac_autotune, trace_dir=args.trace,
-        variant=args.kfac_name, log=log)
+        log=log)
     tracer, reg = obs.setup_trainer(trace_dir=args.trace,
                                     prom_file=args.prom_file,
                                     tuner=tuner)

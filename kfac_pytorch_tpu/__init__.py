@@ -27,7 +27,7 @@ except ModuleNotFoundError as _e:  # pragma: no cover - jax-less lanes
         raise
     # jax-less environments (the CI fleet-sim and lint jobs, a bare
     # coordination host) still get the stdlib-only planes below —
-    # coord/, service/, resilience/, sim/, perfmodel — while the
+    # coord/, service/, resilience/, sim/ — while the
     # optimizer surface stays absent and any use of it raises the
     # original, informative ModuleNotFoundError.
     _jax = None

@@ -19,7 +19,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 #: deliberately out: they monkeypatch, fake preconditioners and read
 #: scratch env vars by design; the contracts below bind the shipped
 #: tree. (A rule further narrows this through its ``scope``.)
-DEFAULT_ROOTS = ('kfac_pytorch_tpu', 'examples', 'scripts', 'bench.py')
+DEFAULT_ROOTS = ('kfac_pytorch_tpu', 'examples', 'scripts')
 
 #: suppression comment grammar::
 #:

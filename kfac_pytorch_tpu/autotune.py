@@ -3,9 +3,7 @@
 The reference leaves every performance knob — ``kfac_update_freq`` /
 ``fac_update_freq``, the comm mode, the wire dtype — to hand-tuned shell
 configs (``configs/``, ``train_*.sh``; the paper tunes them per
-model/cluster by hand). This repo grew the three ingredients of a closed
-loop without the loop itself: ``perfmodel.py`` predicts per-phase costs,
-``obs/drift.py`` measures the gap, and three *independent* controllers
+model/cluster by hand). In this repo three *independent* controllers
 mutated the same ``KFAC`` attributes with last-writer-wins semantics
 (``KFACParamScheduler._apply``, ``StragglerGovernor``'s stretch ladder,
 and the elastic rescale hooks). This module closes the loop in two
@@ -42,15 +40,13 @@ deterministic synthetic feed in tests), it hill-climbs the bounded knob
 ladder (frequency doublings/halvings, the fp32→bf16→int8 wire ladder)
 one probe window at a time, with hysteresis (dwell windows after a
 commit, cooldown after a revert) so compiled variants churn rarely.
-Before any measurement exists it seeds from ``perfmodel.predict``
-priors. Every improving candidate must pass the ``obs/drift`` band
-gate before committing: on the modeled chip a measured phase ratio
-outside the [optimistic, conservative] band VETOES the change — the
-tuner can never silently regress a modeled phase; elsewhere the gate is
-advisory. Decisions emit trace instants, resilience counters, log lines
-in the shared ``incident.EVENT_PATTERNS`` grammar (so ``kfac-obs``
-renders tuning timelines for free), and an append-only JSONL decision
-log (the CI artifact).
+It starts from the knobs the preconditioner was built with and compares
+measured windows only; a candidate commits when its window beats the
+baseline by ``rel_improve`` and the ``quality_gate`` counter did not
+rise while it was probed. Decisions emit trace instants, resilience
+counters, log lines in the shared ``incident.EVENT_PATTERNS`` grammar
+(so ``kfac-obs`` renders tuning timelines for free), and an append-only
+JSONL decision log (the CI artifact).
 
 Stdlib-only at import time (jax / obs bridges are lazy and guarded), so
 the module stays importable from supervisors and analysis tools.
@@ -438,9 +434,7 @@ class KnobArbiter:
 
 def _taxonomy_seconds(marginals):
     """{'decomp+gather': s} host labels -> ledger taxonomy names
-    ('ComputeInverse+CommunicateInverse'), matching
-    ``obs.drift.measured_from_phase_timers`` semantics (seconds in,
-    seconds out)."""
+    ('ComputeInverse+CommunicateInverse'); seconds in, seconds out."""
     from kfac_pytorch_tpu.obs.trace import PHASE_TAXONOMY
     out = {}
     for label, s in marginals.items():
@@ -538,38 +532,6 @@ def decide_comm_mode(bytes_by_mode, kfac_update_freq):
     return min(per_step, key=per_step.get), per_step
 
 
-def prior_best_freq(predicted, variant, ladder, fac_update_freq=1,
-                    anchor='central', slack=0.02, decomp_impl=None):
-    """Seed ``kfac_update_freq`` from the analytic perf model before any
-    measurement exists. Predicted steady step time (model + precondition
-    + factor/fac_freq + decomposition/F) is monotone in F — amortizing
-    more is never slower — so "fastest" alone would always pick the
-    ladder top and needlessly stale the preconditioner. The prior is
-    therefore the SMALLEST ladder value within ``slack`` (2%) of the
-    asymptotic steady time: maximum freshness once further stretching
-    is perf noise. Returns None when the block carries no usable phases
-    (the controller then starts from the configured value)."""
-    try:
-        from kfac_pytorch_tpu.perfmodel import prior_phase_costs
-        ph = prior_phase_costs(predicted, variant=variant, anchor=anchor,
-                               decomp_impl=decomp_impl)
-    except Exception:  # noqa: BLE001 — priors are best-effort
-        return None
-    if not ph:
-        return None
-
-    def steady(F):
-        return (ph['model'] + ph['precondition']
-                + ph['factor'] / max(1, fac_update_freq)
-                + ph['decomp'] / F)
-
-    floor = steady(max(ladder))
-    for F in sorted(ladder):
-        if steady(F) <= floor * (1.0 + slack):
-            return F
-    return max(ladder)
-
-
 class KnobController:
     """Bounded online hill-climb over the runtime knob ladder.
 
@@ -584,7 +546,7 @@ class KnobController:
       neighboring knob value (frequency x2 / ÷2 within
       ``freq_bounds``, or the next wire dtype on the ladder);
     - commit the candidate only if its window beats the baseline by
-      ``rel_improve`` AND the drift gate does not veto; otherwise
+      ``rel_improve`` AND the quality gate does not veto; otherwise
       revert and put that candidate on ``cooldown``;
     - after a commit, dwell ``dwell_windows`` windows before the next
       probe (hysteresis: no knob flap inside the dwell);
@@ -595,12 +557,9 @@ class KnobController:
     Frequency tuning trades preconditioner freshness for step time —
     ``freq_bounds`` caps how far the tuner may move from the
     configured cadence (default: no lower than 1, no higher than 8x
-    the starting value). The drift veto consults
-    ``obs.drift.drift_block`` over the window's per-phase marginals:
-    verdict 'drift' (only possible on the modeled chip) rejects the
-    candidate; elsewhere the gate is advisory and violations are only
-    counted. While a straggler stretch is in force the controller
-    discards windows — a host emergency is not a tuning signal.
+    the starting value). While a straggler stretch is in force the
+    controller discards windows — a host emergency is not a tuning
+    signal.
     """
 
     def __init__(self, precond, *, window=16, settle=2, rel_improve=0.03,
@@ -609,9 +568,8 @@ class KnobController:
                        'comm_precision', 'decomp_impl', 'comm_mode',
                        'capture_impl'),
                  freq_bounds=None, comm_precisions=COMM_PRECISIONS,
-                 predicted=None, platform=None, variant=None,
-                 anchor='central', decision_log=None, log=None,
-                 clock=time.monotonic, quality_gate=None):
+                 decision_log=None, log=None, clock=time.monotonic,
+                 quality_gate=None):
         if window < 2:
             raise ValueError(f'window must be >= 2, got {window}')
         self.precond = precond
@@ -627,10 +585,6 @@ class KnobController:
         self.freq_bounds = (tuple(freq_bounds) if freq_bounds
                             else (1, max(8, kf0 * 8)))
         self.comm_precisions = tuple(comm_precisions)
-        self.predicted = predicted
-        self.platform = platform
-        self.variant = variant or getattr(precond, 'variant', 'inverse_dp')
-        self.anchor = anchor
         # numerical-health gate: a zero-arg callable returning a
         # monotone "badness" counter (e.g. the HealthMonitor's skipped-
         # batch + escalation total). Sampled when a probe starts and
@@ -642,7 +596,6 @@ class KnobController:
         # math; this gate protects the TUNING DECISION).
         self.quality_gate = quality_gate
         self._probe_quality = None
-        self.quality_vetoes = 0
         self.decision_log = decision_log
         import logging
         self.log = log if log is not None else logging.getLogger(__name__)
@@ -662,13 +615,11 @@ class KnobController:
         self._rotation = 0
         self._dwell_left = 0
         self._steady_since = None
-        self._seeded = 'seed' if predicted is not None else 'done'
         self.comm_mode_choice = None
         # counters / artifacts
         self.commits = 0
         self.reverts = 0
         self.vetoes = 0
-        self.advisory_violations = 0
         self.decisions = deque(maxlen=256)
         self.last_window = None
 
@@ -693,8 +644,6 @@ class KnobController:
         ('pred'/'stats'/'decomp'/'gather'); ``seconds`` its wall time.
         Deterministic by construction — no clock is read here."""
         self._step = int(step) if step is not None else self._step + 1
-        if self._seeded == 'seed':
-            self._seed()
         if self._settle_left > 0:
             # post-change settle: recompiles / first traces of a fresh
             # knob set must not pollute the window
@@ -708,110 +657,6 @@ class KnobController:
         self._n += 1
         if self._n >= self.window:
             self._window_done()
-
-    # -- seeding -----------------------------------------------------------
-
-    def _freq_ladder(self):
-        lo, hi = self.freq_bounds
-        ladder, v = [], max(1, int(lo))
-        while v <= hi:
-            ladder.append(v)
-            v *= 2
-        return ladder or [max(1, int(lo))]
-
-    def _seed(self):
-        self._seeded = 'done'
-        # kernels first: the freq prior prices the decomposition phase
-        # at the kernel the run will actually execute
-        self._seed_decomp_impl()
-        self._seed_capture_impl()
-        self._seed_freq()
-
-    def _seed_freq(self):
-        if 'kfac_update_freq' not in self.tune:
-            return
-        best = prior_best_freq(
-            self.predicted, self.variant, self._freq_ladder(),
-            fac_update_freq=getattr(self.precond, 'fac_update_freq', 1)
-            or 1, anchor=self.anchor,
-            decomp_impl=getattr(self.precond, 'decomp_impl', None))
-        cur = getattr(self.precond, 'kfac_update_freq', None)
-        if best is None or cur is None or best == cur:
-            return
-        self.arbiter.propose('tuner', kfac_update_freq=best)
-        self._decision('seed', knob='kfac_update_freq', frm=cur, to=best)
-        self.log.info('autotune: seeded kfac_update_freq=%d from '
-                      'perfmodel prior (%s)', best, self.anchor)
-        self._instant('autotune_seed', kfac_update_freq=best)
-        self._settle_left = self.settle
-        # the seeded value becomes the config the first baseline measures
-
-    def _seed_decomp_impl(self):
-        """Seed the decomposition-kernel rung from the perf model's
-        GEMM-roofline priors (perfmodel.decomp_impl_priors): when the
-        iterative kernel's predicted decomposition phase undercuts the
-        cold kernel's, start there — the fenced eigh constants say the
-        gap is seconds-per-refresh on the modeled chip, too expensive
-        to discover by probing alone."""
-        if 'decomp_impl' not in self.tune:
-            return
-        cur = getattr(self.precond, 'decomp_impl', None)
-        method = getattr(self.precond, 'method', None)
-        if cur is None or method not in DECOMP_LADDERS:
-            return
-        try:
-            from kfac_pytorch_tpu.perfmodel import decomp_impl_priors
-            priors = decomp_impl_priors(self.predicted, method,
-                                        anchor=self.anchor)
-        except Exception:  # noqa: BLE001 — priors are best-effort
-            return
-        if not priors:
-            return
-        best = min(priors, key=priors.get)
-        eff = (DECOMP_LADDERS[method][1] if cur == 'auto' else cur)
-        if best == eff:
-            return
-        self.arbiter.propose('tuner', decomp_impl=best)
-        self._decision('seed', knob='decomp_impl', frm=cur, to=best,
-                       prior_s=priors)
-        self.log.info('autotune: seeded decomp_impl=%s from perfmodel '
-                      'prior (%s)', best, self.anchor)
-        self._instant('autotune_seed', decomp_impl=best)
-        self._settle_left = self.settle
-
-    def _seed_capture_impl(self):
-        """Seed the capture-kernel rung from the perf model's fusion
-        priors (perfmodel.capture_impl_priors): when the fused Pallas
-        capture's predicted ComputeFactor phase undercuts the unfused
-        XLA path's, start there — the win is the skipped HBM patch
-        matrix and the folded EMA/quantize epilogues, which the roofline
-        prices without a probe."""
-        if 'capture_impl' not in self.tune:
-            return
-        cur = getattr(self.precond, 'capture_impl', None)
-        if cur is None:
-            # None = the legacy capture path AND the rung hidden from
-            # the tuner (preconditioner.CAPTURE_IMPLS contract)
-            return
-        try:
-            from kfac_pytorch_tpu.perfmodel import capture_impl_priors
-            priors = capture_impl_priors(self.predicted,
-                                         anchor=self.anchor)
-        except Exception:  # noqa: BLE001 — priors are best-effort
-            return
-        if not priors:
-            return
-        best = min(priors, key=priors.get)
-        eff = (CAPTURE_LADDER[1] if cur == 'auto' else cur)
-        if best == eff:
-            return
-        self.arbiter.propose('tuner', capture_impl=best)
-        self._decision('seed', knob='capture_impl', frm=cur, to=best,
-                       prior_s=priors)
-        self.log.info('autotune: seeded capture_impl=%s from perfmodel '
-                      'prior (%s)', best, self.anchor)
-        self._instant('autotune_seed', capture_impl=best)
-        self._settle_left = self.settle
 
     # -- the window --------------------------------------------------------
 
@@ -837,7 +682,7 @@ class KnobController:
             self._maybe_comm_mode(measured)
             self._next_probe()
         elif self.state == 'probe':
-            self._judge(t, measured)
+            self._judge(t)
         elif self.state == 'dwell':
             self.baseline_t = t  # track drift of the committed config
             self._dwell_left -= 1
@@ -988,18 +833,17 @@ class KnobController:
         except Exception:  # noqa: BLE001
             return None
 
-    def _judge(self, t, measured):
+    def _judge(self, t):
         knob, old, new = self._candidate
         improved = t < self.baseline_t * (1 - self.rel_improve)
-        vetoed = improved and self._drift_veto(measured, knob, new)
-        if improved and not vetoed:
+        vetoed = False
+        if improved:
             q0, q1 = self._probe_quality, self._quality()
             if q0 is not None and q1 is not None and q1 > q0:
                 # the probe window regressed accuracy (health events
                 # fired): a faster-but-wrong rung never commits
                 vetoed = True
                 self.vetoes += 1
-                self.quality_vetoes += 1
                 self._bump('autotune_vetoes')
                 self._decision('veto', knob=knob, value=new,
                                reason='quality',
@@ -1048,54 +892,6 @@ class KnobController:
             self._settle_left = self.settle
             self._next_probe()
 
-    # -- gates -------------------------------------------------------------
-
-    def _drift_veto(self, measured, knob, value):
-        """The obs/drift band gate over this window's phase marginals.
-        Verdict 'drift' — only reachable when the platform IS the chip
-        the perf model describes — vetoes the candidate; on any other
-        platform the gate is advisory (violations counted, commit
-        allowed). No predicted block = no gate."""
-        if not self.predicted:
-            return False
-        try:
-            from kfac_pytorch_tpu.obs import drift
-            verdict, violations = drift.gate(
-                {k: v for k, v in measured.items()
-                 if k not in ('step_mean', 'step_max')},
-                self.predicted, platform=self.platform,
-                variant=self.variant, anchor=self.anchor,
-                comm_precision=getattr(self.precond, 'comm_precision',
-                                       'fp32') or 'fp32',
-                # bind ComputeInverse to the kernel the probe actually
-                # ran — without this, committing an iterative rung on
-                # the modeled chip would land seconds under the fenced
-                # full-eigh band and the gate would veto the very win
-                # it exists to protect
-                decomp_impl=getattr(self.precond, 'decomp_impl', None),
-                # likewise bind ComputeFactor to the capture kernel the
-                # probe actually ran — the fused band sits well under
-                # the unfused one on the modeled chip
-                capture_impl=getattr(self.precond, 'capture_impl', None),
-                source='autotune')
-            if verdict == 'drift':
-                self.vetoes += 1
-                self._bump('autotune_vetoes')
-                self._decision('veto', knob=knob, value=value,
-                               violations=violations)
-                self.log.warning(
-                    'autotune: drift veto — knob %s %s rejected '
-                    '(violations=%s) at step %d', knob, value,
-                    ','.join(violations), self._step)
-                self._instant('autotune_veto', knob=knob,
-                              violations=violations)
-                return True
-            if violations:
-                self.advisory_violations += len(violations)
-        except Exception:  # noqa: BLE001 — the gate must never take the
-            return False   # trainer down; an error gate is no gate
-        return False
-
     def _maybe_comm_mode(self, measured):
         """One-shot analytic comm-mode verdict from the layout's
         per-step collective bytes at the current cadence (comm_inverse
@@ -1143,7 +939,7 @@ class KnobController:
                     f.write(json.dumps(d) + '\n')
             except OSError:
                 pass
-        if kind in ('seed', 'commit', 'revert'):
+        if kind in ('commit', 'revert'):
             # every knob movement refreshes the adopted snapshot, so a
             # kfac-serve requeue always relaunches at the latest tuned
             # cadence (PR 10 follow-on)
@@ -1232,8 +1028,8 @@ class KnobController:
         registry.counter('autotune/vetoes').set_total(self.vetoes)
 
     def report(self):
-        """The ``autotune`` block for ``bench.py`` extras / smoke
-        artifacts: final knob state + the decision-log tail."""
+        """The ``autotune`` block of the smoke artifacts: final knob
+        state + the decision-log tail."""
         return {
             'enabled': True,
             'state': self.state,
@@ -1243,22 +1039,16 @@ class KnobController:
             'commits': self.commits,
             'reverts': self.reverts,
             'vetoes': self.vetoes,
-            'quality_vetoes': self.quality_vetoes,
-            'advisory_violations': self.advisory_violations,
             'last_window_s': (self.last_window or {}).get('time_s'),
             'decisions_tail': list(self.decisions)[-10:],
         }
 
 
-def controller_from_args(precond, *, enabled, trace_dir=None,
-                         predicted=None, variant=None, log=None,
+def controller_from_args(precond, *, enabled, trace_dir=None, log=None,
                          quality_gate=None):
     """The trainers' shared constructor: returns a
     :class:`KnobController` (decision log under ``trace_dir`` when
-    tracing is on) or None. ``predicted`` should be the perf-model
-    block ONLY when the run matches the workload the model describes
-    (the imagenet resnet50 bs32 config) — the drift gate judges phase
-    ratios against it; other workloads run ungated (advisory-free).
+    tracing is on) or None.
     ``quality_gate``: a zero-arg monotone badness counter — a probe
     window that raised it never commits, whatever its step time said.
     The trainers construct the tuner BEFORE the HealthMonitor exists,
@@ -1269,12 +1059,5 @@ def controller_from_args(precond, *, enabled, trace_dir=None,
         return None
     decision_log = (os.path.join(trace_dir, 'autotune-decisions.jsonl')
                     if trace_dir else None)
-    platform = None
-    try:
-        import jax
-        platform = getattr(jax.devices()[0], 'device_kind', None)
-    except Exception:  # noqa: BLE001 — platform is advisory metadata
-        pass
-    return KnobController(precond, predicted=predicted, platform=platform,
-                          variant=variant, decision_log=decision_log,
+    return KnobController(precond, decision_log=decision_log,
                           log=log, quality_gate=quality_gate)

@@ -303,8 +303,8 @@ ENV: Tuple[EnvVar, ...] = (
       'wall-clock origin of the outage windows'),
     # -- bench / scripts -----------------------------------------------
     E('KFAC_AUTOTUNE_ASSERT', 'flag', 'scripts/autotune_smoke.py',
-      'autotune smoke: fail (not just report) when the tuner loses to '
-      'hand-tuned'),
+      'autotune smoke: fail (not just report) when a leg misses its '
+      'planted optimum'),
     E('KFAC_FLASH_TQ', 'int', 'scripts/bench_flash.py',
       'flash-attention bench: query tile override'),
     E('KFAC_FLASH_TK', 'int', 'scripts/bench_flash.py',

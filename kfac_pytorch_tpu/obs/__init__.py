@@ -24,9 +24,6 @@ none compose. This package is the common layer they all report through:
 - :mod:`aggregate` — the ``kfac-obs`` console entry: merge per-host
   trace JSONL, run logs and incident reports into one clock-aligned
   pod timeline (the ROADMAP "pod-level timeline" open item).
-- :mod:`drift` — the perf-model feedback loop: measured per-phase wall
-  times vs ``perfmodel.py``'s ``predicted`` block, emitted as per-phase
-  drift ratios in every ``bench.py`` JSON (even on CPU rounds).
 
 Everything here is dependency-free stdlib (jax is touched only where the
 process has already loaded it: ``trace.annotation``, the span's second
@@ -36,9 +33,9 @@ stays importable on machines with no accelerator stack at all.
 
 import os as _os
 
-from kfac_pytorch_tpu.obs import drift, metrics, trace
+from kfac_pytorch_tpu.obs import metrics, trace
 
-__all__ = ['trace', 'metrics', 'drift', 'setup_trainer']
+__all__ = ['trace', 'metrics', 'setup_trainer']
 
 
 def setup_trainer(trace_dir=None, prom_file=None, governor=None,

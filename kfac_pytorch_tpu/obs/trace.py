@@ -355,8 +355,7 @@ def flush():
 #: the exclude_parts ledger taxonomy the engine's named_scopes and the
 #: reference's time_breakdown use. 'pred' is the preconditioning apply
 #: (no exclude_parts name of its own — the reference folds it into the
-#: KFAC bucket); kept distinct here as 'Precondition' to match
-#: perfmodel.phases_s.
+#: KFAC bucket); kept distinct here as 'Precondition'.
 PHASE_TAXONOMY = {
     'stats': 'ComputeFactor',
     'decomp': 'ComputeInverse',

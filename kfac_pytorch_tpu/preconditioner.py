@@ -1167,8 +1167,7 @@ class KFAC:
                 # single-device local stats: the whole capture chain
                 # (patch-extract -> factor GEMM -> EMA) collapses into
                 # one fused kernel per factor — the UpdateFactors pass
-                # disappears from the trace by design (its cost is
-                # modeled under ComputeFactor_pallas in perfmodel.py)
+                # disappears from the trace by design
                 with jax.named_scope('kfac.ComputeFactor'):
                     factors = engine.update_factors_fused(
                         plan, factors, acts, gs, self.batch_averaged,

@@ -174,15 +174,12 @@ _PATTERNS = (
         r'global_batch=(?P<global_batch>\d+) '
         r'lr=(?P<lr>[\d.eE+-]+) lr_factor=(?P<lr_factor>[\d.eE+-]+)')),
     # the closed-loop autotuner (kfac_pytorch_tpu/autotune.py): one
-    # event per controller decision — seed from the perf-model prior,
-    # probe/commit/revert of one knob candidate, the drift-band veto,
-    # steady-state arrival, and the advisory comm-mode verdict — so a
-    # kfac-obs timeline renders the whole tuning trajectory from the
-    # run logs with zero new aggregate code (the same shared-grammar
-    # contract the grow/partition stories use)
-    ('autotune_seed', re.compile(
-        r'autotune: seeded kfac_update_freq=(?P<kfac>\d+) from '
-        r'perfmodel prior \((?P<anchor>\w+)\)')),
+    # event per controller decision — probe/commit/revert of one knob
+    # candidate, the quality-gate veto, steady-state arrival, and the
+    # analytic comm-mode verdict — so a kfac-obs timeline renders the
+    # whole tuning trajectory from the run logs with zero new aggregate
+    # code (the same shared-grammar contract the grow/partition stories
+    # use)
     ('autotune_probe', re.compile(
         r'autotune: probing (?P<knob>[\w_]+) (?P<from>\S+) -> '
         r'(?P<to>\S+) at step (?P<step>\d+) \(window (?P<window>\d+)\)')),
@@ -196,9 +193,9 @@ _PATTERNS = (
         r'(?P<to>\S+) \(no improvement: (?P<baseline_s>[\d.]+)s -> '
         r'(?P<probe_s>[\d.]+)s\) at step (?P<step>\d+)')),
     ('autotune_veto', re.compile(
-        r'autotune: drift veto — knob (?P<knob>[\w_]+) (?P<value>\S+) '
-        r'rejected \(violations=(?P<violations>[^)]*)\) at step '
-        r'(?P<step>\d+)')),
+        r'autotune: quality veto — knob (?P<knob>[\w_]+) (?P<value>\S+) '
+        r'rejected \(\+(?P<health_events>[\d.eE+-]+) health events in '
+        r'the probe window\) at step (?P<step>\d+)')),
     ('autotune_steady', re.compile(
         r'autotune: steady state — knobs fac=(?P<fac>\d+) '
         r'kfac=(?P<kfac>\d+) comm_precision=(?P<comm_precision>\w+) '

@@ -7,8 +7,8 @@ shrink barrier and lineage fencing (``resilience.elastic``), the
 3-replica quorum coordination plane (``coord.replicated`` over
 :class:`TcpKvServer` stores) — into one discrete-event loop at
 1,000-10,000 simulated hosts, with every clock, rng and process seam
-injected. Step times are priced from the :mod:`perfmodel` roofline
-scenarios; replica and host faults come from a seeded schedule; the
+injected. A simulated step has one fixed length (``fleet.ITER_S``);
+replica and host faults come from a seeded schedule; the
 output is a semantic event trace (JSONL) that is byte-identical across
 runs with the same seed.
 

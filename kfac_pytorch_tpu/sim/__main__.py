@@ -27,8 +27,6 @@ def main(argv=None):
     p.add_argument('--hosts', type=int, default=1000)
     p.add_argument('--pod-size', type=int, default=8)
     p.add_argument('--seed', type=int, default=0)
-    p.add_argument('--scenario', default='central',
-                   choices=('optimistic', 'central', 'conservative'))
     p.add_argument('--kill-pods', type=int, default=12)
     p.add_argument('--partition-pods', type=int, default=4)
     p.add_argument('--jobs', type=int, default=10)
@@ -61,7 +59,7 @@ def main(argv=None):
         log.setLevel(logging.INFO)
 
     cfg = SimConfig(hosts=args.hosts, pod_size=args.pod_size,
-                    seed=args.seed, scenario=args.scenario,
+                    seed=args.seed,
                     kill_pods=args.kill_pods,
                     partition_pods=args.partition_pods,
                     jobs=args.jobs, fail_jobs=args.fail_jobs,

@@ -51,7 +51,6 @@ import os
 import random
 import threading
 
-from kfac_pytorch_tpu import perfmodel
 from kfac_pytorch_tpu.coord import (
     CoordGiveUp, CoordTimeout, ReplicatedKvBackend, RetryingBackend,
     TcpKvBackend, TcpKvServer)
@@ -69,6 +68,10 @@ from kfac_pytorch_tpu.service import AdmissionController
 #: wall-shaped values are simulated too (never ``time.time()``)
 WALL0 = 1_700_000_000.0
 
+#: sim seconds per simulated training step: the simulator's step length,
+#: from which job durations are laid out — not a timing of anything
+ITER_S = 0.1311
+
 
 @dataclasses.dataclass(frozen=True)
 class SimConfig:
@@ -77,7 +80,6 @@ class SimConfig:
     hosts: int = 1000
     pod_size: int = 8
     seed: int = 0
-    scenario: str = 'central'       # perfmodel roofline scenario
     kill_pods: int = 12             # pods that lose one host (SIGKILL)
     partition_pods: int = 4         # pods split minority | majority
     jobs: int = 10
@@ -302,9 +304,6 @@ class FleetSim:
                 'partition': round(rng.uniform(8.0, 16.0), 3),
                 'minority': minority,
                 'first': rng.choice(['minority', 'majority'])}
-        iter_s = perfmodel.predict()[
-            cfg.scenario]['inverse_dp_freq10']['iter_s']
-        self.iter_s = float(iter_s)
         # unequal tenant weights make the fair-share property visible:
         # with mixed demand the scheduler's weighted-dominant-share
         # ordering must converge usage toward 1:2:4, and no nonzero-
@@ -317,7 +316,7 @@ class FleetSim:
             self.job_plan[j] = {
                 'submit': round(0.5 + 0.8 * (j - 1), 3),
                 'steps': steps,
-                'duration': round(steps * self.iter_s, 3),
+                'duration': round(steps * ITER_S, 3),
                 'fail_rc': 115 if j <= cfg.fail_jobs else 0}
         # the preemption drill: late, wide, high-priority and NOT
         # preemptible — the pool is already packed when these land, so
@@ -328,7 +327,7 @@ class FleetSim:
             self.job_plan[jid] = {
                 'submit': round(1.8 + 0.9 * (k - 1), 3),
                 'steps': steps,
-                'duration': round(steps * self.iter_s, 3),
+                'duration': round(steps * ITER_S, 3),
                 'fail_rc': 0, 'priority': 10,
                 # full-pool width: placing it REQUIRES suspending
                 # every running preemptible job
@@ -773,8 +772,7 @@ class FleetSim:
         random.seed(cfg.seed)
         self._trace('sim_start', hosts=cfg.hosts,
                     pods=len(self.pods), pod_size=cfg.pod_size,
-                    seed=cfg.seed, scenario=cfg.scenario,
-                    iter_s=round(self.iter_s, 4))
+                    seed=cfg.seed, iter_s=ITER_S)
         for idx, t0, t1 in cfg.replica_outages:
             self.loop.at(t0, functools.partial(self._kill_replica, idx))
             self.loop.at(t1, functools.partial(self._restore_replica,

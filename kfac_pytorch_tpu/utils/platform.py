@@ -25,8 +25,8 @@ def enable_compile_cache():
     """Point JAX's persistent compilation cache at
     :func:`compile_cache_dir` and return the directory. Where the
     environment names one, JAX already reads it and this sets no other.
-    The one place in the repo that places the cache — the trainers,
-    ``bench.py`` and ``chip_smoke.py`` all call it."""
+    The one place in the repo that places the cache — the trainers
+    and ``chip_smoke.py`` call it."""
     path = compile_cache_dir()
     if not os.environ.get('JAX_COMPILATION_CACHE_DIR'):
         import jax

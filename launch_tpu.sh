@@ -100,9 +100,8 @@ fi
 # controller in every trainer of the run (the trainers read it as the
 # --kfac-autotune default; an explicit flag still wins). The controller
 # hill-climbs kfac/fac_update_freq and the comm wire dtype from
-# measured step times through the single knob arbiter, with drift-band
-# vetoes on the modeled workload; decisions land in the run log
-# (kfac-obs renders them) and, under KFAC_TRACE_DIR, in
+# measured step times through the single knob arbiter; decisions land
+# in the run log (kfac-obs renders them) and, under KFAC_TRACE_DIR, in
 # <dir>/autotune-decisions.jsonl. See README "Closed-loop autotuning".
 if [ -n "$KFAC_AUTOTUNE" ]; then
   case "$KFAC_AUTOTUNE" in

@@ -16,8 +16,7 @@ wire width (the comm_precision modes of parallel/collectives.py), and
 ``--analytic MODEL`` prints the closed-form FactorComm / InverseComm /
 PredComm payload-byte model per wire dtype (FactorPlan.comm_volume) with
 the compression factor each dtype buys — the analytic side of the
-HLO-measured ledger in scripts/comm_count.py, and the input the drift
-gate (obs/drift.py) scales comm predictions by for compressed runs.
+HLO-measured ledger in scripts/comm_count.py.
 
 Usage: python scripts/comm_models.py [--sizes-kb 4 64 1024 16384]
            [--csv out] [--wire-dtype fp32|bf16|int8]

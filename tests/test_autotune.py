@@ -17,17 +17,17 @@ Pins the tentpole contracts:
    synthetic phase-time feed (no wall clock anywhere), with hysteresis
    (no knob flap inside the dwell window, cooldown after a revert,
    bounded probing in steady state).
-4. The drift-band gate: on the modeled chip a measured phase ratio
-   outside [optimistic, conservative] VETOES an otherwise-improving
-   candidate; on any other platform the same feed commits (advisory).
+4. The controller compares measurements only: it starts from the
+   knobs the preconditioner was built with, and an improving candidate
+   commits unless the ``quality_gate`` counter rose in its probe window.
 5. Knob changes reuse the compiled variant cache (frequency moves
    compile nothing new when revisited) while a ``comm_precision``
    change clears it through the registered invalidator — and the
    mid-run fp32 -> bf16 -> fp32 wire switch keeps the EF-residual
    state structure consistent and checkpoints restorable.
-6. Decisions are artifacts: JSONL decision log, ``report()`` block for
-   bench extras, and log lines in the shared ``incident``
-   event grammar (kfac-obs renders tuning timelines for free).
+6. Decisions are artifacts: JSONL decision log, ``report()`` block,
+   and log lines in the shared ``incident`` event grammar (kfac-obs
+   renders tuning timelines for free).
 """
 
 import json
@@ -429,90 +429,86 @@ def test_controller_discards_windows_under_straggler_stretch():
     assert ctl.windows >= 1                   # measuring again
 
 
-def test_controller_seeds_from_perfmodel_prior():
-    """Before any measurement: an eigen-variant predicted block (huge
-    fenced decomposition cost) seeds kfac_update_freq to the ladder
-    value minimizing predicted steady step time."""
-    from kfac_pytorch_tpu import perfmodel
-    pre = _FakePrecond(fac=1, kfac=1)
+def test_first_window_starts_from_constructed_knobs(caplog):
+    """No seeding step: the first record moves no knob, the baseline
+    window measures the knobs the preconditioner was built with, and
+    the first decision is a probe (no 'seed', no 'autotune: seeded'
+    line)."""
+    pre = _GuardedPrecond(fac=2, kfac=4)
+    built = autotune._capture(pre)
+    log = logging.getLogger('test_autotune_first_window')
     ctl = autotune.KnobController(pre, window=4, settle=0,
-                                  tune=('kfac_update_freq',),
-                                  freq_bounds=(1, 512),
-                                  predicted=perfmodel.predict_block(),
-                                  variant='eigen_dp')
-    ctl.record(('pred',), 0.01)               # first record triggers seed
-    # decomp ~73 s vs model ~0.11 s: the prior pushes to the ladder top
-    assert pre.kfac_update_freq == 512
-    assert any(d['kind'] == 'seed' for d in ctl.decisions)
+                                  steady_every=0, freq_bounds=(1, 8),
+                                  log=log)
+    with caplog.at_level(logging.INFO, logger=log.name):
+        ctl.record(('pred',), 0.01)
+        assert autotune._capture(pre) == built and not ctl.decisions
+        for _ in range(3):
+            ctl.record(('pred',), 0.01)
+    assert ctl.last_window['window'] == 1
+    assert ctl.last_window['knobs'] == built
+    assert ctl.decisions[0]['kind'] == 'probe'
+    assert not any(d['kind'] == 'seed' for d in ctl.decisions)
+    assert 'seeded' not in caplog.text
 
 
-def test_prior_best_freq_prefers_cheap_decomp_low_freq():
-    predicted = {'scenarios': {'central': {'phases_s': {
-        'Model': 0.1, 'Precondition': 0.01, 'ComputeFactor': 0.01,
-        'ComputeInverse_chol': 0.001,
-        'ComputeInverse_eigh_full': 50.0}}}}
-    # Cholesky variant: decomp negligible -> freq 1 is optimal
-    assert autotune.prior_best_freq(predicted, 'inverse_dp',
-                                    [1, 2, 4, 8]) == 1
-    # eigen variant: decomp dominant -> max freq
-    assert autotune.prior_best_freq(predicted, 'eigen_dp',
-                                    [1, 2, 4, 8]) == 8
-    assert autotune.prior_best_freq({'scenarios': {}}, 'eigen_dp',
-                                    [1, 2]) is None
+@pytest.mark.parametrize('make', [
+    lambda pre, **kw: autotune.KnobController(pre, **kw),
+    lambda pre, **kw: autotune.controller_from_args(pre, enabled=True,
+                                                    **kw),
+], ids=['KnobController', 'controller_from_args'])
+def test_model_arguments_are_rejected(make):
+    """The analytic model and everything that indexed it are gone: a
+    stale caller fails loudly."""
+    pre = _FakePrecond()
+    assert make(pre) is not None
+    for name, value in (('predicted', {'scenarios': {}}),
+                        ('platform', 'TPU v5e'),
+                        ('variant', 'eigen_dp'), ('anchor', 'central')):
+        with pytest.raises(TypeError, match=name):
+            make(pre, **{name: value})
 
 
 # ---------------------------------------------------------------------------
-# the drift gate: veto on the modeled chip, advisory elsewhere
+# the one gate: quality_gate
 # ---------------------------------------------------------------------------
 
-def _veto_harness(platform):
-    """Probe window improves (passes the objective) but its measured
-    'Precondition' marginal sits far outside the predicted band."""
-    from kfac_pytorch_tpu import perfmodel
+def _veto_harness(gate=None, log=None):
+    """Baseline window at 0.6 s steps, then a probe window at 0.5 s:
+    the candidate passes the objective. ``gate`` is 'rises' (the
+    badness counter goes up in the probe window), 'flat' or None."""
     pre = _FakePrecond(fac=1, kfac=4)
-    ctl = autotune.KnobController(pre, window=4, settle=0,
-                                  rel_improve=0.03, dwell_windows=1,
-                                  cooldown=2, steady_every=0,
-                                  tune=('kfac_update_freq',),
-                                  freq_bounds=(1, 8),
-                                  predicted=perfmodel.predict_block(),
-                                  platform=platform, variant='eigen_dp')
-    ctl._seeded = 'done'                      # isolate the gate from seeding
-    for _ in range(4):                        # baseline window: 0.6 s steps
+    events = {'n': 0}
+    ctl = autotune.KnobController(
+        pre, window=4, settle=0, rel_improve=0.03, dwell_windows=1,
+        cooldown=2, steady_every=0, tune=('kfac_update_freq',),
+        freq_bounds=(1, 8), log=log,
+        quality_gate=(lambda: events['n']) if gate else None)
+    for _ in range(4):                        # baseline window
         ctl.record(('pred',), 0.6)
     assert ctl.state == 'probe'
-    for _ in range(4):                        # probe window: 0.5 s -> improved
+    for _ in range(4):                        # probe window: improved
+        events['n'] += gate == 'rises'
         ctl.record(('pred',), 0.5)
     return pre, ctl
 
 
-def test_drift_veto_on_modeled_chip():
-    """0.5 s measured Precondition vs a ~0.008 s predicted band on the
-    modeled chip: the candidate improved the objective but is VETOED —
-    the tuner can never silently regress a modeled phase."""
-    pre, ctl = _veto_harness('TPU v5e')
-    assert ctl.vetoes == 1 and ctl.commits == 0
-    assert pre.kfac_update_freq != 8          # the vetoed value never stuck
-    veto = next(d for d in ctl.decisions if d['kind'] == 'veto')
-    assert veto['value'] == 8
-    assert 'Precondition' in veto['violations']
-
-
-def test_drift_gate_advisory_off_the_modeled_chip():
-    """The SAME feed on an unmodeled platform commits: the band is
-    advisory (violations counted, knob applied)."""
-    pre, ctl = _veto_harness('cpu')
-    assert ctl.vetoes == 0 and ctl.commits == 1
-    assert ctl.advisory_violations >= 1
-    assert pre.kfac_update_freq != 4          # the probe value stuck
-
-
-def test_no_predicted_block_means_no_gate():
-    pre = _FakePrecond(fac=1, kfac=4)
-    ctl = autotune.KnobController(pre, window=4, settle=0,
-                                  tune=('kfac_update_freq',))
-    assert ctl._drift_veto({'Precondition': 99.0}, 'kfac_update_freq',
-                           8) is False
+@pytest.mark.parametrize('gate', [None, 'flat', 'rises'])
+def test_improving_candidate_commits_unless_quality_gate_rises(gate):
+    """rel_improve and the quality gate are all that stand between an
+    improving candidate and its commit."""
+    pre, ctl = _veto_harness(gate)
+    if gate == 'rises':
+        assert ctl.vetoes == 1 and ctl.commits == 0
+        assert pre.kfac_update_freq != 8      # the vetoed value never stuck
+        veto = next(d for d in ctl.decisions if d['kind'] == 'veto')
+        assert veto['value'] == 8 and veto['reason'] == 'quality'
+        assert veto['health_events'] == 4
+    else:
+        assert ctl.vetoes == 0 and ctl.commits == 1
+        assert pre.kfac_update_freq != 4      # the probe value stuck
+    assert set(ctl.counts()) == {'autotune_commits', 'autotune_reverts',
+                                 'autotune_vetoes'}
 
 
 # ---------------------------------------------------------------------------
@@ -622,8 +618,7 @@ def test_autotune_log_lines_speak_the_incident_grammar():
                                       freq_bounds=(1, 8), log=log)
         _feed(ctl, pre, _amortized, 400)
         # and one veto line (rig the gate through the harness)
-        _, vctl = _veto_harness('TPU v5e')
-        vctl.log = log
+        _veto_harness('rises', log=log)
     finally:
         log.handlers.clear()
     rep = IncidentReport(host_id=0).scrape_lines(records)
@@ -649,23 +644,13 @@ def test_veto_log_line_speaks_the_grammar():
     log.setLevel(logging.INFO)
     log.addHandler(_Capture())
     try:
-        from kfac_pytorch_tpu import perfmodel
-        pre = _FakePrecond(fac=1, kfac=4)
-        ctl = autotune.KnobController(
-            pre, window=4, settle=0, rel_improve=0.03, dwell_windows=1,
-            cooldown=2, steady_every=0, tune=('kfac_update_freq',),
-            freq_bounds=(1, 8), predicted=perfmodel.predict_block(),
-            platform='TPU v5e', variant='eigen_dp', log=log)
-        ctl._seeded = 'done'
-        for _ in range(4):
-            ctl.record(('pred',), 0.6)
-        for _ in range(4):
-            ctl.record(('pred',), 0.5)
+        _veto_harness('rises', log=log)
     finally:
         log.handlers.clear()
     rep = IncidentReport(host_id=0).scrape_lines(records)
     veto = [e for e in rep.events if e['kind'] == 'autotune_veto']
     assert veto and veto[0]['knob'] == 'kfac_update_freq'
+    assert veto[0]['value'] == 8 and veto[0]['health_events'] == 4
 
 
 # ---------------------------------------------------------------------------
@@ -1026,11 +1011,11 @@ def test_quality_gate_vetoes_accuracy_regressing_rung():
     _feed(ctl, pre, model, 300)
     assert pre.decomp_impl == 'xla'           # the fast-but-wrong rung
     assert ctl.commits == 0                   # never committed
-    assert ctl.quality_vetoes >= 1
+    assert ctl.vetoes >= 1
     assert ctl.state == 'steady'
     vetoes = [d for d in ctl.decisions if d['kind'] == 'veto']
     assert vetoes and vetoes[0].get('reason') == 'quality'
-    assert ctl.report()['quality_vetoes'] == ctl.quality_vetoes
+    assert ctl.report()['vetoes'] == ctl.vetoes
 
 
 def test_arbiter_decomp_impl_is_trace_affecting():
@@ -1051,26 +1036,6 @@ def test_arbiter_decomp_impl_is_trace_affecting():
     arb.adopt_external()
     assert arb.base['decomp_impl'] == 'xla'
     assert 'decomp_impl' not in arb.tuner
-
-
-def test_decomp_impl_seeded_from_perfmodel_prior():
-    """On the modeled chip the fenced eigh constants say the iterative
-    rung wins by orders of magnitude: the controller seeds
-    decomp_impl from the perfmodel prior before any measurement."""
-    from kfac_pytorch_tpu import perfmodel
-    block = perfmodel.predict_block()
-    pre = _DecompPrecond(method='eigh', decomp_impl='xla', kfac=4)
-    ctl = autotune.KnobController(pre, window=8, settle=1,
-                                  tune=('decomp_impl',),
-                                  predicted=block)
-    ctl.record(('pred',), 0.01)               # first record triggers seed
-    assert pre.decomp_impl == 'subspace'
-    seeds = [d for d in ctl.decisions if d['kind'] == 'seed']
-    assert seeds and seeds[0]['knob'] == 'decomp_impl'
-    # the priors themselves: iterative rungs orders under the fenced
-    # QDWH seconds on the modeled chip
-    priors = perfmodel.decomp_impl_priors(block, 'eigh')
-    assert priors['subspace'] < 0.1 * priors['xla']
 
 
 # ---------------------------------------------------------------------------
@@ -1169,7 +1134,7 @@ def test_quality_gate_vetoes_regressing_capture_rung():
     _feed(ctl, pre, model, 300)
     assert pre.capture_impl == 'xla'          # the fast-but-wrong rung
     assert ctl.commits == 0
-    assert ctl.quality_vetoes >= 1
+    assert ctl.vetoes >= 1
     assert ctl.state == 'steady'
     vetoes = [d for d in ctl.decisions if d['kind'] == 'veto']
     assert vetoes and vetoes[0].get('reason') == 'quality'
@@ -1231,23 +1196,3 @@ def test_controller_capture_auto_probes_the_other_rung():
     _feed(ctl, pre, model, 200)
     assert pre.capture_impl == 'xla'
     assert ctl.commits == 1
-
-
-def test_capture_impl_seeded_from_perfmodel_prior():
-    """On the modeled chip the fused capture kernels halve the factor
-    phase's HBM bytes: the controller seeds capture_impl from the
-    perfmodel prior before any measurement."""
-    from kfac_pytorch_tpu import perfmodel
-    block = perfmodel.predict_block()
-    pre = _CapturePrecond(capture_impl='xla', kfac=4)
-    ctl = autotune.KnobController(pre, window=8, settle=1,
-                                  tune=('capture_impl',),
-                                  predicted=block)
-    ctl.record(('pred',), 0.01)               # first record triggers seed
-    assert pre.capture_impl == 'pallas'
-    seeds = [d for d in ctl.decisions if d['kind'] == 'seed']
-    assert seeds and seeds[0]['knob'] == 'capture_impl'
-    # the prior itself: fused strictly under unfused on the HBM-bound
-    # factor phase (CAPTURE_FUSION_BYTES_FACTOR halves the bytes term)
-    priors = perfmodel.capture_impl_priors(block)
-    assert priors['pallas'] < priors['xla']

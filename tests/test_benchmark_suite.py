@@ -1,0 +1,25 @@
+"""The benchmark's own tests, collected under ``tests/`` so that tier-1
+runs them: the configuration files ``BENCHMARK.json`` names, the
+reduction from a trace to the ledger's metrics, and the readers of the
+program's spans (``benchmarks/tests/test_configs.py``, ``test_reduce.py``,
+``test_spans.py``). They stay where the benchmark keeps them; this file
+only puts their directories on the path and imports their cases.
+``test_run_cpu.py`` (17 cases, each a ``run.py`` subprocess) is not
+collected: alone on this CPU it takes 313 s, more than a tier-1 worker
+has to spare (CHANGES.md, PR 35).
+"""
+
+import os
+import sys
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# test_spans imports test_reduce; benchmarks/tests/conftest.py (not read
+# from here) adds the other two
+for _p in (os.path.join(_REPO, 'benchmarks', 'tests'),
+           os.path.join(_REPO, 'benchmarks'), _REPO):
+    if _p not in sys.path:
+        sys.path.insert(0, _p)
+
+from test_configs import *  # noqa: E402,F401,F403
+from test_reduce import *  # noqa: E402,F401,F403
+from test_spans import *  # noqa: E402,F401,F403

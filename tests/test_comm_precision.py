@@ -22,9 +22,8 @@ Pins the tentpole contracts:
    decomposition of a run is never prefetched, and the dispatch records
    overlapping ``kfac.CommunicateInverse.prefetch`` /
    ``kfac.Precondition`` trace spans with ``consumer_step = step + 1``.
-6. The drift gate and the analytic volume model speak the same
-   compression factors (obs/drift.scale_comm_scenarios,
-   plan.FactorPlan.comm_volume).
+6. The analytic volume model (plan.FactorPlan.comm_volume) prices each
+   wire dtype by its compression factor.
 """
 
 import functools
@@ -40,7 +39,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 import kfac_pytorch_tpu as kfac
 from kfac_pytorch_tpu import capture, training
 from kfac_pytorch_tpu import nn as knn
-from kfac_pytorch_tpu.obs import drift
 from kfac_pytorch_tpu.obs.trace import TraceRecorder
 from kfac_pytorch_tpu.parallel import collectives as coll
 
@@ -505,49 +503,8 @@ def test_prefetch_trace_spans_overlap(mode):
 
 
 # ---------------------------------------------------------------------------
-# drift gate + analytic volume model
+# analytic volume model
 # ---------------------------------------------------------------------------
-
-def test_scale_comm_scenarios_per_wire_dtype():
-    block = {'scenarios': {
-        'central': {'phases_s': {'CommunicateFactor': 0.30,
-                                 'CommunicateInverse': 0.146,
-                                 'ComputeInverse_eigh_full': 2.0}},
-        'optimistic': {'phases_s': {'CommunicateFactor': 0.20,
-                                    'CommunicateInverse': 0.10}},
-    }}
-    for wd, (f, i) in {'fp32': (1.0, 1.0), 'bf16': (0.5, 0.5),
-                       'int8': (0.5, 0.25)}.items():
-        out = drift.scale_comm_scenarios(block, wd)
-        c = out['scenarios']['central']['phases_s']
-        assert c['CommunicateFactor'] == pytest.approx(0.30 * f)
-        assert c['CommunicateInverse'] == pytest.approx(0.146 * i)
-        # compute phases untouched
-        assert c['ComputeInverse_eigh_full'] == 2.0
-        if wd != 'fp32':
-            assert out['comm_precision'] == wd
-    # the original block is never mutated
-    assert block['scenarios']['central']['phases_s'][
-        'CommunicateFactor'] == 0.30
-
-
-def test_drift_block_covers_compressed_runs():
-    block = {'scenarios': {
-        'optimistic': {'phases_s': {'CommunicateInverse': 0.08}},
-        'conservative': {'phases_s': {'CommunicateInverse': 0.16}},
-        'central': {'phases_s': {'CommunicateInverse': 0.12}},
-    }}
-    # a bf16 run measuring half the fp32 band: drift under the raw
-    # model, ok under the compression-scaled one
-    measured = {'CommunicateInverse': 0.06}
-    raw = drift.drift_block(measured, block, platform='TPU v5e',
-                            variant='eigen')
-    scaled = drift.drift_block(measured, block, platform='TPU v5e',
-                               variant='eigen', comm_precision='bf16')
-    assert raw['gate']['verdict'] == 'drift'
-    assert scaled['gate']['verdict'] == 'ok'
-    assert scaled['comm_precision'] == 'bf16'
-
 
 def test_plan_comm_volume_compression_factors():
     model = MLP()

@@ -8,8 +8,6 @@ Pins, per ISSUE 5's acceptance list:
   hand-plumbed path (health / resilience / kfac_phase);
 - kfac-obs merging a pod drill's artifact classes (runlog + incident
   JSON + trace JSONL) into one ordered, clock-aligned timeline;
-- drift ratios pinned on a synthetic predicted/measured pair, plus the
-  schema over the real perfmodel block;
 - exporters: JSONL, Prometheus textfile, native TensorBoard roundtrip,
   and rank gating.
 """
@@ -23,7 +21,7 @@ import textwrap
 
 import pytest
 
-from kfac_pytorch_tpu.obs import aggregate, drift, metrics, trace
+from kfac_pytorch_tpu.obs import aggregate, metrics, trace
 
 pytestmark = pytest.mark.core
 
@@ -510,109 +508,6 @@ def test_incident_scrapes_trace_jsonl(tmp_path):
     assert 'kfac.step' not in kinds  # spans are not incident events
     trip = next(e for e in rep.events if e['kind'] == 'watchdog_trip')
     assert trip['rc'] == 114 and trip['wall'] is not None
-
-
-# -- drift ---------------------------------------------------------------------
-
-
-def _synthetic_predicted():
-    phases = {'Model': 0.10, 'Precondition': 0.02, 'ComputeFactor': 0.05,
-              'ComputeInverse_chol': 0.04, 'ComputeInverse_eigh_full': 8.0}
-    return {'predicted_not_measured': True, 'scenarios': {
-        'optimistic': {'phases_s': {k: v * 0.5 for k, v in phases.items()}},
-        'central': {'phases_s': dict(phases)},
-        'conservative': {'phases_s': {k: v * 2 for k, v in phases.items()}},
-    }}
-
-
-def test_drift_ratios_pinned_on_synthetic_pair():
-    pred = _synthetic_predicted()
-    measured = {'Model': 0.15, 'ComputeFactor': 0.05,
-                'CommunicateFactor': 0.30}
-    block = drift.drift_block(measured, pred, platform='TPU v5 lite',
-                              variant='inverse_dp')
-    assert block['comparable'] is True
-    m = block['phases']['Model']
-    assert m['ratio'] == 1.5                       # 0.15 / 0.10 central
-    assert m['band_s'] == [0.05, 0.2]
-    assert m['within_band'] is True                # inside [0.5x, 2x]
-    f = block['phases']['ComputeFactor']
-    assert f['ratio'] == 1.0 and f['within_band'] is True
-    # no single-chip prediction for comm phases -> explicit null
-    c = block['phases']['CommunicateFactor']
-    assert c['predicted_s'] == {} and c['ratio'] is None
-    assert c['within_band'] is None
-    assert block['gate']['verdict'] == 'ok'
-    assert block['gate']['violations'] == []
-
-    # out-of-band measurement on the model chip: the gate trips
-    bad = drift.drift_block({'Model': 0.5}, pred, platform='TPU v5e')
-    assert bad['phases']['Model']['within_band'] is False
-    assert bad['gate']['verdict'] == 'drift'
-    assert bad['gate']['violations'] == ['Model']
-    # same numbers on CPU: advisory, never chip evidence
-    adv = drift.drift_block({'Model': 0.5}, pred, platform='cpu')
-    assert adv['comparable'] is False
-    assert adv['gate']['verdict'] == 'advisory'
-    # tolerance widens the band
-    tol = drift.drift_block({'Model': 0.5}, pred, platform='TPU v5e',
-                            tolerance=3.0)
-    assert tol['phases']['Model']['within_band'] is True
-
-    # variant binds ComputeInverse to the right kernel
-    chol = drift.drift_block({'ComputeInverse': 0.04}, pred,
-                             platform='TPU v5e', variant='inverse_dp')
-    assert chol['phases']['ComputeInverse']['ratio'] == 1.0
-    eig = drift.drift_block({'ComputeInverse': 0.04}, pred,
-                            platform='TPU v5e', variant='eigen_dp')
-    assert eig['phases']['ComputeInverse']['ratio'] == round(0.04 / 8.0, 4)
-    # joint phases sum their parts
-    joint = drift.drift_block({'Model+ComputeFactor': 0.15}, pred,
-                              platform='TPU v5e')
-    assert joint['phases']['Model+ComputeFactor']['predicted_s'][
-        'central'] == 0.15
-    assert joint['phases']['Model+ComputeFactor']['ratio'] == 1.0
-
-
-def test_drift_measured_adapters():
-    got = drift.measured_from_phase_timers(
-        {'pred': 1.0, 'stats': 2.0, 'decomp+gather': 30.0,
-         'step_mean': 10.0})
-    assert got == {'Precondition': 0.001, 'ComputeFactor': 0.002,
-                   'ComputeInverse+CommunicateInverse': 0.030,
-                   'step_mean': 0.010}
-    extra = {'sgd_iter_s': 0.1, 'inverse_dp_iter_s_freq1': 0.18,
-             'phase_breakdown_s': None}
-    got = drift.measured_from_bench_extras(extra)
-    assert got['Model'] == 0.1
-    assert abs(got['Precondition+ComputeFactor+ComputeInverse']
-               - 0.08) < 1e-12
-    # with the breakdown ladder present, its per-phase numbers win
-    extra['phase_breakdown_s'] = {'Total': 0.2, 'ComputeFactor': 0.03,
-                                  'CommunicateInverse': 0.01, 'Rest': 0.1}
-    got = drift.measured_from_bench_extras(extra)
-    assert got['ComputeFactor'] == 0.03
-    assert 'Total' not in got and 'Rest' not in got
-    assert 'Precondition+ComputeFactor+ComputeInverse' not in got
-
-
-def test_drift_block_over_real_perfmodel():
-    perfmodel = pytest.importorskip('kfac_pytorch_tpu.perfmodel')
-    pred = perfmodel.predict_block()
-    if 'scenarios' not in pred:
-        pytest.skip(f'perf inputs unavailable: {pred.get("error")}')
-    block = drift.drift_block({'Model': 0.1, 'ComputeFactor': 0.02},
-                              pred, platform='cpu smoke')
-    assert 'error' not in block
-    assert block['phases']['Model']['ratio'] is not None
-    assert block['gate']['verdict'] == 'advisory'
-    # malformed predicted never raises
-    assert 'phases' in drift.drift_block({'Model': 0.1}, None)
-    assert drift.micro_measured({'unstaggered': {
-        'steady_ms': 10.0, 'refresh_ms': 35.0}}) == {
-        'Model+Precondition+ComputeFactor': 0.01,
-        'ComputeInverse': 0.025}
-    assert drift.micro_measured({}) == {}
 
 
 # -- training integration ------------------------------------------------------
