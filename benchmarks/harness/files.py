@@ -44,6 +44,13 @@ def load_module(kind, name):
     return mod
 
 
+def load_kfac_reference(config):
+    """The K-FAC algebra the configuration is checked against:
+    ``reference/kfac_plain.py`` unless it names another."""
+    return load_module('reference',
+                       config.get('kfac_reference', 'kfac_plain'))
+
+
 def benchmark_json():
     with open(os.path.join(ROOT, 'BENCHMARK.json')) as f:
         return json.load(f)

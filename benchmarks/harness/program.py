@@ -163,8 +163,17 @@ def kfac_state_dtypes(state):
                    for x in jax.tree.leaves((ks.factors, ks.decomp))})
 
 
-def health_counters(metrics):
-    """The health counters of the newest step's metrics that must stay 0
-    (a step degraded to SGD or skipped must not pass as K-FAC)."""
-    return {k: float(v) for k, v in metrics.items()
-            if k in ('health/skipped', 'health/rung', 'health/fallbacks')}
+#: the counters read where the configuration names none: the health
+#: guard's, where the program runs with one
+HEALTH_COUNTERS = ('health/skipped', 'health/rung', 'health/fallbacks')
+
+
+def health_counters(metrics, names=None):
+    """The counters of the newest step's metrics that must stay 0 (a step
+    degraded to SGD or skipped must not pass as K-FAC). ``names`` is the
+    configuration's ``check.counters`` (a router's dropped tokens, say):
+    each has to be among the step's metrics."""
+    if names is None:
+        return {k: float(metrics[k]) for k in HEALTH_COUNTERS
+                if k in metrics}
+    return {k: float(metrics[k]) for k in names}

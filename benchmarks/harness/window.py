@@ -86,12 +86,40 @@ def measure(stepper, traffic, seconds):
     return out
 
 
+def _bad_steps(mets):
+    """Per step: its loss is not finite, or the health guard refused its
+    batch."""
+    return [not np.isfinite(m['loss'])
+            or not bool(m.get('health/ok', True)) for m in mets]
+
+
 def step_health(mets):
     """``mets``: every dispatched step's metrics, on the host. -> (losses,
     bad, first_bad): every loss, the number of steps whose loss is not
     finite or whose batch the health guard refused, and the index of the
     first such step (None: none)."""
     losses = [float(m['loss']) for m in mets]
-    flags = [not np.isfinite(m['loss'])
-             or not bool(m.get('health/ok', True)) for m in mets]
+    flags = _bad_steps(mets)
     return losses, sum(flags), flags.index(True) if True in flags else None
+
+
+def tally(mets, first, steps, counters, compiles):
+    """A run's failures, counted over one set of steps: the window's.
+
+    ``mets``: every step's metrics from the run's first (set-up, then the
+    window's ``steps`` from index ``first``); ``counters``: the health
+    counters after the window; ``compiles``: compilations inside it.
+    -> (attempted, failed, parts). ``attempted`` is the window's steps;
+    ``failed`` those of them whose loss is not finite or whose batch the
+    guard refused, and at least 1 for any other fault (a bad step in
+    set-up, a raised counter, a compile in the window), so that it is 0
+    only where nothing is wrong and never above ``attempted``. ``parts``
+    names each fault; all are 0 in a sound run but ``first_bad_step``, the
+    run's first bad step counted from its first step, which is -1."""
+    flags = _bad_steps(mets[:first + steps])
+    bad_setup, bad_window = sum(flags[:first]), sum(flags[first:])
+    parts = {'bad_steps_window': bad_window, 'bad_steps_setup': bad_setup,
+             **counters, 'compiles_in_window': compiles,
+             'first_bad_step': flags.index(True) if True in flags else -1}
+    other = bad_setup or compiles or any(counters.values())
+    return steps, min(steps, max(bad_window, int(bool(other)))), parts

@@ -20,6 +20,13 @@ input rows ``a`` and output cotangents ``g`` of a batch of N:
 - conv: ``a`` becomes patch rows in ``(kh, kw, c_in)`` order (+ ones),
   divided by the number of output positions S; ``A = rows'rows / N``;
   ``g`` rows are scaled by ``N S``; ``G = rows'rows / (N S)``.
+- rows: a layer that sees some of R rows (an expert of a routed layer).
+  The plain model records ``(a, w)`` for it, ``a`` ``[R, d_in]`` and a row
+  weight ``w`` in {0, 1}, and states ``loss_rows`` = T, the size of the
+  loss's mean; ``n = max(sum w, 1)``; ``A = (a w)'(a w) / n``,
+  ``G = (T g w)'(T g w) / n``. With ``w = 1`` and ``R = T`` that is
+  ``dense`` on flat tokens. A layer with ``sum w = 0`` on a step keeps
+  its running averages.
 - running average from the identity: ``F <- (1 - w) F + w stat`` with
   ``w = ema_new_weight``.
 - damping split by traces: ``pi = (tr A / dim A) / (tr G / dim G)``,
@@ -29,6 +36,12 @@ input rows ``a`` and output cotangents ``g`` of a batch of N:
 - KL clip: all layers are scaled by ``min(1, sqrt(kl_clip / |sum(pre * dW)
   lr^2|))``.
 - optimizer: ``u = g + wd p; m = mu m + u; p = p - lr m``.
+
+A layer's weight is the whole leaf ``<path>/kernel`` (with ``<path>/bias``
+where it has one), or, where the layer names ``leaf`` and ``index``, the
+slice ``[index]`` of the stacked leaf ``leaf`` ``[E, d_in, d_out]`` (no
+bias): experts kept as one leaf for a grouped product have a factor pair
+each. The optimizer, ``first_update`` and ``param_change`` go by leaf.
 
 ``lower`` is the control, the same computation one precision step down:
 ``'kfac'`` lowers the K-FAC state and arithmetic alone (``float32 ->
@@ -80,7 +93,13 @@ def _patch_rows(x, layer):
 
 
 def _stats(layer, a, g, stat_dtype):
-    """The (A, G) statistics of one layer from its input and cotangent."""
+    """The (A, G) statistics of one layer from its input and cotangent,
+    and the number of rows they were taken from."""
+    seen = None
+    if layer['kind'] == 'rows':
+        a, weight = a
+        seen = weight.astype(jnp.float32).sum()
+        weight = weight.astype(stat_dtype)[:, None]
     a = a.astype(stat_dtype)
     g = g.astype(stat_dtype)
     n = a.shape[0]
@@ -88,6 +107,10 @@ def _stats(layer, a, g, stat_dtype):
         rows, spatial = _patch_rows(a, layer)
         grows = g.reshape(-1, g.shape[-1]) * (n * spatial)
         gden = n * spatial
+    elif layer['kind'] == 'rows':
+        rows, grows = a, g * weight * layer['loss_rows']
+        spatial = 1
+        n = gden = jnp.maximum(seen, 1.0).astype(stat_dtype)
     else:
         rows = a.reshape(n, -1, a.shape[-1]).mean(axis=1)
         grows = g.reshape(n, -1, g.shape[-1]).mean(axis=1) * n
@@ -95,11 +118,14 @@ def _stats(layer, a, g, stat_dtype):
     if layer['bias']:
         rows = jnp.concatenate(
             [rows, jnp.ones((rows.shape[0], 1), rows.dtype)], axis=-1)
+    if seen is not None:
+        rows = rows * weight
     rows = rows / spatial
     with jax.default_matmul_precision('highest'):
         big_a = (rows.T @ rows) / n
         big_g = (grows.T @ grows) / gden
-    return big_a.astype(stat_dtype), big_g.astype(stat_dtype)
+    return (big_a.astype(stat_dtype), big_g.astype(stat_dtype),
+            n if seen is None else seen)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 4))
@@ -165,8 +191,19 @@ def _inverse_of(damped, q):
     return lambda m: q(inv @ q(m))
 
 
+def _kernel(layer, tree):
+    """The layer's weight in ``tree``: a whole leaf, or one slice of a
+    stacked leaf (a view: writing to it writes to the leaf)."""
+    if 'leaf' in layer:
+        if layer['bias']:
+            raise ValueError(f'{layer["path"]}: a slice of a stacked leaf '
+                             'has no bias')
+        return tree[layer['leaf']][layer['index']]
+    return tree[layer['path'] + '/kernel']
+
+
 def _grad_matrix(layer, grads):
-    k = np.asarray(grads[layer['path'] + '/kernel'], np.float64)
+    k = np.asarray(_kernel(layer, grads), np.float64)
     mat = k.reshape(-1, k.shape[-1]).T
     if layer['bias']:
         b = np.asarray(grads[layer['path'] + '/bias'], np.float64)
@@ -177,7 +214,7 @@ def _grad_matrix(layer, grads):
 def _write_matrix(layer, grads, mat):
     shape = layer['kernel']
     w = mat[:, :-1] if layer['bias'] else mat
-    grads[layer['path'] + '/kernel'] = w.T.reshape(shape)
+    _kernel(layer, grads)[...] = w.T.reshape(shape)
     if layer['bias']:
         grads[layer['path'] + '/bias'] = mat[:, -1]
 
@@ -232,8 +269,11 @@ def run(model, cfg, traffic, make_params, param_key, data_key, steps,
         out['losses'].append(float(loss))
         grads = {p: np.asarray(v, np.float64) for p, v in grads.items()}
         if upd_f:
+            seen = jax.device_get({p: s[2] for p, s in stats.items()})
             for layer in layers:
                 path = layer['path']
+                if seen[path] == 0:
+                    continue        # no row came to it: nothing to average
                 for side in (0, 1):
                     stat = np.asarray(
                         jax.device_get(stats[path][side]), np.float64)

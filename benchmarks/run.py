@@ -18,7 +18,9 @@ import time
 _T0 = time.time()
 
 import argparse
+import hashlib
 import json
+import math
 import os
 import shutil
 import sys
@@ -46,7 +48,8 @@ class Laps:
     so the parts add up to the time from the start to the last lap."""
 
     PARTS = ('setup_s', 'window_s', 'traced_s', 'memory_plan_s',
-             'first_order_s', 'reference_s', 'reduce_s')
+             'first_order_warm_s', 'first_order_s', 'reference_s',
+             'reduce_s')
 
     def __init__(self, t0):
         self.last, self.parts = t0, dict.fromkeys(self.PARTS, 0.0)
@@ -166,6 +169,31 @@ def first_order_leg(builder, plain, config, traffic, seed, fence, n):
     return {'sgd_step_ms': secs / steps * 1e3}
 
 
+def first_order_marker(cache_dir, cell, config, traffic):
+    """-> (path, text) of the note, inside the compile cache, that this
+    checkout's cache holds the cell's first-order program. The text holds
+    the checkout's absolute path, which is part of JAX's cache key (a
+    copied cache never hits, so its note must not count), and a digest of
+    what the program is built from."""
+    built_from = json.dumps([config, traffic], sort_keys=True).encode()
+    text = json.dumps({'checkout': ROOT,
+                       'built_from': hashlib.sha256(built_from).hexdigest()})
+    return os.path.join(cache_dir, 'first_order_warm',
+                        cell['name'] + '.json'), text
+
+
+def finite_or_none(obj):
+    """``obj`` with every float that is not finite replaced by None: the
+    result line is JSON, which has no NaN."""
+    if isinstance(obj, dict):
+        return {k: finite_or_none(v) for k, v in obj.items()}
+    if isinstance(obj, (list, tuple)):
+        return [finite_or_none(v) for v in obj]
+    if isinstance(obj, float) and not math.isfinite(obj):
+        return None
+    return obj
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument('--workload', required=True)
@@ -266,15 +294,14 @@ def main():
         - traced0)
     win['setup_s'] = setup_s
     mets = jax.device_get(stepper.metrics)
-    losses, bad, first_bad = window.step_health(mets)
+    losses, _, first_bad = window.step_health(mets)
     mine['losses'] = losses[:chk['steps']]
-    counters = program.health_counters(mets[-1])
-    failed = bad + int(sum(counters.values())) + win['compiles_in_window']
-    attempted = win['steps']
+    counters = program.health_counters(mets[-1], chk.get('counters'))
+    attempted, failed, faults = window.tally(
+        mets, win['first_step'], win['steps'], counters,
+        win['compiles_in_window'])
     # how the losses went over set-up and window together, and where the
-    # run's first refused or non-finite step was (detail only: a model
-    # that blows up inside the window makes `failed` turn on where the
-    # window ends, PERF.md section 7)
+    # run's first refused or non-finite step was
     say(phase='window', **{k: v for k, v in win.items()
                            if np.isscalar(v)},
         loss_first=losses[0], loss_last=losses[-1], loss_max=max(losses),
@@ -305,16 +332,31 @@ def main():
     # ---- the program's state goes; first-order leg and reference ------
     kfac_step_ms = win['window_s'] / win['steps'] * 1e3
     del stepper, prog
-    if args.trace:
-        ctx['sgd'] = first_order_leg(builder, plain, config, traffic,
-                                     args.seed, host_fence, 2 * window.CHUNK)
-        ctx['sgd']['kfac_over_sgd'] = (kfac_step_ms
-                                       / ctx['sgd']['sgd_step_ms'])
-        laps.lap('first_order_s')
+    # the first run of a cell in a checkout, traced or not, builds the
+    # first-order program: the driver allows that run 1,200 s, and the
+    # first traced run, which has 360 s, then reads it from the cache
+    marker, note = first_order_marker(cache_dir, cell, config, traffic)
+    warmed = False
+    if os.path.isfile(marker):
+        with open(marker) as f:
+            warmed = f.read() == note
+    if args.trace or not warmed:
+        c0, h0 = counter.compiles, counter.hits
+        sgd = first_order_leg(builder, plain, config, traffic, args.seed,
+                              host_fence, 2 * window.CHUNK)
+        os.makedirs(os.path.dirname(marker), exist_ok=True)
+        with open(marker, 'w') as f:
+            f.write(note)
+        say(phase='first_order', traced=bool(args.trace), **sgd,
+            compiled=(counter.compiles - c0) - (counter.hits - h0))
+        if args.trace:
+            sgd['kfac_over_sgd'] = kfac_step_ms / sgd['sgd_step_ms']
+            ctx['sgd'] = sgd
+        laps.lap('first_order_s' if args.trace else 'first_order_warm_s')
 
     t = time.perf_counter()
     key = weights.seed_key(args.seed)
-    ref_mod = files.load_module('reference', 'kfac_plain')
+    ref_mod = files.load_kfac_reference(config)
     ref = ref_mod.run(plain, config, traffic,
                       weights.params_fn(config['init']), key,
                       program.data_key(key), chk['steps'],
@@ -359,6 +401,10 @@ def main():
     result['check'] = {r['check']: {'value': float(r['value']),
                                     'limit': r['limit']} for r in rows}
     result['check']['failed'] = {'value': failed, 'limit': 0}
+    for name, value in faults.items():
+        result['check'][name] = {
+            'value': value, 'limit': -1 if name == 'first_bad_step' else 0}
+    result = finite_or_none(result)
     laps.lap('reduce_s')
     say(phase='time', **laps.parts, total_s=sum(laps.parts.values()))
     for name, pair in result['check'].items():

@@ -10,6 +10,9 @@ import numpy as np
 import pytest
 
 from harness import check, files
+# the reference's own tests ride along: tests/test_benchmark_suite.py
+# collects what this module holds
+from test_reference import *  # noqa: E402,F401,F403
 
 KEY_PATH = re.compile(r'[a-z_0-9]+(\.[a-z_0-9]+)+$')
 
@@ -41,6 +44,12 @@ def test_configuration_file_holds_what_is_read(entry):
     # a limit for every number compared, and no other
     assert set(config['check']['limits']) == compared_numbers()
     assert all(limit > 0 for limit in config['check']['limits'].values())
+    # the pieces it names are files of the benchmark
+    files.find('builders', config['builder'], '.py')
+    files.find('reference', config['plain'], '.py')
+    assert callable(files.load_kfac_reference(config).run)
+    assert all(isinstance(name, str)
+               for name in config['check'].get('counters', []))
     paths = [p for line in config['changed'] for p in changed_key_paths(line)]
     assert 'optimizer.lr' in paths      # both cells run a constant rate
     for key_path in paths:

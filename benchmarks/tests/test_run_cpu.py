@@ -14,7 +14,12 @@ BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH)
 KEYS = {'correct', 'attempted', 'failed', 'metrics', 'device', 'check'}
 TIME_PARTS = ('setup_s', 'window_s', 'traced_s', 'memory_plan_s',
-              'first_order_s', 'reference_s', 'reduce_s')
+              'first_order_warm_s', 'first_order_s', 'reference_s',
+              'reduce_s')
+#: what `failed` is made of, each beside its limit in `check`
+FAULTS = ('bad_steps_window', 'bad_steps_setup', 'health/skipped',
+          'health/rung', 'health/fallbacks', 'compiles_in_window',
+          'first_bad_step')
 
 
 def run_cell(workload, trace=0, devices=1, extra=(), cwd=ROOT, seconds=2):
@@ -63,7 +68,10 @@ def test_untraced_run(workload, seconds):
     assert all(r['value'] <= r['limit'] for r in checks.values())
     # ... in the result line too, as its last key, and at the end of stderr
     assert list(last)[-1] == 'check'
-    assert set(last['check']) == set(checks) | {'failed'}
+    assert list(last['check']) == [*checks, 'failed', *FAULTS]
+    assert all(last['check'][k] == {'value': 0, 'limit': 0}
+               for k in ('failed', *FAULTS[:-1]))
+    assert last['check']['first_bad_step'] == {'value': -1, 'limit': -1}
     assert all(last['check'][k] == {'value': r['value'], 'limit': r['limit']}
                for k, r in checks.items())
     tail = proc.stderr.splitlines()[-len(last['check']):]
@@ -179,6 +187,66 @@ def test_lower_precision_control_fails(mode):
     assert not checks['first_update_norm_gap']['ok']
 
 
+def test_every_step_refused_is_a_well_formed_not_correct():
+    """A loss that is never finite, from the first step of set-up on: the
+    sum that `failed` used to be (bad steps of set-up and window + the
+    counters) passes `attempted`, and the driver could not read the line
+    (PR 30, 36, 37). Counted over the window's steps it is `attempted`."""
+    proc, rows = run_cell('tiny-bert-refused-freq10')
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    last = json.loads(proc.stdout.splitlines()[-1],
+                      parse_constant=pytest.fail)     # no NaN in the line
+    assert set(last) == KEYS and list(last)[-1] == 'check'
+    assert last['correct'] is False
+    assert last['failed'] == last['attempted'] > 0
+    chk = last['check']
+    assert chk['failed'] == {'value': last['attempted'], 'limit': 0}
+    assert chk['bad_steps_window'] == {'value': last['attempted'],
+                                       'limit': 0}
+    assert chk['bad_steps_setup'] == {'value': 10, 'limit': 0}
+    assert chk['first_bad_step'] == {'value': 0, 'limit': -1}
+    assert chk['health/skipped']['value'] == last['attempted'] + 10
+    assert chk['loss_gap']['value'] is None         # the loss was NaN
+    tail = proc.stderr.splitlines()[-len(chk):]
+    assert [line.split()[1] for line in tail] == list(chk)
+
+
+def test_first_order_program_is_built_by_a_checkouts_first_run():
+    """The first run of a cell in a checkout builds and runs the
+    first-order program, traced or not, and leaves a note in the compile
+    cache; a later untraced run skips it, and a traced run then reads its
+    program from the cache. A note that names another checkout (a copied
+    cache: the path is part of JAX's cache key) does not count."""
+    note = os.path.join(ROOT, '.jax_cache', 'first_order_warm',
+                        'tiny-bert-freq1.json')
+    if os.path.exists(note):
+        os.remove(note)
+
+    def run(trace):
+        proc, rows = run_cell('tiny-bert-freq1', trace=trace)
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        assert rows[-1]['correct'] is True
+        return by_phase(rows, 'time')[0], by_phase(rows, 'first_order')
+
+    time_row, legs = run(0)
+    assert time_row['first_order_warm_s'] > 0 and len(legs) == 1
+    assert legs[0]['traced'] is False
+    with open(note) as f:
+        assert json.load(f)['checkout'] == ROOT
+    time_row, legs = run(0)
+    assert time_row['first_order_warm_s'] == 0 and not legs
+    time_row, legs = run(1)
+    assert time_row['first_order_warm_s'] == 0
+    assert time_row['first_order_s'] > 0
+    assert legs[0]['traced'] is True and legs[0]['compiled'] == 0
+    with open(note) as f:
+        text = f.read()
+    with open(note, 'w') as f:
+        f.write(text.replace(ROOT, '/another/checkout'))
+    time_row, legs = run(0)
+    assert time_row['first_order_warm_s'] > 0 and len(legs) == 1
+
+
 def test_stuck_step_is_not_correct():
     """The timed path broken underneath: parameters never change."""
     proc, rows = run_cell('tiny-bert-stuck-freq1')
@@ -190,9 +258,10 @@ def test_stuck_step_is_not_correct():
 
 @pytest.mark.parametrize('bad_at,bad', [(None, 0), (352, 8), (5, 355)])
 def test_step_health_counts_refused_and_non_finite_steps(bad_at, bad):
-    """``failed`` counts every step of set-up and window that the guard
-    refused or whose loss is not finite; the ``window`` row says where the
-    first was (``bert-base-squad`` at lr 0.04 blew up 274-356 steps in)."""
+    """A step is bad where the guard refused it or its loss is not finite;
+    the ``window`` row says where the run's first was (``bert-base-squad``
+    at lr 0.04 blew up 274-356 steps in). How ``failed`` is tallied from
+    them: ``test_reference.py``."""
     import numpy as np
     from harness import window
     mets = [{'loss': np.float32(5.9), 'health/ok': np.bool_(True)}

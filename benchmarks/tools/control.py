@@ -31,7 +31,7 @@ def main():
     traffic, _ = files.load_json('traffic', cell['traffic'])
     traffic = dict(traffic, chips=cell['chips'])
     plain = files.load_module('reference', config['plain'])
-    ref_mod = files.load_module('reference', 'kfac_plain')
+    ref_mod = files.load_kfac_reference(config)
     chk = config['check']
     mode = sys.argv[2]
     for seed in map(int, sys.argv[3:]):

@@ -1,6 +1,6 @@
 #!/usr/bin/env python3
-"""Set a configuration's ``optimizer.lr`` by the written rule: the first
-rate of a ladder at which the cell *trains* for three times its window.
+"""Walk a ladder of constant rates for a cell and say which of them
+*trains* for three times its window.
 
     python3 benchmarks/tools/lr_ladder.py <cell> <seed> <out dir> <rate> [<rate> ...]
 
@@ -16,7 +16,16 @@ the last is cumulative), so it is not run for longer. The
 first rate that trains stays in the file; exit code 1 if none does. Every
 run's rows go to ``<out dir>/ladder_<rate>_<seconds>.log``; one summary
 line a run is printed. The parent process never touches JAX (the chip
-belongs to the child)."""
+belongs to the child).
+
+No rate of ``bert-base-squad``'s ladder 0.04 x 4^-k trains (PR 28), so this
+tool's rule does not set its ``optimizer.lr``. The rule that does (PR 38;
+PERF.md section 4): **two rungs of this ladder (a factor of 16) under the
+lowest rate on record at which a step was refused, with the rung between
+shown quiet** on 8 seeds and the chosen rate on 12, each for two windows
+(``failed`` 0, every counter 0). PR 28's rule, the first rung at which no
+step was refused on the seeds it ran, put the cell one rung under the
+cliff, and three checks drew a seed that fell off it."""
 
 import json
 import os
