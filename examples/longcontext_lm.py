@@ -22,6 +22,16 @@ derives the data/sequence worlds from the spec):
   JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
       python examples/longcontext_lm.py \
       --seq-len 512 --kfac-mesh dp2xsp4 --epochs 1
+
+``--model sparse-decoder`` trains ``models.sparse_decoder_lm`` instead: one
+chip's share of a decoder with latent attention and sigmoid-routed experts
+(kanana-2-30b-a3b's published widths; ``--n-layer``, ``--n-head`` heads
+held, ``--experts-held``, the vocabulary and ``--d-model`` are what is cut
+to fit), flat tokens, on one device; its log line adds the router's
+counters (``moe/dropped`` must stay 0):
+  python examples/longcontext_lm.py --model sparse-decoder --kfac-name \
+      inverse_dp --seq-len 4096 --batch-size 1 --n-layer 5 --n-head 4 \
+      --d-model 2048 --experts-held 8 --synthetic-vocab 16032 --epochs 1
 """
 
 import argparse
@@ -39,7 +49,7 @@ import optax
 from jax.sharding import Mesh, PartitionSpec as P
 
 import kfac_pytorch_tpu as kfac
-from kfac_pytorch_tpu import models, training
+from kfac_pytorch_tpu import capture, models, training
 from kfac_pytorch_tpu.utils import metrics
 
 
@@ -52,6 +62,14 @@ def parse_args():
                    help='global batch (sequences per step)')
     p.add_argument('--epochs', type=int, default=3)
     p.add_argument('--steps-per-epoch', type=int, default=100)
+    p.add_argument('--model', choices=['transformer', 'sparse-decoder'],
+                   default='transformer')
+    p.add_argument('--experts-held', type=int, default=8,
+                   help='sparse-decoder: routed experts this chip holds '
+                        '(ids 0..n-1 of the published 128)')
+    p.add_argument('--expert-capacity', type=int, default=None,
+                   help='sparse-decoder: rows of a held expert\'s buffer '
+                        '(default: four times the expected load)')
     p.add_argument('--n-layer', type=int, default=4)
     p.add_argument('--n-head', type=int, default=8)
     p.add_argument('--d-model', type=int, default=256)
@@ -268,13 +286,26 @@ def main():
     else:
         seq_axis = 'seq' if ns > 1 else None
         data_axis = 'data' if nd > 1 else None
-    model = models.transformer_lm(
-        vocab_size=vocab, n_layer=args.n_layer, n_head=args.n_head,
-        d_model=args.d_model, max_len=args.seq_len, seq_axis=seq_axis,
-        seq_impl=args.seq_impl)
-    twin = models.transformer_lm(
-        vocab_size=vocab, n_layer=args.n_layer, n_head=args.n_head,
-        d_model=args.d_model, max_len=args.seq_len, seq_axis=None)
+    step_kw = {}
+    if args.model == 'sparse-decoder':
+        assert ndev == 1, 'the sparse decoder trains on one device here'
+        tokens = args.batch_size * args.seq_len
+        capacity = args.expert_capacity or -(-4 * tokens * 6 // 128)
+        model = twin = models.sparse_decoder_lm(
+            vocab_size=vocab, hidden_size=args.d_model,
+            num_layers=args.n_layer, head_ids=tuple(range(args.n_head)),
+            expert_ids=tuple(range(args.experts_held)),
+            expert_capacity=capacity, dtype=jnp.bfloat16)
+        # the model's counters ride in the state and in the step's metrics
+        step_kw = dict(extra_mutable=(capture.COUNTERS,))
+    else:
+        model = models.transformer_lm(
+            vocab_size=vocab, n_layer=args.n_layer, n_head=args.n_head,
+            d_model=args.d_model, max_len=args.seq_len, seq_axis=seq_axis,
+            seq_impl=args.seq_impl)
+        twin = models.transformer_lm(
+            vocab_size=vocab, n_layer=args.n_layer, n_head=args.n_head,
+            d_model=args.d_model, max_len=args.seq_len, seq_axis=None)
 
     # K-FAC distributes factor work over the flattened mesh when both
     # axes exist; with one axis it uses that axis directly. A composed
@@ -348,7 +379,7 @@ def main():
     step = training.build_train_step(
         model, tx, precond, ce, axis_name=kfac_axis, mesh=mesh,
         batch_specs={'input': bspec, 'label': bspec}, tracer=tracer,
-        autotune=tuner)
+        autotune=tuner, **step_kw)
 
     def eval_loss_local(params, batch):
         out = model.apply({'params': params}, batch['input'], train=False)
@@ -409,9 +440,11 @@ def main():
         vppl = math.exp(min(val_m.avg, 20))
         # one registry call renders the health/resilience suffixes
         # byte-identically to the old hand-plumbed health_suffix
-        log.info('epoch %d: train_ppl %.2f val_ppl %.2f (%.1fs)%s', epoch,
+        moe = ''.join(f' {k} {float(v):g}' for k, v in m.items()
+                      if k.startswith('moe/'))
+        log.info('epoch %d: train_ppl %.2f val_ppl %.2f (%.1fs)%s%s', epoch,
                  ppl, vppl, time.perf_counter() - t0,
-                 reg.epoch_suffixes())
+                 reg.epoch_suffixes(), moe)
         monitor.epoch_flush()
         reg.export(step=epoch)
         if tracer is not None:

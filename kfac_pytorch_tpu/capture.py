@@ -35,6 +35,11 @@ import numpy as np
 # Collection names.
 ACTS = 'kfac_a'    # sown layer inputs
 TAPS = 'kfac_tap'  # differentiable zero taps on layer outputs
+#: what the model counts for the step's metrics (a router's dropped rows):
+#: float32 scalars the model keeps in this collection; the trainer hands it
+#: to ``build_train_step(extra_mutable=...)`` and every step's metrics hold
+#: its leaves under their '/'-joined names
+COUNTERS = 'counters'
 
 
 @dataclasses.dataclass(frozen=True)
@@ -47,7 +52,7 @@ class LayerMeta:
     """
     name: str                 # '/'.join(path) — stable registry key
     path: Tuple[str, ...]     # module path inside the params pytree
-    kind: str                 # 'dense' | 'conv'
+    kind: str                 # 'dense' | 'conv' | 'stacked'
     use_bias: bool
     in_dim: int               # true factor-A dim (incl. bias column)
     out_dim: int              # true factor-G dim
@@ -55,6 +60,10 @@ class LayerMeta:
     kernel_size: Optional[Tuple[int, int]] = None   # conv only
     strides: Optional[Tuple[int, int]] = None       # conv only
     padding: Optional[Tuple[Tuple[int, int], Tuple[int, int]]] = None  # explicit
+    # 'stacked' only: the layer is slice ``[index]`` of the leaf ``kernel
+    # [E, d_in, d_out]`` at ``path`` (``nn.StackedDense``), with a factor
+    # pair of its own from the rows that came to it
+    index: Optional[int] = None
 
     @property
     def grad_shape(self):
@@ -149,6 +158,10 @@ def init(model, rngs, *args, **kwargs):
     variables = dict(variables)
     variables.pop(ACTS, None)
     variables.pop(TAPS, None)
+    if COUNTERS in variables:
+        # the initializing call counted too: a run starts from zero
+        variables[COUNTERS] = jax.tree.map(jnp.zeros_like,
+                                           variables[COUNTERS])
     return variables
 
 
@@ -340,6 +353,15 @@ def layer_act(acts, meta: LayerMeta):
 def layer_g(gs, meta: LayerMeta):
     """Pull layer ``meta``'s output-gradient out of the tap-grad pytree."""
     return get_path(gs, meta.path)['g']
+
+
+def counter_metrics(extra_vars):
+    """``{'moe/dropped': scalar, ...}``: the leaves of the model's
+    :data:`COUNTERS` collection under their '/'-joined names (empty where
+    the model keeps none)."""
+    from flax import traverse_util
+    return traverse_util.flatten_dict(
+        dict(extra_vars.get(COUNTERS, {})), sep='/')
 
 
 def canonical_padding(in_size, kernel_size, strides, padding):

@@ -58,7 +58,9 @@ def layer_grad_matrix(meta, grads):
     """
     sub = capture.get_path(grads, meta.path)
     k = sub['kernel']
-    if meta.kind == 'dense':
+    if meta.kind == 'stacked':
+        gm = k[meta.index].T
+    elif meta.kind == 'dense':
         gm = k.T
     else:
         kh, kw, cin, cout = meta.kernel_shape
@@ -80,7 +82,10 @@ def write_grad_matrix(meta, grads, mat):
         sub['bias'] = b.astype(sub['bias'].dtype)
     else:
         w = mat
-    if meta.kind == 'dense':
+    if meta.kind == 'stacked':
+        kernel = sub['kernel'].at[meta.index].set(
+            w.T.astype(sub['kernel'].dtype))
+    elif meta.kind == 'dense':
         kernel = w.T
     else:
         kh, kw, cin, cout = meta.kernel_shape
@@ -111,20 +116,33 @@ def _capture_backend(capture_impl):
 
 
 def compute_layer_stats(plan, acts, gs, batch_averaged=True,
-                        capture_impl=None):
+                        capture_impl=None, stacks=None):
     """Per-layer Kronecker factor statistics from captured (a, g).
+
+    ``stacks``: a dict to fill with ``{path: ((A, G) [E, d, d], n [E])}``,
+    the batched statistics of every stacked leaf and the rows its slices
+    got (for :func:`update_factor_rows`).
 
     ``capture_impl='pallas'`` computes every statistic with the fused
     Pallas kernels (interpreter mode off-TPU) — numerically pinned to
     the reference by tests/test_pallas_capture.py."""
     back, kw = _capture_backend(capture_impl)
     a_list, g_list = [], []
+    if stacks is None:
+        stacks = {}     # a stacked leaf's statistics: one batched product
     with (back.routing_report() if back is not ops
           else contextlib.nullcontext()):
         for meta in plan.metas:
             a = capture.layer_act(acts, meta)
             g = capture.layer_g(gs, meta)
-            if meta.kind == 'dense':
+            if meta.kind == 'stacked':
+                if meta.path not in stacks:
+                    sown = capture.get_path(acts, meta.path)
+                    stacks[meta.path] = (_stacked_stats(
+                        a, g, sown['n'], sown['t']), sown['n'])
+                a_list.append(stacks[meta.path][0][0][meta.index])
+                g_list.append(stacks[meta.path][0][1][meta.index])
+            elif meta.kind == 'dense':
                 a_list.append(back.compute_a_dense(a, meta.use_bias, **kw))
                 g_list.append(back.compute_g_dense(g, batch_averaged,
                                                    **kw))
@@ -136,21 +154,130 @@ def compute_layer_stats(plan, acts, gs, batch_averaged=True,
     return a_list, g_list
 
 
-def stack_stats(plan, a_list, g_list):
-    """Scatter per-layer stats into the global stacked-bucket layout
-    (identity padding; dummy rows are identity)."""
+def _stacked_stats(a, g, n, t):
+    """(A, G) ``[E, d, d]`` of the ``E`` slices of a stacked leaf
+    (``nn.StackedDense``) from its row buffers ``a [E, C, d_in]`` and
+    cotangents ``g [E, C, d_out]``, ``n [E]`` rows each (the rest of a
+    buffer is zero on both sides) and ``t`` the size of the loss's mean:
+    ``A = a'a / max(n, 1)``, ``G = (t g)'(t g) / max(n, 1)``. Not
+    ``compute_g_dense``'s scaling by the row count: the cotangents carry
+    ``1 / t``, whatever part of the ``t`` rows came here."""
+    with jax.named_scope('kfac.expert_stats'):
+        n = jnp.maximum(n, 1.0)[:, None, None]
+        gram = functools.partial(jnp.einsum, 'ecd,ecf->edf',
+                                 preferred_element_type=jnp.float32)
+        return gram(a, a) / n, gram(g, g) * (t * t / n)
+
+
+def rows_seen(plan, acts):
+    """``{bucket key: [n_rows] bool}``: False for the factor rows of a
+    stacked slice no row came to this step, which keep their running
+    averages (:func:`update_factors`); None where the plan has no
+    stacked layer."""
+    if not any(m.kind == 'stacked' for m in plan.metas):
+        return None
     out = {}
     for bdim in plan.bucket_dims:
-        b = plan.buckets[bdim]
-        rows = []
-        for s in b.slot_of_row:
-            if s is None:
-                rows.append(jnp.eye(bdim, dtype=jnp.float32))
+        flags = []
+        for s in plan.buckets[bdim].slot_of_row:
+            meta = None if s is None else plan.metas[s.layer_idx]
+            if meta is None or meta.kind != 'stacked':
+                flags.append(jnp.ones((), bool))
             else:
-                mat = (a_list[s.layer_idx] if s.side == 'A'
-                       else g_list[s.layer_idx])
-                rows.append(ops.identity_pad(mat, bdim))
-        out[_key(bdim)] = jnp.stack(rows)
+                flags.append(
+                    capture.get_path(acts, meta.path)['n'][meta.index] > 0)
+        out[_key(bdim)] = jnp.stack(flags)
+    return out
+
+
+def rowwise_buckets(plan, stats_reduce):
+    """Keys of the buckets whose running averages are updated row by row,
+    over the stored rows (:func:`update_factor_rows`): the buckets too
+    large to decompose whole (:func:`tiled_buckets`), whose stacked
+    statistics and averaged copy would be as large again, where one
+    device holds every row and takes the statistics from its own batch."""
+    if stats_reduce != 'local' or plan.num_devices != 1:
+        return ()
+    return tiled_buckets(plan)
+
+
+def _row_stat(plan, bdim, slot, a_list, g_list):
+    if slot is None:
+        return jnp.eye(bdim, dtype=jnp.float32)
+    mat = a_list[slot.layer_idx] if slot.side == 'A' \
+        else g_list[slot.layer_idx]
+    return ops.identity_pad(mat, bdim)
+
+
+def _stat_runs(plan, bdim, a_list, g_list, stacks):
+    """The bucket's rows as runs ``(first row, statistics [k, D, D], seen
+    [k] or None)`` in row order: a stacked leaf's slices that lie side by
+    side, in their own order, are one run read from the leaf's batched
+    statistics where they lie (:func:`compute_layer_stats`' ``stacks``);
+    every other row is a run of one."""
+    slots = plan.buckets[bdim].slot_of_row
+    runs, r = [], 0
+    while r < len(slots):
+        slot = slots[r]
+        meta = None if slot is None else plan.metas[slot.layer_idx]
+        if meta is None or meta.kind != 'stacked':
+            runs.append((r, _row_stat(plan, bdim, slot, a_list, g_list)[None],
+                         None))
+            r += 1
+            continue
+        both, n = stacks[meta.path]
+        k = 1
+        while (r + k < len(slots) and slots[r + k] is not None
+               and slots[r + k].side == slot.side
+               and plan.metas[slots[r + k].layer_idx].path == meta.path
+               and plan.metas[slots[r + k].layer_idx].index
+               == meta.index + k):
+            k += 1
+        stat = both[0 if slot.side == 'A' else 1]
+        stat = stat[meta.index:meta.index + k]
+        runs.append((r, ops.identity_pad(stat, bdim),
+                     n[meta.index:meta.index + k] > 0))
+        r += k
+    return runs
+
+
+def update_factor_rows(plan, bdim, current, a_list, g_list, stacks,
+                       factor_decay, guard, commit=None):
+    """One bucket's running averages, a run of rows at a time written
+    over ``current`` (a donated state's buffer is the result's; the
+    bucket's statistics are never stacked): what :func:`stack_stats`,
+    :func:`update_factors` with :func:`rows_seen`'s flags and, with
+    ``guard``, ``where_finite_rows(..., reinit_identity=True)`` do to a
+    whole bucket. ``commit`` (a traced bool): where False every row stays
+    as it was."""
+    out = current
+    eye = jnp.eye(bdim, dtype=current.dtype)
+    for first, stat, came in _stat_runs(plan, bdim, a_list, g_list, stacks):
+        old = lax.dynamic_slice_in_dim(out, first, stat.shape[0], axis=0)
+        new = ops.update_running_avg(stat, old, factor_decay)
+        if came is not None:
+            new = jnp.where(came[:, None, None], new, old)
+        if guard:
+            new = jnp.where(
+                _rows_finite(new)[:, None, None], new,
+                jnp.where(_rows_finite(old)[:, None, None], old, eye))
+        if commit is not None:
+            new = jnp.where(commit, new, old)
+        out = lax.dynamic_update_slice_in_dim(out, new, first, axis=0)
+    return out
+
+
+def stack_stats(plan, a_list, g_list, skip=()):
+    """Scatter per-layer stats into the global stacked-bucket layout
+    (identity padding; dummy rows are identity). ``skip``: keys of the
+    buckets updated row by row instead (:func:`rowwise_buckets`)."""
+    out = {}
+    for bdim in plan.bucket_dims:
+        if _key(bdim) in skip:
+            continue
+        b = plan.buckets[bdim]
+        out[_key(bdim)] = jnp.stack([
+            _row_stat(plan, bdim, s, a_list, g_list) for s in b.slot_of_row])
     return out
 
 
@@ -193,6 +320,9 @@ def _update_factors_fused(pc, plan, factors_local, acts, gs, batch_averaged,
                     jnp.eye(bdim, dtype=jnp.float32), cur, factor_decay))
                 continue
             meta = plan.metas[s.layer_idx]
+            if meta.kind == 'stacked':
+                raise NotImplementedError(
+                    f'{meta.name}: fused capture of a stacked layer')
             f = meta.in_dim if s.side == 'A' else meta.out_dim
             ema = (cur[:f, :f], factor_decay)
             if s.side == 'A':
@@ -227,7 +357,8 @@ def _update_factors_fused(pc, plan, factors_local, acts, gs, batch_averaged,
 
 def update_factors(plan, factors_local, stats_stacked, factor_decay,
                    stats_reduce, axis_name, comm_precision='fp32',
-                   comm_err=None, capture_impl=None, extra_reduce=()):
+                   comm_err=None, capture_impl=None, extra_reduce=(),
+                   seen=None):
     """Running-average update of the local factor shard.
 
     ``stats_reduce='pmean'``: MPD semantics — factors are the global-batch
@@ -261,11 +392,16 @@ def update_factors(plan, factors_local, stats_stacked, factor_decay,
     under a lossy ``comm_precision`` the cast error folds into the
     data-axis EF residual and re-enters the next data reduce; DP
     variants (``comm_err=None``) run the tensor wire EF-free.
+
+    ``seen``: :func:`rows_seen`'s flags; a row whose flag is False keeps
+    its running average (an expert no row was routed to).
     """
     new = {}
     new_err = None if comm_err is None else dict(comm_err)
     for bdim in plan.bucket_dims:
         key = _key(bdim)
+        if key not in stats_stacked:
+            continue    # updated row by row (rowwise_buckets)
         b = plan.buckets[bdim]
         stats = stats_stacked[key]
         err_in = None if comm_err is None else comm_err[key]
@@ -296,6 +432,12 @@ def update_factors(plan, factors_local, stats_stacked, factor_decay,
                                              b.per_dev, axis=0)
         new[key] = ops.update_running_avg(local, factors_local[key],
                                           factor_decay)
+        if seen is not None:
+            came = lax.dynamic_slice_in_dim(
+                seen[key], coll.axis_index(axis_name) * b.per_dev,
+                b.per_dev)
+            new[key] = jnp.where(came[:, None, None], new[key],
+                                 factors_local[key])
     return new, new_err
 
 
@@ -375,9 +517,19 @@ def _local_trace_avgs(plan, factors_local, axis_name):
 NS_ACCEPT_RESID = 0.05
 
 
+def tiled_buckets(plan):
+    """Keys of the buckets whose Cholesky inverse goes tile by tile
+    (``ops.inverse_tiling``, from the bucket's shape)."""
+    return tuple(
+        _key(bdim) for bdim in plan.bucket_dims
+        if ops.inverse_tiling(plan.buckets[bdim].per_dev, bdim)
+        != (plan.buckets[bdim].per_dev, bdim))
+
+
 def compute_decomposition(plan, factors_local, damping, method, eps,
                           axis_name, basis_local=None, warm_sweeps=None,
-                          invs_prev_local=None, impl=None):
+                          invs_prev_local=None, impl=None,
+                          stored_local=None, guard=False, commit=None):
     """Batched eigh or pi-damped Cholesky inverse of the local factor rows.
 
     eigh parity: eigen.py:98-119 / eigen_dp.py:62-75 (eigenvalue clamp
@@ -406,6 +558,15 @@ def compute_decomposition(plan, factors_local, damping, method, eps,
     legacy env path). The preconditioner's ``decomp_impl`` knob routes
     through here so the autotuner's ladder rung is a traced-program
     choice, not an ambient env read.
+
+    stored_local / guard: this device's rows of the stored decomposition
+    (``local_decomposition``) and whether the health guard is on. Only the
+    buckets of :func:`tiled_buckets` use them: their groups are written
+    over the stored rows and screened as they are written
+    (``ops.damped_psd_inverse``), and ``guard_decomposition`` is told to
+    pass them by; a bucket inverted whole is guarded there as ever.
+    ``commit`` (a traced bool, hoisted updates): where False those buckets
+    keep their stored rows; the caller sees to the others.
     """
     if method == 'eigh':
         evals, evecs = {}, {}
@@ -430,12 +591,16 @@ def compute_decomposition(plan, factors_local, damping, method, eps,
         own_avg = lax.dynamic_slice_in_dim(flat_avg, off, b.per_dev)
         mate_avg = jnp.take(flat_avg, _local_table(b.mate_flat, axis_name))
         damp_vec = jnp.sqrt(damping * own_avg / mate_avg)
-        damped = ops.add_scaled_identity(factors_local[key], damp_vec)
         if invs_prev_local is None:
-            invs[key] = ops.psd_inverse(damped)
+            # whole, or in groups of the bucket's rows where it is large
+            invs[key] = ops.damped_psd_inverse(
+                factors_local[key], damp_vec,
+                prev=None if stored_local is None
+                else stored_local['invs'][key], guard=guard, commit=commit)
         else:
             invs[key] = ops.warm_inverse(
-                damped, invs_prev_local[key],
+                ops.add_scaled_identity(factors_local[key], damp_vec),
+                invs_prev_local[key],
                 iters=2 if warm_sweeps is None else max(int(warm_sweeps),
                                                         1),
                 accept_resid=NS_ACCEPT_RESID)
@@ -822,6 +987,9 @@ def _layer_rows_padded(meta, acts, gs, batch_averaged, pg):
     """This layer's factor-convention row matrices (ops.layer_rows_*),
     feature-padded with zeros to the pred group's bucket dims — the one
     shared row/padding contract of both E-KFAC moment estimators."""
+    if meta.kind == 'stacked':
+        raise NotImplementedError(
+            f'{meta.name}: E-KFAC moments of a stacked layer')
     a = capture.layer_act(acts, meta)
     g = capture.layer_g(gs, meta)
     if meta.kind == 'dense':
@@ -1024,7 +1192,7 @@ def local_decomposition(plan, decomp, axis_name, comm_mode, method):
     return {'invs': _local_rows(plan, decomp['invs'], axis_name, comm_mode)}
 
 
-def guard_decomposition(decomp_new, decomp_prev, method):
+def guard_decomposition(decomp_new, decomp_prev, method, done=()):
     """Non-finite screen over a freshly-computed decomposition: per row,
     fall back to the last good decomposition, or to the identity when no
     good one exists yet (all-zero cold state).
@@ -1040,6 +1208,8 @@ def guard_decomposition(decomp_new, decomp_prev, method):
     local rows, or both gathered/replicated). Only the decomposition
     keys of ``decomp_new`` are consulted — extra state keys (E-KFAC
     scales) are screened separately by :func:`where_finite_rows`.
+    ``done``: keys of the buckets screened already, as they were written
+    (:func:`tiled_buckets`): passed through.
     """
     if method == 'eigh':
         out_d, out_q = {}, {}
@@ -1058,6 +1228,9 @@ def guard_decomposition(decomp_new, decomp_prev, method):
         return out
     out_i = {}
     for key, xn in decomp_new['invs'].items():
+        if key in done:
+            out_i[key] = xn
+            continue
         xp = decomp_prev['invs'][key]
         good = _rows_finite(xn)
         cold = jnp.logical_not(jnp.any(xp != 0, axis=(-2, -1)))
@@ -1241,6 +1414,17 @@ def preconditioned_grads(plan, grads, grad_mats, preds, lr, kl_clip,
     else:
         nu = jnp.float32(1.0)
     new_grads = grads
+    stacks = {}     # a stacked leaf is written once, from all its slices
     for i, meta in enumerate(plan.metas):
-        new_grads = write_grad_matrix(meta, new_grads, preds[i] * nu)
+        if meta.kind == 'stacked':
+            stacks.setdefault(meta.path, {})[meta.index] = (preds[i] * nu).T
+        else:
+            new_grads = write_grad_matrix(meta, new_grads, preds[i] * nu)
+    for path, slices in stacks.items():
+        # nn.StackedDense reports every slice, so all of them are here
+        sub = dict(capture.get_path(new_grads, path))
+        sub['kernel'] = jnp.stack(
+            [slices[e] for e in range(sub['kernel'].shape[0])]
+        ).astype(sub['kernel'].dtype)
+        new_grads = capture.set_path(new_grads, path, sub)
     return new_grads

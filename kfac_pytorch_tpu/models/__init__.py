@@ -15,6 +15,8 @@ from kfac_pytorch_tpu.models.densenet import (
 from kfac_pytorch_tpu.models.inception_v4 import inception_v4
 from kfac_pytorch_tpu.models.rnn import wikitext_lstm
 from kfac_pytorch_tpu.models.gpt import TransformerLM, transformer_lm
+from kfac_pytorch_tpu.models.sparse_decoder import (
+    SparseDecoderConfig, SparseDecoderLM, sparse_decoder_lm)
 
 
 def get_model(name, num_classes=10, **kw):

@@ -84,6 +84,51 @@ class Dense(linen.Module, _KFACLayerMixin):
         return self._tap_output(y)
 
 
+class StackedDense(linen.Module, _KFACLayerMixin):
+    """``E`` bias-free dense layers kept as ONE leaf ``kernel [E, d_in,
+    d_out]`` and applied as one grouped product (the experts a routed layer
+    holds). Input ``x [E, C, d_in]``: a buffer of ``C`` rows a layer, the
+    rows past ``rows[e]`` zero; output ``[E, C, d_out]``.
+
+    K-FAC treats every slice as a layer of its own (one ``LayerMeta`` of
+    kind ``'stacked'`` with its ``index``): a factor pair from the rows
+    that came to it. For that the layer sows, beside its input, ``n`` =
+    ``rows`` (``[E]``, how many rows each slice got) and ``t`` =
+    ``loss_rows``, the size of the loss's mean (all tokens, not the rows
+    routed here): ``A_e = a_e'a_e / max(n_e, 1)``, ``G_e = (t g_e)'(t g_e)
+    / max(n_e, 1)``, and a slice no row came to keeps its running averages
+    (``engine.compute_layer_stats`` / ``update_factors``).
+    """
+    features: int
+    dtype: Optional[Any] = None
+    param_dtype: Any = jnp.float32
+    #: fan-in of ONE slice (the default initializer would take ``E * d_in``)
+    kernel_init: Callable = linen.initializers.variance_scaling(
+        1.0, 'fan_in', 'normal', batch_axis=(0,))
+    kfac_enabled: bool = True
+
+    @linen.compact
+    def __call__(self, x, rows, loss_rows):
+        n_stack, _, d_in = x.shape
+        kernel = self.param('kernel', self.kernel_init,
+                            (n_stack, d_in, self.features), self.param_dtype)
+        if self.kfac_enabled:
+            for e in range(n_stack):
+                capture.report_layer(capture.LayerMeta(
+                    name='/'.join(self.path) + f'/{e}', path=tuple(self.path),
+                    kind='stacked', use_bias=False, in_dim=d_in,
+                    out_dim=self.features,
+                    kernel_shape=(d_in, self.features), index=e))
+            self._capture_input(x)
+            self.sow(capture.ACTS, 'n', jnp.asarray(rows, jnp.float32),
+                     reduce_fn=_overwrite, init_fn=lambda: ())
+            self.sow(capture.ACTS, 't', jnp.asarray(loss_rows, jnp.float32),
+                     reduce_fn=_overwrite, init_fn=lambda: ())
+        x, kernel = linen.dtypes.promote_dtype(x, kernel, dtype=self.dtype)
+        y = jnp.einsum('ecd,edf->ecf', x, kernel)
+        return self._tap_output(y)
+
+
 class Conv(linen.Module, _KFACLayerMixin):
     """2-D convolution with K-FAC capture (reference hook target:
     ``nn.Conv2d``). NHWC inputs, HWIO kernel.

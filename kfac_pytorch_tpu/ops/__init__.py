@@ -20,6 +20,8 @@ from kfac_pytorch_tpu.ops.factors import (
 )
 from kfac_pytorch_tpu.ops.linalg import (
     psd_inverse,
+    damped_psd_inverse,
+    inverse_tiling,
     sym_eig,
     jacobi_eigh,
     subspace_eigh,
@@ -35,7 +37,7 @@ __all__ = [
     'extract_patches', 'compute_a_dense', 'compute_a_conv',
     'compute_g_dense', 'compute_g_conv', 'layer_rows_dense',
     'layer_rows_conv', 'ekfac_scales', 'update_running_avg',
-    'psd_inverse', 'sym_eig', 'jacobi_eigh', 'subspace_eigh',
+    'psd_inverse', 'damped_psd_inverse', 'inverse_tiling', 'sym_eig', 'jacobi_eigh', 'subspace_eigh',
     'newton_schulz_inverse', 'warm_inverse',
     'clamp_eigvals', 'add_scaled_identity',
     'masked_trace', 'identity_pad',

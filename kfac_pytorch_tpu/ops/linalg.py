@@ -42,6 +42,97 @@ def psd_inverse(x):
         chol, y, left_side=True, lower=True, transpose_a=True)
 
 
+#: What the Cholesky inverse of a bucket costs in temporaries: the compiler
+#: unrolls each triangular solve into D / 128 panel steps and keeps their
+#: shrinking right-hand sides, D / 256 times the right-hand side's bytes
+#: (sandbox compiles for a v5e, PR 39: 12 x 3,200^2 6.0 GB, 3 x 6,144^2
+#: 10.8 GB, 8 x 2,048^2 0.95 GB; the Cholesky factorisation itself takes
+#: the bucket's bytes once). A bucket whose estimate stays under
+#: WHOLE_INVERSE_TEMP_BYTES is inverted whole: at or above the largest the
+#: benchmark's dense cells invert (BERT-base's 12 x 3,200^2, 6.1 GB), so
+#: their programs are what they were.
+WHOLE_INVERSE_TEMP_BYTES = 6 * 2 ** 30
+#: ... a larger one in groups of rows, and where one matrix alone passes
+#: it in panels of the identity's columns, each within this estimate
+INVERSE_GROUP_TEMP_BYTES = 2 ** 30
+
+
+def inverse_tiling(rows, dim, itemsize=4):
+    """How a ``[rows, dim, dim]`` bucket is inverted, from its shape alone:
+    ``(rows a group, columns a panel)``. ``(rows, dim)`` is whole."""
+    one = dim ** 3 * itemsize // 256    # a matrix's estimated temporaries
+    if rows * one <= WHOLE_INVERSE_TEMP_BYTES:
+        return rows, dim
+    if one <= INVERSE_GROUP_TEMP_BYTES:
+        return min(rows, INVERSE_GROUP_TEMP_BYTES // one), dim
+    panels = next(p for p in range(1, dim + 1) if dim % p == 0
+                  and one // p <= INVERSE_GROUP_TEMP_BYTES)
+    return 1, dim // panels
+
+
+def _psd_inverse_panels(x, width):
+    """:func:`psd_inverse` with the identity solved ``width`` columns at a
+    time (the same two triangular solves a panel, one after the other)."""
+    chol = jnp.linalg.cholesky(x)
+    d = x.shape[-1]
+
+    def one_panel(j):
+        cols = j * width + jnp.arange(width)
+        eye = (jnp.arange(d)[:, None] == cols[None, :]).astype(x.dtype)
+        eye = jnp.broadcast_to(eye, x.shape[:-1] + (width,))
+        y = lax.linalg.triangular_solve(chol, eye, left_side=True, lower=True)
+        return lax.linalg.triangular_solve(
+            chol, y, left_side=True, lower=True, transpose_a=True)
+
+    out = lax.map(one_panel, jnp.arange(d // width))    # [P, rows, D, width]
+    return jnp.moveaxis(out, 0, -2).reshape(x.shape)
+
+
+def damped_psd_inverse(x, damp, prev=None, guard=False, commit=None):
+    """``(x + damp I)^-1`` of a bucket ``x [rows, D, D]``, ``damp [rows]``:
+    whole, or tile by tile where :func:`inverse_tiling` says so.
+
+    A group is ``size`` consecutive rows, damped, inverted and written
+    into the result where they belong, one group after the other, so that
+    only one group's damped copy and Cholesky temporaries live at a time.
+    The last group starts at ``rows - size``: it inverts a few rows of its
+    neighbour again (the same bits) instead of padding the bucket.
+
+    ``prev`` (tiled buckets only; the whole path takes no notice): the
+    bucket's stored inverses. The groups are written over them, so that a
+    donated state's buffer is the result's and no second bucket is held;
+    with ``guard`` a row whose new inverse is not finite keeps its stored
+    one, or the identity where none is stored yet (all zeros): what
+    ``engine.guard_decomposition`` does to a whole bucket, group by
+    group. ``commit`` (a traced bool; needs ``prev``): where False every
+    row keeps its stored inverse, as if the bucket had not been touched."""
+    rows, d = x.shape[0], x.shape[-1]
+    size, width = inverse_tiling(rows, d, x.dtype.itemsize)
+    if (size, width) == (rows, d):
+        return psd_inverse(add_scaled_identity(x, damp))
+
+    def one_group(i, out):
+        start = jnp.minimum(i * size, rows - size)
+        xs = add_scaled_identity(
+            lax.dynamic_slice_in_dim(x, start, size, axis=0),
+            lax.dynamic_slice_in_dim(damp, start, size, axis=0))
+        inv = (psd_inverse(xs) if width == d
+               else _psd_inverse_panels(xs, width))
+        old = lax.dynamic_slice_in_dim(out, start, size, axis=0)
+        if guard:
+            good = jnp.all(jnp.isfinite(inv), axis=(-2, -1), keepdims=True)
+            cold = jnp.logical_not(jnp.any(old != 0, axis=(-2, -1),
+                                           keepdims=True))
+            inv = jnp.where(good, inv, jnp.where(
+                cold, jnp.eye(d, dtype=x.dtype), old))
+        if commit is not None:
+            inv = jnp.where(commit, inv, old)
+        return lax.dynamic_update_slice_in_dim(out, inv, start, axis=0)
+
+    return lax.fori_loop(0, -(-rows // size), one_group,
+                         jnp.zeros_like(x) if prev is None else prev)
+
+
 #: batched matmul at HIGHEST internal precision — the warm-path kernels
 #: (Newton-Schulz, subspace tracking) are accuracy-sensitive contractions
 _mm = functools.partial(jnp.einsum, precision=lax.Precision.HIGHEST)

@@ -297,8 +297,15 @@ def pred_layout_record(plan: 'FactorPlan'):
     - ``pad_flop_share``: multiplied over needed flop of the apply's
       GEMMs, ``dg²·da + dg·da²`` multiply-adds a row at the bucket dims
       (dummy slots of the owner-local layout included) over the same at
-      the layers' true dims.
+      the layers' true dims;
+    - ``stacked_layers``: how many of the layers are slices of a stacked
+      leaf (``nn.StackedDense``: an expert each);
+    - ``decomp_groups``: ``{str(bucket dim): [groups of rows, panels of
+      columns]}`` for the buckets the Cholesky decomposition inverts tile
+      by tile (``ops.inverse_tiling``), empty where every bucket goes
+      whole.
     """
+    from kfac_pytorch_tpu.ops.linalg import inverse_tiling
     local = plan.comm_mode == 'pred'
     reads = [pg.run_starts(side, local) is not None
              for pg in plan.pred_groups for side in 'ag']
@@ -309,9 +316,17 @@ def pred_layout_record(plan: 'FactorPlan'):
         for i in pg.layer_idx:
             m = plan.metas[int(i)]
             true += m.out_dim ** 2 * m.in_dim + m.out_dim * m.in_dim ** 2
+    groups = {}
+    for bdim in plan.bucket_dims:
+        rows = plan.buckets[bdim].per_dev
+        size, width = inverse_tiling(rows, bdim)
+        if (size, width) != (rows, bdim):
+            groups[str(bdim)] = [-(-rows // size), bdim // width]
     return {'pred_operand_slices': sum(reads),
             'pred_operand_takes': len(reads) - sum(reads),
-            'pad_flop_share': round(padded / true, 4)}
+            'pad_flop_share': round(padded / true, 4),
+            'stacked_layers': sum(m.kind == 'stacked' for m in plan.metas),
+            'decomp_groups': groups}
 
 
 def _slot_cost(dim):

@@ -542,7 +542,7 @@ class KFAC:
         over the layer list the new world's plan discovered.
         """
         if not isinstance(metas, dict):
-            metas = {m.path: m for m in metas}
+            metas = {m.name: m for m in metas}
         if self.exclude_vocabulary_size is not None:
             from kfac_pytorch_tpu.capture import filter_vocab_head
             metas = filter_vocab_head(metas, self.exclude_vocabulary_size)
@@ -587,6 +587,15 @@ class KFAC:
             'precond.setup: %d buckets %s, %s', len(self.plan.bucket_dims),
             self.plan.bucket_dims,
             ', '.join(f'{k} {v}' for k, v in record.items()))
+        if record['decomp_groups'] and not self.hoists_update:
+            # said once, not left to a compile that runs out of memory
+            logging.getLogger(__name__).warning(
+                'precond.setup: buckets %s are too large to invert whole, '
+                'and this variant (eigh, stagger, E-KFAC or prefetch) '
+                'updates them inside the health guard\'s cond, where the '
+                'compiler copies each before a branch writes to it: the '
+                'Cholesky variants hoist that update out (hoists_update)',
+                sorted(record['decomp_groups']))
         return self.plan
 
     def rebase_cohorts(self):
@@ -958,6 +967,22 @@ class KFAC:
         return new_state
 
     @property
+    def hoists_update(self):
+        """Whether the trainer runs this plan's factor and inverse updates
+        outside the health guard's ``cond`` (``step(update_only=True,
+        commit=ok)``) and preconditions inside. True where a bucket is
+        inverted tile by tile (``engine.tiled_buckets``): inside a
+        ``cond`` the compiler copies such a bucket before the branch
+        writes to it (a second 2 GB beside the sparse decoder's 2,048
+        bucket; sandbox compiles, PERF.md PR 39), outside it the groups
+        are written where they lie. From the plan's shapes alone; the
+        Cholesky variants without stagger, E-KFAC or prefetch."""
+        return bool(self.plan is not None and self.method != 'eigh'
+                    and not self.stagger and not self.ekfac
+                    and not self.comm_prefetch
+                    and engine.tiled_buckets(self.plan))
+
+    @property
     def resolved_decomp_impl(self):
         """The kernel the traced step actually selects: 'auto' resolves
         per method (subspace for eigh, Newton-Schulz for Cholesky);
@@ -1112,7 +1137,8 @@ class KFAC:
              update_factors: bool = True, update_inverse: bool = True,
              update_basis: bool = True, warm_basis: bool = False,
              factors_only: bool = False, stagger_update: bool = False,
-             prefetch: bool = False, axis_name: str = '__default__'):
+             prefetch: bool = False, axis_name: str = '__default__',
+             update_only: bool = False, commit=None):
         """One K-FAC step: (state, grads, captured stats) ->
         (preconditioned grads, new state).
 
@@ -1139,6 +1165,15 @@ class KFAC:
         runs one full decomposition first); a cold state would
         precondition with zeros.
 
+        ``update_only`` (STATIC) with ``commit`` (a traced bool): the
+        hoisted form of a factor and inverse update (:attr:`hoists_update`).
+        Statistics, running averages and decomposition run as ever and the
+        state comes back WITHOUT the step counted and without a gradient
+        touched (``grads`` may be None); where ``commit`` is False every
+        factor and inverse keeps its stored value. The trainer calls it
+        outside the health guard's ``cond`` and preconditions inside with a
+        plain ``step(update_factors=False, update_inverse=False)``.
+
         Parity with step() (kfac_preconditioner_base.py:185-230): factor
         stats + running-avg update (+ pmean for MPD), decomposition on the
         local shard, gather/owner-pred per comm mode, KL-clipped write-back.
@@ -1162,6 +1197,7 @@ class KFAC:
             if self.exclude_communicate_factor:
                 reduce = 'local'
             cap_impl = self.resolved_capture_impl
+            rowwise = ()
             if (cap_impl == 'pallas' and reduce == 'local'
                     and plan.num_devices == 1):
                 # single-device local stats: the whole capture chain
@@ -1176,11 +1212,14 @@ class KFAC:
                 # named scopes mirror the reference's phase taxonomy
                 # (exclude_parts names) so xprof traces attribute time
                 # the same way scripts/time_breakdown.py does
+                rowwise = engine.rowwise_buckets(plan, reduce)
+                stacks = {}
                 with jax.named_scope('kfac.ComputeFactor'):
                     a_list, g_list = engine.compute_layer_stats(
                         plan, acts, gs, self.batch_averaged,
-                        capture_impl=cap_impl)
-                    stats = engine.stack_stats(plan, a_list, g_list)
+                        capture_impl=cap_impl, stacks=stacks)
+                    stats = engine.stack_stats(plan, a_list, g_list,
+                                               skip=rowwise)
                 with jax.named_scope('kfac.UpdateFactors'):
                     # the pmean inside carries its own CommunicateFactor
                     # scope
@@ -1190,7 +1229,8 @@ class KFAC:
                         plan, factors, stats, self.factor_decay, reduce,
                         axis_name, comm_precision=self.comm_precision,
                         comm_err=comm_err, capture_impl=cap_impl,
-                        extra_reduce=extra)
+                        extra_reduce=extra,
+                        seen=engine.rows_seen(plan, acts))
             if self.health is not None and comm_err is not None:
                 # a non-finite residual row resets to zero (the always-
                 # safe EF state: feedback is a correction, never load-
@@ -1206,8 +1246,25 @@ class KFAC:
                 # corruption) re-initializes to the identity and
                 # re-accumulates — pass-through when everything is finite
                 with jax.named_scope('kfac.HealthGuard.factors'):
-                    factors = engine.where_finite_rows(
-                        factors, state.factors, reinit_identity=True)
+                    factors = {**factors, **engine.where_finite_rows(
+                        {k: v for k, v in factors.items()
+                         if k not in rowwise},
+                        state.factors, reinit_identity=True)}
+            if commit is not None:
+                factors = {k: v if k in rowwise
+                           else jnp.where(commit, v, state.factors[k])
+                           for k, v in factors.items()}
+            if rowwise:
+                # the largest buckets: a run of rows at a time over the
+                # stored rows, screened as they are written
+                factors = dict(factors)
+                with jax.named_scope('kfac.UpdateFactors'):
+                    for key in rowwise:
+                        factors[key] = engine.update_factor_rows(
+                            plan, int(key), state.factors[key], a_list,
+                            g_list,
+                            stacks, self.factor_decay,
+                            guard=self.health is not None, commit=commit)
             # SDC drill: corrupt a stored factor block AFTER the guard,
             # so the corruption lands in the state exactly as a flipped
             # bit would (tests/test_faults.py heal drill)
@@ -1269,13 +1326,22 @@ class KFAC:
                     else:
                         invs_prev = engine.local_invs(
                             plan, decomp, axis_name, self.comm_mode)
+                # buckets inverted tile by tile are written over their
+                # stored rows and screened as they are written
+                tiled = (engine.tiled_buckets(plan)
+                         if self.method != 'eigh' else ())
+                stored = (engine.local_decomposition(
+                    plan, decomp, axis_name, self.comm_mode, self.method)
+                    if tiled else None)
                 with jax.named_scope('kfac.ComputeInverse'):
                     decomp_local = engine.compute_decomposition(
                         plan, factors, damping, self.method, self.eps,
                         axis_name, basis_local=basis_local,
                         warm_sweeps=self.warm_sweeps,
                         invs_prev_local=invs_prev,
-                        impl=self.resolved_decomp_impl)
+                        impl=self.resolved_decomp_impl,
+                        stored_local=stored,
+                        guard=self.health is not None, commit=commit)
                 # chaos drill: simulated eigh/Cholesky blowup, injected
                 # BEFORE the guard so the guard is what survives it
                 decomp_local = faults.corrupt_decomposition(
@@ -1289,10 +1355,19 @@ class KFAC:
                     with jax.named_scope('kfac.HealthGuard.decomp'):
                         decomp_local = engine.guard_decomposition(
                             decomp_local,
+                            stored if tiled else
                             engine.local_decomposition(
                                 plan, decomp, axis_name, self.comm_mode,
                                 self.method),
-                            self.method)
+                            self.method, done=tiled)
+                if commit is not None:
+                    held_back = engine.local_decomposition(
+                        plan, decomp, axis_name, self.comm_mode,
+                        self.method)['invs']
+                    decomp_local = {'invs': {
+                        k: v if k in tiled
+                        else jnp.where(commit, v, held_back[k])
+                        for k, v in decomp_local['invs'].items()}}
                 if self.comm_mode == 'inverse':
                     with jax.named_scope('kfac.CommunicateInverse'):
                         new_decomp = engine.gather_decomposition(
@@ -1319,6 +1394,9 @@ class KFAC:
                                                    'pred'),
                                 decomp_local['evecs'], axis_name)
                     decomp = decomp_local
+        if update_only:
+            return None, state.replace(factors=factors, decomp=decomp,
+                                       comm_err=comm_err)
         if self.ekfac:
             decomp = dict(decomp)
             decomp['scales'] = scales_prev

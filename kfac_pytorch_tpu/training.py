@@ -397,11 +397,17 @@ def build_train_step(model, tx, precond, loss_fn, axis_name=None, mesh=None,
             grads = coll.average_grads(grads, axis_name)
             loss = coll.pmean(loss, axis_name)
 
+        # a plan with buckets too large for the cond (KFAC.hoists_update)
+        # has its factor and inverse updates run before it, committed by
+        # the batch screen's own flag; the branches then start from that
+        # state and the true one only preconditions
+        hoisted = {}
+
         def apply_update(hstate):
             """The normal K-FAC + optimizer update (the only path when
             the health guard is off; the lax.cond true-branch otherwise).
             """
-            kfac_state = state.kfac_state
+            kfac_state = hoisted.get('state', state.kfac_state)
             new_grads = grads
             precond_ok = jnp.ones((), bool)
             if precond is not None:
@@ -413,8 +419,8 @@ def build_train_step(model, tx, precond, loss_fn, axis_name=None, mesh=None,
                         hstate, hyper.damping, health_cfg))
                 pgrads, kfac_state = precond.step(
                     kfac_state, grads, acts, gs, hyper=h,
-                    update_factors=update_factors,
-                    update_inverse=update_inverse,
+                    update_factors=update_factors and not hoisted,
+                    update_inverse=update_inverse and not hoisted,
                     update_basis=update_basis,
                     warm_basis=warm_basis, factors_only=factors_only,
                     stagger_update=stagger_update, prefetch=prefetch,
@@ -458,13 +464,15 @@ def build_train_step(model, tx, precond, loss_fn, axis_name=None, mesh=None,
                                  extra_vars=extra_vars, health=hstate)
 
         if health_cfg is None:
-            return apply_update(state.health), {'loss': loss}
+            new_state = apply_update(state.health)
+            return new_state, {'loss': loss, **capture.counter_metrics(
+                new_state.extra_vars)}
 
         def skip_update(hstate):
             """Bad batch: params, opt_state, factor EMAs and extra_vars
             stay bit-exactly as if the batch never happened; only the
             step counters and health counters advance."""
-            kfac_state = state.kfac_state
+            kfac_state = hoisted.get('state', state.kfac_state)
             if kfac_state is not None:
                 # keep KFACState.step in lockstep with TrainState.step so
                 # in-engine fault steps stay aligned with trainer steps
@@ -478,9 +486,23 @@ def build_train_step(model, tx, precond, loss_fn, axis_name=None, mesh=None,
         # every device agrees (batch_ok psums the per-shard bad flags)
         with jax.named_scope('train.health_screen'):
             ok = health_lib.batch_ok(axis_name, grads, loss_local, acts, gs)
+        if (precond is not None and precond.hoists_update
+                and (update_factors or update_inverse)
+                and not (factors_only or stagger_update or prefetch)):
+            _, hoisted['state'] = precond.step(
+                state.kfac_state, None, acts, gs,
+                hyper=hyper.replace(damping=health_lib.effective_damping(
+                    state.health, hyper.damping, health_cfg)),
+                update_factors=update_factors,
+                update_inverse=update_inverse, update_basis=update_basis,
+                warm_basis=warm_basis, axis_name=axis_name,
+                update_only=True, commit=ok)
         new_state = jax.lax.cond(ok, apply_update, skip_update,
                                  state.health)
-        mets = {'loss': loss}
+        # what the model counts (capture.COUNTERS), as the new state has
+        # it: a refused batch leaves a cumulative counter where it was
+        mets = {'loss': loss,
+                **capture.counter_metrics(new_state.extra_vars)}
         with jax.named_scope('train.health_screen'):
             mets.update({'health/' + k: v for k, v in
                          health_lib.metrics(new_state.health, ok).items()})

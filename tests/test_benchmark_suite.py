@@ -1,12 +1,15 @@
 """The benchmark's own tests, collected under ``tests/`` so that tier-1
 runs them: the configuration files ``BENCHMARK.json`` names, the
 reduction from a trace to the ledger's metrics, and the readers of the
-program's spans (``benchmarks/tests/test_configs.py``, ``test_reduce.py``,
-``test_spans.py``). They stay where the benchmark keeps them; this file
-only puts their directories on the path and imports their cases.
-``test_run_cpu.py`` (17 cases, each a ``run.py`` subprocess) is not
+program's spans, the plain K-FAC reference and the tally of a run's
+failures, and the sparse decoder's cell (``benchmarks/tests/test_configs.py``,
+``test_reduce.py``, ``test_spans.py``, ``test_reference.py``,
+``test_sparse_lm.py``). They stay where the benchmark keeps them; this
+file only puts their directories on the path and imports their cases.
+``test_run_cpu.py`` (19 cases, each a ``run.py`` subprocess) is not
 collected: alone on this CPU it takes 313 s, more than a tier-1 worker
-has to spare (CHANGES.md, PR 35).
+has to spare (CHANGES.md, PR 35); ``test_sparse_lm.py`` brings four such
+runs of its own tiny cell.
 """
 
 import os
@@ -23,3 +26,7 @@ for _p in (os.path.join(_REPO, 'benchmarks', 'tests'),
 from test_configs import *  # noqa: E402,F401,F403
 from test_reduce import *  # noqa: E402,F401,F403
 from test_spans import *  # noqa: E402,F401,F403
+# test_configs imports test_reference's cases too: by name here, so that
+# they do not hang on that
+from test_reference import *  # noqa: E402,F401,F403
+from test_sparse_lm import *  # noqa: E402,F401,F403

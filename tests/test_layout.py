@@ -217,7 +217,9 @@ def test_pred_group_rows_contiguous_per_device(model, ndev, assignment):
                                   pad_flop_share=1.1605)),
 ])
 def test_layout_record_of_the_benchmark_models(model, comm_mode, record):
-    assert pred_layout_record(_plan(model, 1, comm_mode)) == record
+    # dense and conv layers only, every bucket inverted whole
+    assert pred_layout_record(_plan(model, 1, comm_mode)) == dict(
+        record, stacked_layers=0, decomp_groups={})
     assert record['pad_flop_share'] <= 1.18
 
 
