@@ -28,7 +28,7 @@ applied to a temporary — the mathematically intended semantics.
 
 import contextlib
 import functools
-from typing import Dict, List, Optional
+from typing import Dict, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -190,6 +190,103 @@ def rows_seen(plan, acts):
     return out
 
 
+def stats_finite(plan, a_list, g_list, stacks):
+    """``([L] bool, [L] bool)``: is layer ``i``'s ``A`` / ``G`` statistic
+    (:func:`compute_layer_stats`) free of NaN and Inf, read from its
+    diagonal (``ops.tile_diagonal``: 512 / d of its bytes). The health
+    guard's witness for the captured tensors, which it no longer reads: a
+    statistic is a Gram product of what was captured, so each captured
+    element it reads is squared into a diagonal entry, where nothing can
+    cancel it. A NaN or an Inf in a captured activation or output-gradient
+    therefore shows on that layer's diagonal, exactly; so does a finite
+    value whose square overflows float32 (above about 1.8e19), which the
+    captured tensor's own ``isfinite`` let through. An off-diagonal sum is
+    bounded by the larger of its two diagonal entries (Cauchy-Schwarz), so
+    with finite inputs it overflows only where one of those does, to the
+    rounding of the last addition (and a row that did is still caught as
+    it is written, :func:`settle_factor_rows`). What the diagonal cannot
+    see is a captured element that no statistic reads (the odd pixels
+    under a 1x1 convolution of stride 2): such a value reaches no factor.
+    A stacked leaf's slices are read once, from its batched statistics."""
+    # 512-wide tiles: a statistic a layer and side is many small matrices,
+    # and at 128 their tiles were a fifth of the update program's kernels
+    ok = functools.partial(ops.diagonal_finite, tile=512)
+    leaf_ok = {path: (ok(both[0]), ok(both[1]))
+               for path, (both, _) in stacks.items()}
+    ok_a, ok_g = [], []
+    for i, meta in enumerate(plan.metas):
+        if meta.kind == 'stacked':
+            ok_a.append(leaf_ok[meta.path][0][meta.index])
+            ok_g.append(leaf_ok[meta.path][1][meta.index])
+        else:
+            ok_a.append(ok(a_list[i]))
+            ok_g.append(ok(g_list[i]))
+    return jnp.stack(ok_a), jnp.stack(ok_g)
+
+
+class LayerStats(NamedTuple):
+    """What :func:`compute_layer_stats` makes of one batch, with
+    :func:`stats_finite`'s flags: a pure function of the captured tensors,
+    so the trainer can make it before the health guard's ``cond`` and hand
+    it to ``KFAC.step`` inside."""
+    a_list: list
+    g_list: list
+    stacks: dict
+    ok_a: jnp.ndarray
+    ok_g: jnp.ndarray
+
+
+def layer_stats(plan, acts, gs, batch_averaged=True, capture_impl=None):
+    """:func:`compute_layer_stats` and :func:`stats_finite` in one."""
+    stacks = {}
+    a_list, g_list = compute_layer_stats(plan, acts, gs, batch_averaged,
+                                         capture_impl, stacks)
+    return LayerStats(a_list, g_list, stacks,
+                      *stats_finite(plan, a_list, g_list, stacks))
+
+
+def rows_ok(plan, stats):
+    """``{bucket key: [n_rows] bool}``: :func:`stats_finite`'s flag of the
+    statistic each factor row averages in (True for a dummy row, whose
+    statistic is the identity)."""
+    n = len(plan.metas)
+    flat = jnp.concatenate([stats.ok_a, stats.ok_g, jnp.ones((1,), bool)])
+    return {_key(bdim): flat[np.asarray(
+        [2 * n if s is None else s.layer_idx + (0 if s.side == 'A' else n)
+         for s in plan.buckets[bdim].slot_of_row])]
+        for bdim in plan.bucket_dims}
+
+
+@functools.partial(jax.jit, static_argnames='guard')
+def settle_factor_rows(fresh, old, take, guard, commit=None):
+    """New running averages ``fresh [k, D, D]`` as they may be kept, in the
+    pass that writes them.
+
+    ``take``: ``[k]`` flags known before that pass (the row was seen, its
+    statistic is finite, the batch is committed); a row with one of them
+    False keeps ``old``. With ``guard`` the row's own flag comes out of the
+    same pass (one fusion writes the rows and reduces ``isfinite`` over
+    them: ``tests/test_chip_compile.py``), and a row that is still not
+    finite is one whose STORED value was corrupt (silent data corruption):
+    it starts again from the identity, its init() value, and re-accumulates
+    from fresh statistics rather than staying NaN for the rest of the run
+    (:func:`ops.heal_rows`: only such rows are written again). A statistic
+    that is not finite never gets this far (its ``take`` flag) and a
+    weighted mean of two finite values is finite, so the two together are
+    "the last good row, or the identity where that is corrupt too".
+    ``commit`` False: nothing is healed either."""
+    if take:
+        keep = functools.reduce(jnp.logical_and, take)
+        fresh = jnp.where(keep[:, None, None], fresh, old)
+    if not guard:
+        return fresh
+    bad = jnp.logical_not(ops.rows_finite(fresh))
+    if commit is not None:
+        bad = jnp.logical_and(bad, commit)
+    eye = jnp.eye(fresh.shape[-1], dtype=fresh.dtype)
+    return ops.heal_rows(fresh, bad, lambda r, _: eye)[0]
+
+
 def rowwise_buckets(plan, stats_reduce):
     """Keys of the buckets whose running averages are updated row by row,
     over the stored rows (:func:`update_factor_rows`): the buckets too
@@ -242,27 +339,28 @@ def _stat_runs(plan, bdim, a_list, g_list, stacks):
 
 
 def update_factor_rows(plan, bdim, current, a_list, g_list, stacks,
-                       factor_decay, guard, commit=None):
+                       factor_decay, guard, commit=None, stat_ok=None):
     """One bucket's running averages, a run of rows at a time written
     over ``current`` (a donated state's buffer is the result's; the
-    bucket's statistics are never stacked): what :func:`stack_stats`,
-    :func:`update_factors` with :func:`rows_seen`'s flags and, with
-    ``guard``, ``where_finite_rows(..., reinit_identity=True)`` do to a
-    whole bucket. ``commit`` (a traced bool): where False every row stays
-    as it was."""
+    bucket's statistics are never stacked): what :func:`stack_stats` and
+    :func:`update_factors` with :func:`rows_seen`'s flags and the same
+    ``guard``, ``stat_ok`` and ``commit`` do to a whole bucket, by the same
+    :func:`settle_factor_rows`."""
     out = current
-    eye = jnp.eye(bdim, dtype=current.dtype)
     for first, stat, came in _stat_runs(plan, bdim, a_list, g_list, stacks):
-        old = lax.dynamic_slice_in_dim(out, first, stat.shape[0], axis=0)
-        new = ops.update_running_avg(stat, old, factor_decay)
-        if came is not None:
-            new = jnp.where(came[:, None, None], new, old)
+        k = stat.shape[0]
+        old = lax.dynamic_slice_in_dim(out, first, k, axis=0)
+        take = [] if came is None else [came]
         if guard:
-            new = jnp.where(
-                _rows_finite(new)[:, None, None], new,
-                jnp.where(_rows_finite(old)[:, None, None], old, eye))
+            take.append(ops.rows_finite(stat) if stat_ok is None
+                        else stat_ok[first:first + k])
         if commit is not None:
-            new = jnp.where(commit, new, old)
+            take.append(jnp.broadcast_to(commit, (k,)))
+        # (settled before it is written: the repair loop carries the run,
+        # never the stored bucket)
+        new = settle_factor_rows(
+            ops.update_running_avg(stat, old, factor_decay), old, take,
+            guard, commit)
         out = lax.dynamic_update_slice_in_dim(out, new, first, axis=0)
     return out
 
@@ -358,7 +456,7 @@ def _update_factors_fused(pc, plan, factors_local, acts, gs, batch_averaged,
 def update_factors(plan, factors_local, stats_stacked, factor_decay,
                    stats_reduce, axis_name, comm_precision='fp32',
                    comm_err=None, capture_impl=None, extra_reduce=(),
-                   seen=None):
+                   seen=None, guard=False, stat_ok=None, commit=None):
     """Running-average update of the local factor shard.
 
     ``stats_reduce='pmean'``: MPD semantics — factors are the global-batch
@@ -395,6 +493,14 @@ def update_factors(plan, factors_local, stats_stacked, factor_decay,
 
     ``seen``: :func:`rows_seen`'s flags; a row whose flag is False keeps
     its running average (an expert no row was routed to).
+
+    ``guard``: the health guard's screen, :func:`settle_factor_rows`. A
+    row whose statistic is not finite keeps its average: by ``stat_ok``
+    (:func:`rows_ok`) where the statistics are this device's own, by a read
+    of the reduced rows where they come off the wire (``pmean``: another
+    device's share or the error-feedback residual can be at fault, so the
+    flags made before the reduce do not cover them). ``commit`` (a traced
+    bool): where False every row stays as it was.
     """
     new = {}
     new_err = None if comm_err is None else dict(comm_err)
@@ -430,14 +536,20 @@ def update_factors(plan, factors_local, stats_stacked, factor_decay,
             idx = coll.axis_index(axis_name)
             local = lax.dynamic_slice_in_dim(stats, idx * b.per_dev,
                                              b.per_dev, axis=0)
-        new[key] = ops.update_running_avg(local, factors_local[key],
-                                          factor_decay)
-        if seen is not None:
-            came = lax.dynamic_slice_in_dim(
-                seen[key], coll.axis_index(axis_name) * b.per_dev,
+        def mine(flags):
+            return lax.dynamic_slice_in_dim(
+                flags[key], coll.axis_index(axis_name) * b.per_dev,
                 b.per_dev)
-            new[key] = jnp.where(came[:, None, None], new[key],
-                                 factors_local[key])
+        take = [] if seen is None else [mine(seen)]
+        if guard:
+            take.append(ops.rows_finite(local)
+                        if stat_ok is None or stats_reduce == 'pmean'
+                        else mine(stat_ok))
+        if commit is not None:
+            take.append(jnp.broadcast_to(commit, (b.per_dev,)))
+        new[key] = settle_factor_rows(
+            ops.update_running_avg(local, factors_local[key], factor_decay),
+            factors_local[key], take, guard, commit)
     return new, new_err
 
 
@@ -879,7 +991,7 @@ def merge_shard_decomposition(plan, shard, decomp_stored, shard_new,
                 # together or not at all — a half-committed pair would
                 # precondition in a basis its spectrum does not match
                 ok = jnp.logical_and(ok, jnp.logical_and(
-                    _rows_finite(fresh_d), _rows_finite(fresh_q)))
+                    ops.rows_finite(fresh_d), ops.rows_finite(fresh_q)))
             new_d[key] = pick(ok, fresh_d, decomp_stored['evals'][key])
             new_q[key] = pick(ok, fresh_q, decomp_stored['evecs'][key])
         out['evals'], out['evecs'] = new_d, new_q
@@ -892,7 +1004,7 @@ def merge_shard_decomposition(plan, shard, decomp_stored, shard_new,
         slots, ok = tables(bdim)
         fresh = jnp.take(xg, slots, axis=0)
         if guard:
-            ok = jnp.logical_and(ok, _rows_finite(fresh))
+            ok = jnp.logical_and(ok, ops.rows_finite(fresh))
         new_i[key] = pick(ok, fresh, decomp_stored['invs'][key])
     out['invs'] = new_i
     return out
@@ -959,7 +1071,7 @@ def merge_cohort_decomposition(plan, cohorts, decomp_stored, cohort_new,
             ok = valid
             if guard:
                 ok = jnp.logical_and(ok, jnp.logical_and(
-                    _rows_finite(dn), _rows_finite(qn)))
+                    ops.rows_finite(dn), ops.rows_finite(qn)))
             d_prev = jnp.take(ds, rows, axis=0)
             q_prev = jnp.take(qs, rows, axis=0)
             new_d[key] = ds.at[rows].set(jnp.where(ok[:, None], dn, d_prev))
@@ -975,7 +1087,7 @@ def merge_cohort_decomposition(plan, cohorts, decomp_stored, cohort_new,
         xs = decomp_stored['invs'][key]
         ok = valid
         if guard:
-            ok = jnp.logical_and(ok, _rows_finite(xn))
+            ok = jnp.logical_and(ok, ops.rows_finite(xn))
         x_prev = jnp.take(xs, rows, axis=0)
         new_i[key] = xs.at[rows].set(
             jnp.where(ok[:, None, None], xn, x_prev))
@@ -1152,11 +1264,6 @@ def rotate_ekfac_scales(plan, scales, evecs_prev, evecs_new):
     return out
 
 
-def _rows_finite(x):
-    """[rows, ...] -> [rows] bool: row contains no non-finite entry."""
-    return jnp.all(jnp.isfinite(x), axis=tuple(range(1, x.ndim)))
-
-
 def where_finite_rows(new, prev, reinit_identity=False):
     """Per-leading-row non-finite screen over a ``{key: [rows, ...]}``
     dict: rows of ``new`` containing any NaN/Inf are replaced by the
@@ -1169,11 +1276,11 @@ def where_finite_rows(new, prev, reinit_identity=False):
     out = {}
     for key, n in new.items():
         p = prev[key]
-        good = _rows_finite(n)
+        good = ops.rows_finite(n)
         fb = p
         if reinit_identity:
             eye = jnp.eye(n.shape[-1], dtype=n.dtype)
-            pgood = _rows_finite(p)
+            pgood = ops.rows_finite(p)
             fb = jnp.where(pgood[:, None, None], p, eye[None])
         good = good.reshape(good.shape + (1,) * (n.ndim - 1))
         out[key] = jnp.where(good, n, fb)
@@ -1192,7 +1299,8 @@ def local_decomposition(plan, decomp, axis_name, comm_mode, method):
     return {'invs': _local_rows(plan, decomp['invs'], axis_name, comm_mode)}
 
 
-def guard_decomposition(decomp_new, decomp_prev, method, done=()):
+def guard_decomposition(decomp_new, decomp_prev, method, done=(),
+                        guard=True, commit=None):
     """Non-finite screen over a freshly-computed decomposition: per row,
     fall back to the last good decomposition, or to the identity when no
     good one exists yet (all-zero cold state).
@@ -1201,22 +1309,33 @@ def guard_decomposition(decomp_new, decomp_prev, method, done=()):
     then degrades that layer to its previous — still curvature-bearing —
     preconditioner instead of poisoning every subsequent step; a cold
     blowup degrades to the identity, i.e. plain gradient pass-through
-    scaled by ``1/(1+damping)``. Pure ``jnp.where`` selects: the healthy
-    path's output is bit-identical to the unguarded computation.
+    scaled by ``1/(1+damping)``. The healthy path's output is
+    bit-identical to the unguarded computation.
+
+    A Cholesky inverse is settled by :func:`ops.settle_inverse_rows`: its
+    flag is read from its diagonal, which is exact for that operand
+    (:func:`ops.inverse_rows_finite`), the stored bucket is read only for a
+    row at fault, and only such a row is written again. ``commit`` (a
+    traced bool, hoisted updates; Cholesky only): where False every row
+    keeps its stored inverse; ``guard`` False leaves that alone. An
+    eigendecomposition has no such witness (a NaN in one eigenvector says
+    nothing of the others): it keeps a read of every element and a
+    ``jnp.where`` over the bucket, as before.
 
     Layouts must match between ``decomp_new`` and ``decomp_prev`` (both
     local rows, or both gathered/replicated). Only the decomposition
     keys of ``decomp_new`` are consulted — extra state keys (E-KFAC
     scales) are screened separately by :func:`where_finite_rows`.
-    ``done``: keys of the buckets screened already, as they were written
+    ``done``: keys of the buckets settled already, as they were written
     (:func:`tiled_buckets`): passed through.
     """
     if method == 'eigh':
+        assert guard and commit is None
         out_d, out_q = {}, {}
         for key in decomp_new['evecs']:
             dn, qn = decomp_new['evals'][key], decomp_new['evecs'][key]
             dp, qp = decomp_prev['evals'][key], decomp_prev['evecs'][key]
-            good = jnp.logical_and(_rows_finite(dn), _rows_finite(qn))
+            good = jnp.logical_and(ops.rows_finite(dn), ops.rows_finite(qn))
             cold = jnp.logical_not(jnp.any(qp != 0, axis=(-2, -1)))
             eye = jnp.eye(qn.shape[-1], dtype=qn.dtype)
             fb_q = jnp.where(cold[:, None, None], eye[None], qp)
@@ -1226,19 +1345,11 @@ def guard_decomposition(decomp_new, decomp_prev, method, done=()):
         out = dict(decomp_new)
         out['evals'], out['evecs'] = out_d, out_q
         return out
-    out_i = {}
-    for key, xn in decomp_new['invs'].items():
-        if key in done:
-            out_i[key] = xn
-            continue
-        xp = decomp_prev['invs'][key]
-        good = _rows_finite(xn)
-        cold = jnp.logical_not(jnp.any(xp != 0, axis=(-2, -1)))
-        eye = jnp.eye(xn.shape[-1], dtype=xn.dtype)
-        fb = jnp.where(cold[:, None, None], eye[None], xp)
-        out_i[key] = jnp.where(good[:, None, None], xn, fb)
     out = dict(decomp_new)
-    out['invs'] = out_i
+    out['invs'] = {
+        key: xn if key in done else ops.settle_inverse_rows(
+            xn, decomp_prev['invs'][key], guard, commit)[0]
+        for key, xn in decomp_new['invs'].items()}
     return out
 
 

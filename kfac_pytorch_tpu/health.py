@@ -8,8 +8,9 @@ bad batch producing NaN/Inf gradients would permanently contaminate the
 eigendecomposition — nothing in the hot path checked ``isfinite``.
 
 The guard is entirely IN-JIT (no per-step host sync, no extra compiled
-step variants): the trainer screens the batch's loss, gradients and
-captured factor statistics, and a ``lax.cond`` routes the step —
+step variants): the trainer screens the batch's loss, its gradients and,
+on a factor-update step, the diagonals of its factor statistics
+(``engine.stats_finite``), and a ``lax.cond`` routes the step —
 
 - **healthy batch**: the normal K-FAC + optimizer update runs;
 - **non-finite batch**: BOTH the optimizer update and the factor-EMA
@@ -32,9 +33,37 @@ never contained that batch — escalating damping on the first failure
 would silently fork the two trajectories (pinned by
 tests/test_health.py::test_nan_batch_skips_update_and_ema).
 
-The companion decomposition-level guard lives in
-``engine.guard_decomposition`` (per-row fallback to the last good
-decomposition, identity when cold) and is wired in ``KFAC.step``.
+What is screened, and what is not (PR 42). The screen reads the loss,
+every gradient and the ``[d]`` diagonal of every factor statistic of the
+batch; it does NOT read the captured activations and output-gradients
+again. A statistic is a Gram product of them, so every captured element
+it reads is squared into a diagonal entry, where nothing cancels it: a
+NaN or an Inf there refuses the batch exactly as before
+(``tests/test_health.py::test_stats_fault_triggers_skip`` and the
+one-element cases beside it). Two edges moved. A captured element that no
+statistic and no gradient reads (the odd pixels under a 1x1 convolution
+of stride 2) can no longer refuse a batch: it reaches no state. A finite
+captured value above about 1.8e19, whose square overflows float32, now
+refuses the batch where it used to hold only that factor row back:
+stricter, never looser. Where a step makes no statistics the screen can
+read (the fused Pallas capture, the ComputeFactor ablation) it reads the
+captured tensors as it did.
+
+The guarantee: no non-finite value reaches params, optimizer state,
+factors or decompositions, and a refused batch leaves all of them (and
+``extra_vars``) bit-identical.
+
+The companion engine-level guards are wired in ``KFAC.step``: a factor
+row's flag comes out of the pass that writes its running average and a
+row whose STORED value is corrupt restarts from the identity
+(``engine.settle_factor_rows``); a fresh Cholesky
+inverse's flag is read from its diagonal and a row at fault falls back to
+the last good inverse, the identity when cold
+(``engine.guard_decomposition``, ``ops.settle_inverse_rows``). Neither
+reads or writes a whole operand on a healthy step; what still does (an
+eigendecomposition, E-KFAC's scales, the error-feedback residual,
+statistics reduced over several devices) is counted by
+``KFAC.guard_passes``.
 """
 
 import dataclasses
@@ -101,17 +130,21 @@ class HealthState(flax.struct.PyTreeNode):
                    fallbacks=z())
 
 
-def batch_ok(axis_name, grads, *local_trees):
+def batch_ok(axis_name, grads, *local_trees, flags=()):
     """Scalar bool: is this batch numerically usable on EVERY device?
 
     ``grads`` are already cross-axis reduced (replicated), so their
-    finiteness is checked locally; ``local_trees`` (pre-pmean loss,
-    captured activations / output-gradients) are per-device shards, so
-    their bad-flags are psummed over the axis — one scalar of
-    communication, and the returned flag is replicated (a valid
-    ``lax.cond`` predicate under shard_map).
+    finiteness is checked locally; ``local_trees`` (the pre-pmean loss;
+    captured activations / output-gradients where no statistics exist to
+    stand for them) and ``flags`` (bool arrays already reduced from this
+    device's own data: ``engine.stats_finite``) are per-device, so their
+    bad-flags are psummed over the axis — one scalar of communication, and
+    the returned flag is replicated (a valid ``lax.cond`` predicate under
+    shard_map).
     """
     ok_local = all_finite(*local_trees)
+    for f in flags:
+        ok_local = jnp.logical_and(ok_local, jnp.all(f))
     bad = coll.psum(jnp.where(ok_local, 0.0, 1.0), axis_name)
     return jnp.logical_and(all_finite(grads), bad == 0)
 

@@ -21,6 +21,12 @@ from kfac_pytorch_tpu.ops.factors import (
 from kfac_pytorch_tpu.ops.linalg import (
     psd_inverse,
     damped_psd_inverse,
+    settle_inverse_rows,
+    inverse_rows_finite,
+    diagonal_finite,
+    heal_rows,
+    rows_finite,
+    tile_diagonal,
     inverse_tiling,
     sym_eig,
     jacobi_eigh,
@@ -37,7 +43,9 @@ __all__ = [
     'extract_patches', 'compute_a_dense', 'compute_a_conv',
     'compute_g_dense', 'compute_g_conv', 'layer_rows_dense',
     'layer_rows_conv', 'ekfac_scales', 'update_running_avg',
-    'psd_inverse', 'damped_psd_inverse', 'inverse_tiling', 'sym_eig', 'jacobi_eigh', 'subspace_eigh',
+    'psd_inverse', 'damped_psd_inverse', 'settle_inverse_rows',
+    'inverse_rows_finite', 'diagonal_finite', 'heal_rows', 'rows_finite', 'tile_diagonal',
+    'inverse_tiling', 'sym_eig', 'jacobi_eigh', 'subspace_eigh',
     'newton_schulz_inverse', 'warm_inverse',
     'clamp_eigvals', 'add_scaled_identity',
     'masked_trace', 'identity_pad',

@@ -88,6 +88,123 @@ def _psd_inverse_panels(x, width):
     return jnp.moveaxis(out, 0, -2).reshape(x.shape)
 
 
+def rows_finite(x):
+    """``[rows, ...] -> [rows]`` bool: the row holds no NaN and no Inf."""
+    return jnp.all(jnp.isfinite(x), axis=tuple(range(1, x.ndim)))
+
+
+#: side of the square tiles :func:`tile_diagonal` reads: the lane width of
+#: the device's (8, 128) tiling, which every default bucket dim is a
+#: multiple of (``plan.default_bucket_fn``), so each slice is whole tiles
+DIAGONAL_TILE = 128
+
+
+@functools.partial(jax.jit, static_argnames='tile')
+def tile_diagonal(x, tile=DIAGONAL_TILE):
+    """The diagonal of ``x [..., D, D]``, read from the ``D / tile`` square
+    tiles it runs through and from nothing else: ``tile / D`` of the
+    operand's bytes. ``jnp.diagonal`` is a gather, for which the TPU's
+    compiler first copies the whole operand into another layout (sandbox
+    compile of ``[12, 3200, 3200]``, PR 42: 625 MiB of temporaries).
+    ``tile``: a multiple of 128; a larger one reads more bytes in fewer
+    operations (each tile is a kernel of its own in the program). Jitted,
+    like the two ``settle_*`` helpers, for a caller that steps eagerly
+    (tests do): one executable a shape where it was a dozen primitives;
+    inside a jitted step the call is inlined."""
+    d = x.shape[-1]
+    parts = []
+    for lo in range(0, d, tile):
+        hi = min(lo + tile, d)
+        on = jnp.eye(hi - lo, dtype=bool)
+        parts.append(jnp.sum(jnp.where(on, x[..., lo:hi, lo:hi], 0),
+                             axis=-1))
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=-1)
+
+
+def diagonal_finite(x, tile=DIAGONAL_TILE):
+    """``[..., D, D] -> [...]`` bool: no NaN and no Inf on the diagonal
+    (:func:`tile_diagonal`). A witness for the whole matrix only where its
+    caller says why: a Gram product (``engine.stats_finite``), a Cholesky
+    inverse (:func:`inverse_rows_finite`)."""
+    return jnp.all(jnp.isfinite(tile_diagonal(x, tile)), axis=-1)
+
+
+def inverse_rows_finite(inv):
+    """``[rows]`` bool: does the Cholesky inverse ``inv [rows, D, D]``
+    (:func:`psd_inverse`) hold no NaN and no Inf, read from its diagonal
+    alone. Exact for that operand, whatever went wrong on the way to it:
+
+    * a NaN or Inf anywhere in the (symmetrised) input, or a pivot that is
+      not positive, leaves a NaN in the Cholesky factor ``L`` at that
+      pivot, and every later pivot is computed from it: the last one,
+      ``L[D-1, D-1]``, is NaN, and ``inv[D-1, D-1] = 1 / L[D-1, D-1]^2``;
+    * with ``L`` finite, column ``j`` of the inverse solves ``L L' x =
+      e_j`` by a forward and a backward substitution. An entry ``k >= j``
+      of the forward result that is not finite enters every entry above it
+      in the backward one (``Inf * 0`` is NaN, not 0), down to ``x[j]``,
+      the diagonal entry;
+    * what is left is a finite ``L^-1`` whose products overflow:
+      ``|inv[i, j]| = |sum_k y[k, i] y[k, j]| <= max(inv[i, i], inv[j, j])``.
+
+    ``tests/test_health.py`` poisons one off-diagonal element and sees the
+    row caught. NOT exact for an eigendecomposition or for an operand
+    corrupted after it was formed: those keep a read of every element."""
+    return diagonal_finite(inv)
+
+
+def heal_rows(x, bad, fallback, beside=None):
+    """``(x, beside)``, ``x [rows, ...]`` with every row ``r`` whose
+    ``bad[r]`` is set replaced by ``fallback(r, beside)``: a loop over the
+    bad rows alone, each written where it lies. On a healthy step it runs
+    no iteration, so the operand is neither read nor written again and no
+    second copy of it is held (what a ``jnp.where`` over the whole operand
+    costs).
+
+    ``beside``: an array the fallback reads, carried through the loop
+    untouched and handed back: a caller that goes on to write into it uses
+    the one handed back, so that the compiler sees one buffer pass through
+    and copies nothing."""
+    where = jnp.nonzero(bad, size=x.shape[0], fill_value=0)[0]
+
+    def one_row(i, carry):
+        out, side = carry
+        r = where[i]
+        return lax.dynamic_update_index_in_dim(
+            out, fallback(r, side).astype(out.dtype), r, axis=0), side
+
+    return lax.fori_loop(0, jnp.sum(bad.astype(jnp.int32)), one_row,
+                         (x, beside))
+
+
+@functools.partial(jax.jit, static_argnames='guard')
+def settle_inverse_rows(inv, stored, guard, commit=None, first=0):
+    """Fresh inverses ``inv [k, D, D]`` as they may be kept, beside the
+    ``stored [rows, D, D]`` ones, of which they replace rows ``first`` to
+    ``first + k``; returns ``(settled, stored)``. With ``guard`` a row that
+    is not finite (:func:`inverse_rows_finite`) falls back to its stored
+    inverse, or to the identity where none is stored yet (the cold state is
+    all zeros and a stored SPD inverse has a positive diagonal, so the
+    diagonal tells them apart). ``commit`` (a traced bool): where False
+    every row keeps its stored value, cold or not. Only the rows at fault
+    are touched (:func:`heal_rows`)."""
+    if not guard and commit is None:
+        return inv, stored
+    rows, d = inv.shape[0], inv.shape[-1]
+    bad = (jnp.logical_not(inverse_rows_finite(inv)) if guard
+           else jnp.zeros((rows,), bool))
+    if commit is not None:
+        bad = jnp.logical_or(bad, jnp.logical_not(commit))
+
+    def last_good(r, kept):
+        old = lax.dynamic_index_in_dim(kept, first + r, 0, keepdims=False)
+        cold = jnp.all(tile_diagonal(old) == 0)
+        if commit is not None:
+            cold = jnp.logical_and(cold, commit)
+        return jnp.where(cold, jnp.eye(d, dtype=inv.dtype), old)
+
+    return heal_rows(inv, bad, last_good, beside=stored)
+
+
 def damped_psd_inverse(x, damp, prev=None, guard=False, commit=None):
     """``(x + damp I)^-1`` of a bucket ``x [rows, D, D]``, ``damp [rows]``:
     whole, or tile by tile where :func:`inverse_tiling` says so.
@@ -101,11 +218,9 @@ def damped_psd_inverse(x, damp, prev=None, guard=False, commit=None):
     ``prev`` (tiled buckets only; the whole path takes no notice): the
     bucket's stored inverses. The groups are written over them, so that a
     donated state's buffer is the result's and no second bucket is held;
-    with ``guard`` a row whose new inverse is not finite keeps its stored
-    one, or the identity where none is stored yet (all zeros): what
-    ``engine.guard_decomposition`` does to a whole bucket, group by
-    group. ``commit`` (a traced bool; needs ``prev``): where False every
-    row keeps its stored inverse, as if the bucket had not been touched."""
+    with ``guard`` and ``commit`` each group is settled against the rows it
+    is about to replace (:func:`settle_inverse_rows`), as
+    ``engine.guard_decomposition`` settles a whole bucket."""
     rows, d = x.shape[0], x.shape[-1]
     size, width = inverse_tiling(rows, d, x.dtype.itemsize)
     if (size, width) == (rows, d):
@@ -118,15 +233,7 @@ def damped_psd_inverse(x, damp, prev=None, guard=False, commit=None):
             lax.dynamic_slice_in_dim(damp, start, size, axis=0))
         inv = (psd_inverse(xs) if width == d
                else _psd_inverse_panels(xs, width))
-        old = lax.dynamic_slice_in_dim(out, start, size, axis=0)
-        if guard:
-            good = jnp.all(jnp.isfinite(inv), axis=(-2, -1), keepdims=True)
-            cold = jnp.logical_not(jnp.any(old != 0, axis=(-2, -1),
-                                           keepdims=True))
-            inv = jnp.where(good, inv, jnp.where(
-                cold, jnp.eye(d, dtype=x.dtype), old))
-        if commit is not None:
-            inv = jnp.where(commit, inv, old)
+        inv, out = settle_inverse_rows(inv, out, guard, commit, first=start)
         return lax.dynamic_update_slice_in_dim(out, inv, start, axis=0)
 
     return lax.fori_loop(0, -(-rows // size), one_group,
@@ -524,7 +631,10 @@ def masked_trace(x, true_dim):
     ``[L]`` for stacked inputs.
     """
     d = x.shape[-1]
-    diag = jnp.diagonal(x, axis1=-2, axis2=-1)
+    # the same entries as jnp.diagonal, read without the gather (whose
+    # operand the TPU's compiler first copies into another layout: every
+    # factor bucket, once an update)
+    diag = tile_diagonal(x)
     idx = jnp.arange(d)
     true_dim = jnp.asarray(true_dim)
     mask = (idx < true_dim[..., None]) if true_dim.ndim > 0 else (idx < true_dim)

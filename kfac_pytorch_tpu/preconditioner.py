@@ -581,6 +581,10 @@ class KFAC:
         # the layout as the apply consumes it: one record, in the
         # recorder's trace and in the run's log
         record = pred_layout_record(self.plan)
+        passes = self.guard_passes()
+        record['guard_passes'] = sum(passes.values())
+        if passes:
+            record['guard_passes_over'] = passes
         obs_trace.instant('kfac.precond.setup', cat='kfac.step',
                           buckets=len(self.plan.bucket_dims), **record)
         logging.getLogger(__name__).info(
@@ -967,6 +971,61 @@ class KFAC:
         return new_state
 
     @property
+    def _fuses_capture(self):
+        """Whether the factor update is the fused Pallas capture (one
+        kernel a factor row, statistics never materialised):
+        ``capture_impl`` resolved to 'pallas', local statistics, one
+        device."""
+        reduce = ('local' if self.exclude_communicate_factor
+                  else self.stats_reduce)
+        return (self.resolved_capture_impl == 'pallas' and reduce == 'local'
+                and self.plan is not None and self.plan.num_devices == 1)
+
+    def layer_stats(self, acts, gs):
+        """This batch's per-layer factor statistics with their ``isfinite``
+        flags (``engine.LayerStats``): a pure function of the captured
+        tensors that writes no state, for :meth:`step`'s ``stats``. None
+        where the step makes no such statistics (the fused capture, the
+        ComputeFactor ablation): the caller then screens the captured
+        tensors themselves."""
+        if self.exclude_compute_factor or self._fuses_capture:
+            return None
+        with jax.named_scope('kfac.ComputeFactor'):
+            return engine.layer_stats(
+                self.plan, acts, gs, self.batch_averaged,
+                capture_impl=self.resolved_capture_impl)
+
+    def guard_passes(self):
+        """``{operand: count}``: the passes a healthy factor+decomposition
+        step of this plan still makes over a whole operand for the health
+        guard alone (an ``isfinite`` or ``!= 0`` reduction that no writer
+        of the operand carries, a ``jnp.where`` over it). Empty where every
+        flag comes from what the step makes anyway: ``inverse_dp`` /
+        ``inverse`` with local statistics on the reference capture path."""
+        if self.health is None or self.plan is None:
+            return {}
+        n = len(self.plan.bucket_dims)
+        out = {}
+        if self._fuses_capture:
+            # the kernels emit no flag: the batch screen reads every
+            # captured tensor, the factor guard new and stored rows
+            out['captured'] = 2 * len(self.plan.metas)
+            out['factors'] = 2 * n
+        elif (self.stats_reduce == 'pmean'
+                and not self.exclude_communicate_factor):
+            out['reduced_stats'] = n    # rows off the wire: read once
+        if self.method == 'eigh':
+            # fresh and stored eigenvectors: no witness smaller than all
+            out['eigenvectors'] = 2 * n
+        elif self.stagger:
+            out['cohort_inverses'] = n  # the merge's per-row screen
+        if self.ekfac:
+            out['ekfac_scales'] = len(self.plan.pred_groups)
+        if self._tracks_comm_err:
+            out['comm_err'] = n
+        return out
+
+    @property
     def hoists_update(self):
         """Whether the trainer runs this plan's factor and inverse updates
         outside the health guard's ``cond`` (``step(update_only=True,
@@ -1138,7 +1197,7 @@ class KFAC:
              update_basis: bool = True, warm_basis: bool = False,
              factors_only: bool = False, stagger_update: bool = False,
              prefetch: bool = False, axis_name: str = '__default__',
-             update_only: bool = False, commit=None):
+             update_only: bool = False, commit=None, stats=None):
         """One K-FAC step: (state, grads, captured stats) ->
         (preconditioned grads, new state).
 
@@ -1174,6 +1233,10 @@ class KFAC:
         outside the health guard's ``cond`` and preconditions inside with a
         plain ``step(update_factors=False, update_inverse=False)``.
 
+        ``stats``: this batch's :meth:`layer_stats`, where the caller has
+        made them already (the trainer does, before the health guard's
+        ``cond``: its batch screen reads their flags); made here otherwise.
+
         Parity with step() (kfac_preconditioner_base.py:185-230): factor
         stats + running-avg update (+ pmean for MPD), decomposition on the
         local shard, gather/owner-pred per comm mode, KL-clipped write-back.
@@ -1197,9 +1260,8 @@ class KFAC:
             if self.exclude_communicate_factor:
                 reduce = 'local'
             cap_impl = self.resolved_capture_impl
-            rowwise = ()
-            if (cap_impl == 'pallas' and reduce == 'local'
-                    and plan.num_devices == 1):
+            guard = self.health is not None
+            if self._fuses_capture:
                 # single-device local stats: the whole capture chain
                 # (patch-extract -> factor GEMM -> EMA) collapses into
                 # one fused kernel per factor — the UpdateFactors pass
@@ -1208,30 +1270,54 @@ class KFAC:
                     factors = engine.update_factors_fused(
                         plan, factors, acts, gs, self.batch_averaged,
                         self.factor_decay)
+                if guard:
+                    # the kernels emit no flag: non-finite EMA rows keep
+                    # the last good factor, a row whose STORED value is
+                    # corrupt too re-initializes to the identity, by a
+                    # read of both (guard_passes counts it)
+                    with jax.named_scope('kfac.HealthGuard.factors'):
+                        factors = engine.where_finite_rows(
+                            factors, state.factors, reinit_identity=True)
+                if commit is not None:
+                    factors = {k: jnp.where(commit, v, state.factors[k])
+                               for k, v in factors.items()}
             else:
                 # named scopes mirror the reference's phase taxonomy
                 # (exclude_parts names) so xprof traces attribute time
                 # the same way scripts/time_breakdown.py does
+                if stats is None:
+                    stats = self.layer_stats(acts, gs)
                 rowwise = engine.rowwise_buckets(plan, reduce)
-                stacks = {}
                 with jax.named_scope('kfac.ComputeFactor'):
-                    a_list, g_list = engine.compute_layer_stats(
-                        plan, acts, gs, self.batch_averaged,
-                        capture_impl=cap_impl, stacks=stacks)
-                    stats = engine.stack_stats(plan, a_list, g_list,
-                                               skip=rowwise)
+                    stacked = engine.stack_stats(
+                        plan, stats.a_list, stats.g_list, skip=rowwise)
+                # a row's flags are settled in the pass that writes it:
+                # one whose statistic is not finite keeps its average, one
+                # whose STORED value is corrupt (silent data corruption)
+                # re-initializes to the identity and re-accumulates
+                stat_ok = engine.rows_ok(plan, stats) if guard else None
                 with jax.named_scope('kfac.UpdateFactors'):
                     # the pmean inside carries its own CommunicateFactor
                     # scope
                     extra = (self._mesh_plan.extra_reduce()
                              if self._mesh_plan is not None else ())
                     factors, comm_err = engine.update_factors(
-                        plan, factors, stats, self.factor_decay, reduce,
+                        plan, factors, stacked, self.factor_decay, reduce,
                         axis_name, comm_precision=self.comm_precision,
                         comm_err=comm_err, capture_impl=cap_impl,
                         extra_reduce=extra,
-                        seen=engine.rows_seen(plan, acts))
-            if self.health is not None and comm_err is not None:
+                        seen=engine.rows_seen(plan, acts), guard=guard,
+                        stat_ok=stat_ok, commit=commit)
+                    # the largest buckets: a run of rows at a time over
+                    # the stored rows
+                    for key in rowwise:
+                        factors[key] = engine.update_factor_rows(
+                            plan, int(key), state.factors[key],
+                            stats.a_list, stats.g_list, stats.stacks,
+                            self.factor_decay, guard=guard, commit=commit,
+                            stat_ok=None if stat_ok is None
+                            else stat_ok[key])
+            if guard and comm_err is not None:
                 # a non-finite residual row resets to zero (the always-
                 # safe EF state: feedback is a correction, never load-
                 # bearing) instead of re-injecting NaN into every later
@@ -1240,31 +1326,6 @@ class KFAC:
                     comm_err = engine.where_finite_rows(
                         comm_err,
                         {k: jnp.zeros_like(v) for k, v in comm_err.items()})
-            if self.health is not None:
-                # non-finite EMA rows keep the last good factor; a row
-                # whose STORED value is already corrupt (silent data
-                # corruption) re-initializes to the identity and
-                # re-accumulates — pass-through when everything is finite
-                with jax.named_scope('kfac.HealthGuard.factors'):
-                    factors = {**factors, **engine.where_finite_rows(
-                        {k: v for k, v in factors.items()
-                         if k not in rowwise},
-                        state.factors, reinit_identity=True)}
-            if commit is not None:
-                factors = {k: v if k in rowwise
-                           else jnp.where(commit, v, state.factors[k])
-                           for k, v in factors.items()}
-            if rowwise:
-                # the largest buckets: a run of rows at a time over the
-                # stored rows, screened as they are written
-                factors = dict(factors)
-                with jax.named_scope('kfac.UpdateFactors'):
-                    for key in rowwise:
-                        factors[key] = engine.update_factor_rows(
-                            plan, int(key), state.factors[key], a_list,
-                            g_list,
-                            stacks, self.factor_decay,
-                            guard=self.health is not None, commit=commit)
             # SDC drill: corrupt a stored factor block AFTER the guard,
             # so the corruption lands in the state exactly as a flipped
             # bit would (tests/test_faults.py heal drill)
@@ -1346,10 +1407,11 @@ class KFAC:
                 # BEFORE the guard so the guard is what survives it
                 decomp_local = faults.corrupt_decomposition(
                     self._faults, state.step, decomp_local)
-                if self.health is not None:
+                if self.health is not None or commit is not None:
                     # a non-finite decomposition row falls back to the
                     # last good one (identity when cold) instead of
-                    # poisoning every later preconditioned gradient;
+                    # poisoning every later preconditioned gradient, and
+                    # a batch not committed keeps every stored row;
                     # guarding PRE-gather/rotation keeps the E-KFAC
                     # moment transport on a finite basis too
                     with jax.named_scope('kfac.HealthGuard.decomp'):
@@ -1359,15 +1421,8 @@ class KFAC:
                             engine.local_decomposition(
                                 plan, decomp, axis_name, self.comm_mode,
                                 self.method),
-                            self.method, done=tiled)
-                if commit is not None:
-                    held_back = engine.local_decomposition(
-                        plan, decomp, axis_name, self.comm_mode,
-                        self.method)['invs']
-                    decomp_local = {'invs': {
-                        k: v if k in tiled
-                        else jnp.where(commit, v, held_back[k])
-                        for k, v in decomp_local['invs'].items()}}
+                            self.method, done=tiled,
+                            guard=self.health is not None, commit=commit)
                 if self.comm_mode == 'inverse':
                     with jax.named_scope('kfac.CommunicateInverse'):
                         new_decomp = engine.gather_decomposition(

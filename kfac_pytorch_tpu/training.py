@@ -397,6 +397,13 @@ def build_train_step(model, tx, precond, loss_fn, axis_name=None, mesh=None,
             grads = coll.average_grads(grads, axis_name)
             loss = coll.pmean(loss, axis_name)
 
+        # the batch's factor statistics are made here, before the guard's
+        # cond: the screen reads their flags (the statistics are Gram
+        # products, engine.stats_finite) in place of every captured tensor
+        stats = None
+        if use_capture and health_cfg is not None:
+            stats = precond.layer_stats(acts, gs)
+
         # a plan with buckets too large for the cond (KFAC.hoists_update)
         # has its factor and inverse updates run before it, committed by
         # the batch screen's own flag; the branches then start from that
@@ -424,7 +431,7 @@ def build_train_step(model, tx, precond, loss_fn, axis_name=None, mesh=None,
                     update_basis=update_basis,
                     warm_basis=warm_basis, factors_only=factors_only,
                     stagger_update=stagger_update, prefetch=prefetch,
-                    axis_name=axis_name)
+                    axis_name=axis_name, stats=stats)
                 if health_cfg is None:
                     new_grads = pgrads
                 else:
@@ -485,7 +492,14 @@ def build_train_step(model, tx, precond, loss_fn, axis_name=None, mesh=None,
         # one replicated scalar decides the branch — no host sync, and
         # every device agrees (batch_ok psums the per-shard bad flags)
         with jax.named_scope('train.health_screen'):
-            ok = health_lib.batch_ok(axis_name, grads, loss_local, acts, gs)
+            if stats is not None:
+                ok = health_lib.batch_ok(axis_name, grads, loss_local,
+                                         flags=(stats.ok_a, stats.ok_g))
+            else:
+                # no statistics to read (no capture this step, or a
+                # capture path that never materialises them)
+                ok = health_lib.batch_ok(axis_name, grads, loss_local,
+                                         acts, gs)
         if (precond is not None and precond.hoists_update
                 and (update_factors or update_inverse)
                 and not (factors_only or stagger_update or prefetch)):
@@ -496,7 +510,7 @@ def build_train_step(model, tx, precond, loss_fn, axis_name=None, mesh=None,
                 update_factors=update_factors,
                 update_inverse=update_inverse, update_basis=update_basis,
                 warm_basis=warm_basis, axis_name=axis_name,
-                update_only=True, commit=ok)
+                update_only=True, commit=ok, stats=stats)
         new_state = jax.lax.cond(ok, apply_update, skip_update,
                                  state.health)
         # what the model counts (capture.COUNTERS), as the new state has

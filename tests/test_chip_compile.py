@@ -210,6 +210,53 @@ def test_apply_reads_bert_base_inverses_in_place_on_v5e(one_chip):
         plan.pred_groups)
 
 
+def test_guard_flags_ride_the_writers_on_v5e(one_chip):
+    """The health guard on a healthy update, at BERT-base's largest
+    bucket (12 x 3,200^2): (1) the running average and its rows'
+    ``isfinite`` flags are ONE fusion (it writes the rows and reduces over
+    them; no pass reads the bucket for the flag alone), and the repair
+    loop behind it holds no second bucket; (2) a fresh inverse is settled
+    from its diagonal tiles (under a twentieth of its bytes), the stored
+    bucket is read only inside the loop over rows at fault, and nothing
+    the size of the bucket is selected or copied. A compile, not a
+    timing."""
+    import re
+
+    from kfac_pytorch_tpu import engine
+    rows, d = 12, 3200
+    bucket = ((rows, d, d), F32)
+
+    def update(stat, old, ok):
+        return engine.settle_factor_rows(
+            old * 0.05 + stat * 0.95, old, [ok], guard=True)
+    args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip)
+            for s, t in (bucket, bucket, ((rows,), jnp.bool_))]
+    compiled = jax.jit(update, donate_argnums=(1,)).lower(*args).compile()
+    text = compiled.as_text()
+    entry = text[text.index('ENTRY'):]
+    whole = re.findall(
+        r'= (\(?[^=]*?\)?) (fusion|copy|select)\(', entry)
+    whole = [(shape, op) for shape, op in whole
+             if f'f32[{rows},{d},{d}]' in shape]
+    # one operation writes the bucket: the fusion that also gives the flags
+    assert len(whole) == 1 and whole[0][1] == 'fusion', whole
+    assert f'pred[{rows}]' in whole[0][0], whole
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+    from kfac_pytorch_tpu import ops
+
+    def settle(inv, stored):
+        return ops.settle_inverse_rows(inv, stored, guard=True)[0]
+    args = [jax.ShapeDtypeStruct(*bucket, sharding=one_chip)] * 2
+    compiled = jax.jit(settle, donate_argnums=(0,)).lower(*args).compile()
+    text = compiled.as_text()
+    entry = text[text.index('ENTRY'):]
+    assert not re.search(
+        rf'= f32\[{rows},{d},{d}\]\S* (fusion|select|copy)\(', entry), entry
+    # the flags' fusions read the diagonal's tiles, not the bucket
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
 @pytest.mark.parametrize('length', [2048, 32768])
 def test_flash_block_attn_fwd_bwd_compiles_for_v5e(one_chip, length,
                                                    monkeypatch):

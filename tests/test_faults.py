@@ -18,7 +18,7 @@ import optax
 import pytest
 
 import kfac_pytorch_tpu as kfac
-from kfac_pytorch_tpu import faults, training
+from kfac_pytorch_tpu import engine, faults, ops, training
 from kfac_pytorch_tpu.utils import checkpoint
 
 from tests.helpers import TinyCNN
@@ -192,6 +192,88 @@ def test_factor_corruption_heals_by_identity_reinit(monkeypatch):
     assert _all_finite(state.kfac_state.factors)
     state, m3 = step(state, batches[3], lr=0.05, damping=0.003)
     assert _all_finite(state.params) and np.isfinite(float(m3['loss']))
+
+
+def _spd(key, rows, dim):
+    m = jax.random.normal(key, (rows, dim, 2 * dim))
+    return jnp.einsum('rij,rkj->rik', m, m) / (2 * dim)
+
+
+@pytest.mark.parametrize('value', [np.nan, np.inf, -np.inf])
+def test_one_poisoned_stored_factor_element_heals_by_identity(value):
+    """One OFF-diagonal element of one stored factor row corrupt (a
+    flipped bit): the row's flag comes out of the pass that writes its new
+    average, the row starts again from the identity and every other row is
+    the unguarded average: what the whole-bucket
+    ``where_finite_rows(..., reinit_identity=True)`` gave."""
+    pre = kfac.KFAC(variant='inverse_dp', num_devices=1, axis_name=None)
+    plan = pre.setup(_metas())
+    stored = {k: _spd(jax.random.PRNGKey(i), *v.shape[:2])
+              for i, (k, v) in enumerate(pre.init().factors.items())}
+    stats = {k: _spd(jax.random.PRNGKey(10 + i), *v.shape[:2])
+             for i, (k, v) in enumerate(stored.items())}
+    key = max(stored, key=lambda k: stored[k].shape[0])
+    d = int(key)
+    assert stored[key].shape[0] > 1
+    stored[key] = stored[key].at[1, 2, d - 3].set(value)
+
+    def update(**guard):
+        return jax.jit(lambda f, s: engine.update_factors(
+            plan, f, s, 0.95, 'local', None, **guard)[0])(stored, stats)
+    want = engine.where_finite_rows(update(), stored, reinit_identity=True)
+    got = update(guard=True)
+    for k in want:
+        # (two programs: the CPU's compiler contracts the average's
+        # multiply-add differently beside the flag's reduction)
+        np.testing.assert_allclose(got[k], want[k], rtol=1e-6, atol=1e-7)
+    np.testing.assert_array_equal(got[key][1], np.eye(d))
+    # a batch that is not committed heals nothing and writes nothing
+    kept = update(guard=True, commit=jnp.zeros((), bool))
+    for k in want:
+        np.testing.assert_array_equal(kept[k], stored[k])
+
+
+def _metas():
+    from kfac_pytorch_tpu import capture
+    model, x = TinyCNN(), _batches(1)[0]['input']
+    params = capture.init(model, jax.random.PRNGKey(0), x)['params']
+    return capture.collect_layer_meta(model, {'params': params}, x)
+
+
+@pytest.mark.parametrize('value,stored_yet', [
+    (np.nan, True), (np.inf, True), (-np.inf, True), (np.nan, False)])
+def test_one_poisoned_factor_element_keeps_the_last_good_inverse(
+        value, stored_yet):
+    """One OFF-diagonal element of one damped factor not finite: its
+    Cholesky inverse is not finite, the guard reads that from the
+    inverse's DIAGONAL alone (``ops.inverse_rows_finite``), and the row
+    falls back to the stored inverse (the identity where none is stored
+    yet) while every other row is the fresh inverse to the bit: what the
+    whole-bucket guard gave with a read of every element of both."""
+    rows, d = 5, 160            # two diagonal tiles, the second partial
+    x = ops.add_scaled_identity(_spd(jax.random.PRNGKey(0), rows, d), 0.05)
+    stored = (ops.psd_inverse(x * 1.5) if stored_yet
+              else jnp.zeros_like(x))
+    fresh = jax.jit(ops.psd_inverse)(x.at[2, 7, 140].set(value))
+    assert not np.isfinite(np.asarray(fresh[2])).all()
+    got = jax.jit(lambda n, p: engine.guard_decomposition(
+        {'invs': {'160': n}}, {'invs': {'160': p}}, 'cholesky'))(
+        fresh, stored)['invs']['160']
+    # the whole-bucket guard this replaces, spelt out
+    good = np.isfinite(np.asarray(fresh)).all(axis=(1, 2))
+    cold = ~(np.asarray(stored) != 0).any(axis=(1, 2))
+    want = np.where(good[:, None, None], fresh, np.where(
+        cold[:, None, None], np.eye(d, dtype=np.float32), stored))
+    assert list(good) == [True, True, False, True, True]
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(
+        got[2], stored[2] if stored_yet else np.eye(d))
+    # not committed (a refused batch, hoisted update): stored rows stay,
+    # cold ones too
+    kept = jax.jit(lambda n, p: engine.guard_decomposition(
+        {'invs': {'160': n}}, {'invs': {'160': p}}, 'cholesky',
+        commit=jnp.zeros((), bool)))(fresh, stored)['invs']['160']
+    np.testing.assert_array_equal(kept, stored)
 
 
 def test_sigterm_fault_trips_preemption_guard(monkeypatch):

@@ -15,7 +15,7 @@ import optax
 import pytest
 
 import kfac_pytorch_tpu as kfac
-from kfac_pytorch_tpu import faults, training
+from kfac_pytorch_tpu import engine, faults, ops, training
 from kfac_pytorch_tpu import health as health_lib
 from kfac_pytorch_tpu.utils.metrics import HealthMonitor
 from kfac_pytorch_tpu.utils.runlog import health_suffix
@@ -35,11 +35,11 @@ def _ce(outputs, batch):
         outputs, batch['label']).mean()
 
 
-def _run(batches, health=True):
+def _run(batches, health=True, variant='eigen_dp'):
     """Fresh model/precond/state, one step per batch; returns the final
     state, the per-step metrics and the step_fn (variant introspection)."""
     model = TinyCNN()
-    precond = kfac.KFAC(variant='eigen_dp', lr=0.05, damping=0.003,
+    precond = kfac.KFAC(variant=variant, lr=0.05, damping=0.003,
                         fac_update_freq=1, kfac_update_freq=1,
                         num_devices=1, axis_name=None, health=health)
     tx = training.sgd(0.05, momentum=0.9)
@@ -201,3 +201,136 @@ def test_resolve():
     assert health_lib.resolve(cfg) is cfg
     with pytest.raises(TypeError):
         health_lib.resolve('yes')
+
+
+# -- the guard's flags come from what the update step makes anyway ---------
+# (inverse_dp, the variant the benchmark's cells run: PERF.md, PR 42)
+
+def _one_element(tree, leaf, hit):
+    """``tree`` with ONE element of its ``leaf``-th inexact leaf NaN where
+    ``hit``: an element in the middle of the tensor, which a statistic
+    reads into a whole row and column of off-diagonal entries."""
+    leaves, treedef = jax.tree.flatten(tree)
+    floats = [i for i, x in enumerate(leaves)
+              if jnp.issubdtype(x.dtype, jnp.inexact) and x.ndim > 1]
+    x = leaves[floats[leaf]]
+    where = tuple(n // 2 for n in x.shape)
+    leaves[floats[leaf]] = x.at[where].set(
+        jnp.where(hit, jnp.nan, x[where]))
+    return jax.tree.unflatten(treedef, leaves)
+
+
+@pytest.fixture(scope='module')
+def healthy_inverse_dp():
+    """Four batches through ``inverse_dp`` with the guard on: the control
+    of the cases below (one run for all of them)."""
+    batches = _batches(4, seed=9)
+    return batches, _run(batches, variant='inverse_dp')
+
+
+@pytest.mark.parametrize('operand', ['activation', 'output_gradient'])
+def test_one_poisoned_captured_element_refuses_the_batch(
+        monkeypatch, healthy_inverse_dp, operand):
+    """One NaN in one captured tensor, gradients finite: the screen reads
+    no captured tensor any more, and the statistic that read the element
+    (``engine.stats_finite``) refuses the batch all the same. Params,
+    optimizer state, factors and decomposition end bit-identical to a run
+    that never saw the batch."""
+    batches, (control, _, _) = healthy_inverse_dp
+
+    def poison(cfg, step, acts, gs):
+        if acts is None:
+            return acts, gs
+        if operand == 'activation':
+            return _one_element(acts, 1, step == 1), gs
+        return acts, _one_element(gs, 1, step == 1)
+
+    monkeypatch.setattr(faults, 'corrupt_captured', poison)
+    faulted, mets, _ = _run(batches[:1] + batches[:1] + batches[1:],
+                            variant='inverse_dp')
+    assert [m['health/ok'] for m in mets] == [1, 0, 1, 1, 1]
+    assert mets[-1]['health/skipped'] == 1
+    _assert_trees_equal(faulted.params, control.params)
+    _assert_trees_equal(faulted.opt_state, control.opt_state)
+    _assert_trees_equal(faulted.kfac_state.factors,
+                        control.kfac_state.factors)
+    _assert_trees_equal(faulted.kfac_state.decomp, control.kfac_state.decomp)
+
+
+def test_guard_on_is_bit_identical_to_guard_off(healthy_inverse_dp):
+    """A healthy factor+decomposition step: the guard's flags and its
+    (idle) repair loops leave params, factors and decomposition as
+    ``health=False`` computes them, to the bit. One step: from the second
+    on the two programs' optimizer updates round differently in the last
+    bit on the CPU (they did before the guard read its flags this way,
+    too), which says nothing of the guard."""
+    batches, _ = healthy_inverse_dp
+    on, mets, _ = _run(batches[:1], variant='inverse_dp')
+    off, _, _ = _run(batches[:1], health=False, variant='inverse_dp')
+    assert mets[0]['health/ok'] == 1
+    _assert_trees_equal(on.params, off.params)
+    _assert_trees_equal(on.kfac_state.factors, off.kfac_state.factors)
+    _assert_trees_equal(on.kfac_state.decomp, off.kfac_state.decomp)
+
+
+def test_healthy_update_step_reads_no_operand_for_the_guard_alone(
+        healthy_inverse_dp):
+    """The lowered factor+decomposition step of ``inverse_dp`` on one
+    device: no ``is_finite`` over a captured tensor, and over a
+    ``[rows, D, D]`` bucket one a bucket, the running average's own in the
+    pass that writes it (one fusion on the chip:
+    tests/test_chip_compile.py) — none over a stored factor or inverse
+    bucket, none over a fresh inverse. Every other flag is read from at
+    most ``[rows, D]`` values."""
+    import re
+    from kfac_pytorch_tpu import capture
+    batches, (state, _, step) = healthy_inverse_dp
+    update = step.variants[(True, True, True, False, False)]
+    hyper = kfac.KFACHyperParams(lr=jnp.float32(0.05),
+                                 damping=jnp.float32(0.003))
+    text = update.lower(state, batches[0], hyper).as_text()
+    screened = re.findall(
+        r'stablehlo\.is_finite %\S+ : \(?tensor<([0-9x]+)xf32>', text)
+    assert screened
+    model = TinyCNN()
+    _, _, _, acts, gs, _ = jax.eval_shape(
+        lambda v, x: capture.value_and_grad_with_capture(
+            model, lambda o: _ce(o, batches[0]), v, x),
+        {'params': state.params}, batches[0]['input'])
+    captured = {'x'.join(map(str, x.shape))
+                for x in jax.tree.leaves((acts, gs)) if x.ndim > 1}
+    assert captured and not captured & set(screened), screened
+    buckets = ['x'.join(map(str, v.shape))
+               for v in state.kfac_state.factors.values()]
+    assert [screened.count(b) for b in buckets] == [1] * len(buckets)
+    # nothing the size of a bucket, or of a captured tensor, is selected
+    # row against row either
+    assert not re.search(r'kfac\.HealthGuard\.factors', text)
+
+
+def test_guard_passes_in_the_setup_record(caplog):
+    """``kfac.precond.setup`` counts the whole-operand passes the guard
+    still makes: none for the Cholesky variants on the reference capture
+    path, the eigenvectors' for ``eigen_dp``, none with the guard off."""
+    import logging
+
+    from kfac_pytorch_tpu import capture
+    model = TinyCNN()
+    x = _batches(1)[0]['input']
+    params = capture.init(model, jax.random.PRNGKey(0), x)['params']
+    metas = capture.collect_layer_meta(model, {'params': params}, x)
+    seen = []
+    with caplog.at_level(logging.INFO, logger='kfac_pytorch_tpu'):
+        for variant, health in (('inverse_dp', True), ('eigen_dp', True),
+                                ('eigen_dp', False)):
+            pre = kfac.KFAC(variant=variant, num_devices=1, axis_name=None,
+                            health=health)
+            pre.setup(metas)
+            seen.append(pre.guard_passes())
+    n = len(pre.plan.bucket_dims)
+    assert seen == [{}, {'eigenvectors': 2 * n}, {}]
+    said = [r.getMessage() for r in caplog.records
+            if 'precond.setup' in r.getMessage()]
+    assert 'guard_passes 0' in said[0] and 'guard_passes 0' in said[2]
+    assert (f'guard_passes {2 * n}' in said[1]
+            and "guard_passes_over {'eigenvectors'" in said[1])
