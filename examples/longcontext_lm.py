@@ -32,6 +32,17 @@ counters (``moe/dropped`` must stay 0):
   python examples/longcontext_lm.py --model sparse-decoder --kfac-name \
       inverse_dp --seq-len 4096 --batch-size 1 --n-layer 5 --n-head 4 \
       --d-model 2048 --experts-held 8 --synthetic-vocab 16032 --epochs 1
+
+``--model mixed-decoder`` trains ``models.mixed_decoder_lm``: one chip's
+share of a decoder whose layers differ in kind (window and full attention
+mixed, grouped-query, gated, QK-normed; four norms a block; sigmoid-routed
+top-8 experts with one shared: Trinity-Mini's published widths). ``--n-layer``
+layers held (one leading dense layer, then whole periods of three window
+layers and one full), ``--n-head`` query heads held with the key/value
+heads they read (8 query heads a key/value head), the rest as above:
+  python examples/longcontext_lm.py --model mixed-decoder --kfac-name \
+      inverse_dp --seq-len 4096 --batch-size 1 --n-layer 5 --n-head 8 \
+      --d-model 2048 --experts-held 8 --synthetic-vocab 25024 --epochs 1
 """
 
 import argparse
@@ -62,14 +73,22 @@ def parse_args():
                    help='global batch (sequences per step)')
     p.add_argument('--epochs', type=int, default=3)
     p.add_argument('--steps-per-epoch', type=int, default=100)
-    p.add_argument('--model', choices=['transformer', 'sparse-decoder'],
-                   default='transformer')
+    p.add_argument('--model', choices=['transformer', 'sparse-decoder',
+                                       'mixed-decoder'],
+                   default='transformer',
+                   help='transformer: models.transformer_lm; '
+                        'sparse-decoder: models.sparse_decoder_lm (latent '
+                        'attention, routed experts); mixed-decoder: '
+                        'models.mixed_decoder_lm (window and full '
+                        'attention mixed, grouped-query, gated; routed '
+                        'experts)')
     p.add_argument('--experts-held', type=int, default=8,
-                   help='sparse-decoder: routed experts this chip holds '
-                        '(ids 0..n-1 of the published 128)')
+                   help='sparse-decoder, mixed-decoder: routed experts '
+                        'this chip holds (ids 0..n-1 of the published 128)')
     p.add_argument('--expert-capacity', type=int, default=None,
-                   help='sparse-decoder: rows of a held expert\'s buffer '
-                        '(default: four times the expected load)')
+                   help='sparse-decoder, mixed-decoder: rows of a held '
+                        'expert\'s buffer (default: four times the '
+                        'expected load)')
     p.add_argument('--n-layer', type=int, default=4)
     p.add_argument('--n-head', type=int, default=8)
     p.add_argument('--d-model', type=int, default=256)
@@ -297,6 +316,19 @@ def main():
             expert_ids=tuple(range(args.experts_held)),
             expert_capacity=capacity, dtype=jnp.bfloat16)
         # the model's counters ride in the state and in the step's metrics
+        step_kw = dict(extra_mutable=(capture.COUNTERS,))
+    elif args.model == 'mixed-decoder':
+        assert ndev == 1, 'the mixed decoder trains on one device here'
+        tokens = args.batch_size * args.seq_len
+        capacity = args.expert_capacity or -(-4 * tokens * 8 // 128)
+        # a key/value head serves 8 query heads (32 / 4 as published)
+        model = twin = models.mixed_decoder_lm(
+            vocab_size=vocab, hidden_size=args.d_model,
+            layer_types=models.held_layer_types(args.n_layer),
+            first_k_dense=1, q_head_ids=tuple(range(args.n_head)),
+            kv_head_ids=tuple(range(-(-args.n_head // 8))),
+            expert_ids=tuple(range(args.experts_held)),
+            expert_capacity=capacity, dtype=jnp.bfloat16)
         step_kw = dict(extra_mutable=(capture.COUNTERS,))
     else:
         model = models.transformer_lm(

@@ -64,6 +64,13 @@ class LayerMeta:
     # [E, d_in, d_out]`` at ``path`` (``nn.StackedDense``), with a factor
     # pair of its own from the rows that came to it
     index: Optional[int] = None
+    # dense kinds only: the name of the first of the layers that were
+    # called on the SAME array as this one (equal ``use_bias``, and for
+    # stacked slices the same index and row weighting), itself included;
+    # None for a layer that reads its input alone. Found during the
+    # recorded trace (``collect_layer_meta``), never declared: the members
+    # of a group have one ``A`` (``plan.build_plan``)
+    input_group: Optional[str] = None
 
     @property
     def grad_shape(self):
@@ -82,15 +89,24 @@ def _registry_active() -> bool:
     return getattr(_REGISTRY, 'active', False)
 
 
-def report_layer(meta: LayerMeta) -> None:
-    """Called by kfac_pytorch_tpu.nn layers during a recorded trace."""
+def report_layer(meta: LayerMeta, reads=()) -> None:
+    """Called by kfac_pytorch_tpu.nn layers during a recorded trace.
+
+    ``reads``: what the layer's ``A`` is made of, as the objects the layer
+    was called with (its input array; a stacked layer's row counts and the
+    size of the loss's mean beside it). Two dense layers whose LAST calls
+    read the same objects have the same ``A``: :func:`input_groups`."""
     if _registry_active():
         _REGISTRY.layers[meta.name] = meta
+        # the objects are kept until the trace is over, so that an id is
+        # not handed out twice
+        _REGISTRY.reads[meta.name] = tuple(reads)
 
 
 class _record_layers:
     def __enter__(self):
         _REGISTRY.layers = {}
+        _REGISTRY.reads = {}
         _REGISTRY.active = True
         return _REGISTRY.layers
 
@@ -99,19 +115,45 @@ class _record_layers:
         return False
 
 
+def input_groups(metas, reads):
+    """``metas`` with ``input_group`` set on the dense and stacked layers
+    that share their input: same array (the same object during the trace),
+    same ``use_bias``, same kind and stacked index, same row weighting.
+    A conv layer never shares (its patches depend on kernel and stride)."""
+    members = {}    # what a layer's A is made of -> the layers, in order
+    for name, meta in metas.items():
+        if meta.kind != 'conv' and reads.get(name):
+            # a Python number (the size of the loss's mean) by value, an
+            # array by identity
+            key = (meta.kind, meta.index, meta.use_bias) + tuple(
+                r if isinstance(r, (int, float)) else id(r)
+                for r in reads[name])
+            members.setdefault(key, []).append(name)
+    out = dict(metas)
+    for names in members.values():
+        if len(names) > 1:
+            for name in names:
+                out[name] = dataclasses.replace(metas[name],
+                                                input_group=names[0])
+    return out
+
+
 def collect_layer_meta(model, variables, *args, exclude_vocabulary_size=None,
                        **kwargs):
     """Discover KFAC-supported layers by tracing one apply (zero FLOPs).
 
     Returns ``{name: LayerMeta}`` in call order. ``exclude_vocabulary_size``
     drops dense layers with that output dim — the tied-embedding pre-softmax
-    exclusion (reference: kfac_preconditioner_base.py:139-140).
+    exclusion (reference: kfac_preconditioner_base.py:139-140). Dense layers
+    that were called on one array come back as an input group
+    (:func:`input_groups`).
     """
     with _record_layers() as layers:
         jax.eval_shape(
             lambda v: model.apply(v, *args, mutable=True, **kwargs),
             variables)
-    metas = dict(layers)
+        metas = input_groups(dict(layers), _REGISTRY.reads)
+    _REGISTRY.reads = {}
     if exclude_vocabulary_size is not None:
         metas = filter_vocab_head(metas, exclude_vocabulary_size)
     return metas

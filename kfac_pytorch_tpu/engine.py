@@ -123,6 +123,10 @@ def compute_layer_stats(plan, acts, gs, batch_averaged=True,
     the batched statistics of every stacked leaf and the rows its slices
     got (for :func:`update_factor_rows`).
 
+    A layer that reads another's ``A`` factor (``plan.a_leaders``: the
+    layers of an input group) gets that layer's ``A`` statistic, the same
+    array: it is computed once.
+
     ``capture_impl='pallas'`` computes every statistic with the fused
     Pallas kernels (interpreter mode off-TPU) — numerically pinned to
     the reference by tests/test_pallas_capture.py."""
@@ -130,20 +134,28 @@ def compute_layer_stats(plan, acts, gs, batch_averaged=True,
     a_list, g_list = [], []
     if stacks is None:
         stacks = {}     # a stacked leaf's statistics: one batched product
+    leaders = plan.a_leaders()
     with (back.routing_report() if back is not ops
           else contextlib.nullcontext()):
-        for meta in plan.metas:
+        for i, meta in enumerate(plan.metas):
             a = capture.layer_act(acts, meta)
             g = capture.layer_g(gs, meta)
+            lead = plan.metas[leaders[i]]
             if meta.kind == 'stacked':
                 if meta.path not in stacks:
                     sown = capture.get_path(acts, meta.path)
+                    # a leaf whose slices read another leaf's slots takes
+                    # that leaf's batched ``A`` (its leader is before it)
                     stacks[meta.path] = (_stacked_stats(
-                        a, g, sown['n'], sown['t']), sown['n'])
+                        a, g, sown['n'], sown['t'],
+                        None if lead is meta else stacks[lead.path][0][0]),
+                        sown['n'])
                 a_list.append(stacks[meta.path][0][0][meta.index])
                 g_list.append(stacks[meta.path][0][1][meta.index])
             elif meta.kind == 'dense':
-                a_list.append(back.compute_a_dense(a, meta.use_bias, **kw))
+                a_list.append(
+                    back.compute_a_dense(a, meta.use_bias, **kw)
+                    if lead is meta else a_list[leaders[i]])
                 g_list.append(back.compute_g_dense(g, batch_averaged,
                                                    **kw))
             else:
@@ -154,19 +166,21 @@ def compute_layer_stats(plan, acts, gs, batch_averaged=True,
     return a_list, g_list
 
 
-def _stacked_stats(a, g, n, t):
+def _stacked_stats(a, g, n, t, a_stat=None):
     """(A, G) ``[E, d, d]`` of the ``E`` slices of a stacked leaf
     (``nn.StackedDense``) from its row buffers ``a [E, C, d_in]`` and
     cotangents ``g [E, C, d_out]``, ``n [E]`` rows each (the rest of a
     buffer is zero on both sides) and ``t`` the size of the loss's mean:
     ``A = a'a / max(n, 1)``, ``G = (t g)'(t g) / max(n, 1)``. Not
     ``compute_g_dense``'s scaling by the row count: the cotangents carry
-    ``1 / t``, whatever part of the ``t`` rows came here."""
+    ``1 / t``, whatever part of the ``t`` rows came here. ``a_stat``: the
+    ``A`` of another leaf that read the same buffers, taken as it is."""
     with jax.named_scope('kfac.expert_stats'):
         n = jnp.maximum(n, 1.0)[:, None, None]
         gram = functools.partial(jnp.einsum, 'ecd,ecf->edf',
                                  preferred_element_type=jnp.float32)
-        return gram(a, a) / n, gram(g, g) * (t * t / n)
+        return (gram(a, a) / n if a_stat is None else a_stat,
+                gram(g, g) * (t * t / n))
 
 
 def rows_seen(plan, acts):
@@ -179,7 +193,7 @@ def rows_seen(plan, acts):
     out = {}
     for bdim in plan.bucket_dims:
         flags = []
-        for s in plan.buckets[bdim].slot_of_row:
+        for s in plan.buckets[bdim].factor_slots:
             meta = None if s is None else plan.metas[s.layer_idx]
             if meta is None or meta.kind != 'stacked':
                 flags.append(jnp.ones((), bool))
@@ -211,7 +225,13 @@ def stats_finite(plan, a_list, g_list, stacks):
     # 512-wide tiles: a statistic a layer and side is many small matrices,
     # and at 128 their tiles were a fifth of the update program's kernels
     ok = functools.partial(ops.diagonal_finite, tile=512)
-    leaf_ok = {path: (ok(both[0]), ok(both[1]))
+    seen = {}       # an ``A`` that several layers read is read once
+
+    def ok_once(stat):
+        if id(stat) not in seen:
+            seen[id(stat)] = ok(stat)
+        return seen[id(stat)]
+    leaf_ok = {path: (ok_once(both[0]), ok(both[1]))
                for path, (both, _) in stacks.items()}
     ok_a, ok_g = [], []
     for i, meta in enumerate(plan.metas):
@@ -219,7 +239,7 @@ def stats_finite(plan, a_list, g_list, stacks):
             ok_a.append(leaf_ok[meta.path][0][meta.index])
             ok_g.append(leaf_ok[meta.path][1][meta.index])
         else:
-            ok_a.append(ok(a_list[i]))
+            ok_a.append(ok_once(a_list[i]))
             ok_g.append(ok(g_list[i]))
     return jnp.stack(ok_a), jnp.stack(ok_g)
 
@@ -253,7 +273,7 @@ def rows_ok(plan, stats):
     flat = jnp.concatenate([stats.ok_a, stats.ok_g, jnp.ones((1,), bool)])
     return {_key(bdim): flat[np.asarray(
         [2 * n if s is None else s.layer_idx + (0 if s.side == 'A' else n)
-         for s in plan.buckets[bdim].slot_of_row])]
+         for s in plan.buckets[bdim].factor_slots])]
         for bdim in plan.bucket_dims}
 
 
@@ -312,7 +332,7 @@ def _stat_runs(plan, bdim, a_list, g_list, stacks):
     side, in their own order, are one run read from the leaf's batched
     statistics where they lie (:func:`compute_layer_stats`' ``stacks``);
     every other row is a run of one."""
-    slots = plan.buckets[bdim].slot_of_row
+    slots = plan.buckets[bdim].factor_slots
     runs, r = [], 0
     while r < len(slots):
         slot = slots[r]
@@ -375,7 +395,7 @@ def stack_stats(plan, a_list, g_list, skip=()):
             continue
         b = plan.buckets[bdim]
         out[_key(bdim)] = jnp.stack([
-            _row_stat(plan, bdim, s, a_list, g_list) for s in b.slot_of_row])
+            _row_stat(plan, bdim, s, a_list, g_list) for s in b.factor_slots])
     return out
 
 
@@ -411,7 +431,7 @@ def _update_factors_fused(pc, plan, factors_local, acts, gs, batch_averaged,
         key = _key(bdim)
         b = plan.buckets[bdim]
         rows = []
-        for r, s in enumerate(b.slot_of_row):
+        for r, s in enumerate(b.factor_slots):
             cur = factors_local[key][r]
             if s is None:
                 rows.append(ops.update_running_avg(
@@ -534,19 +554,19 @@ def update_factors(plan, factors_local, stats_stacked, factor_decay,
                 new_err[key] = err
         else:
             idx = coll.axis_index(axis_name)
-            local = lax.dynamic_slice_in_dim(stats, idx * b.per_dev,
-                                             b.per_dev, axis=0)
+            local = lax.dynamic_slice_in_dim(stats, idx * b.factor_per_dev,
+                                             b.factor_per_dev, axis=0)
         def mine(flags):
             return lax.dynamic_slice_in_dim(
-                flags[key], coll.axis_index(axis_name) * b.per_dev,
-                b.per_dev)
+                flags[key], coll.axis_index(axis_name) * b.factor_per_dev,
+                b.factor_per_dev)
         take = [] if seen is None else [mine(seen)]
         if guard:
             take.append(ops.rows_finite(local)
                         if stat_ok is None or stats_reduce == 'pmean'
                         else mine(stat_ok))
         if commit is not None:
-            take.append(jnp.broadcast_to(commit, (b.per_dev,)))
+            take.append(jnp.broadcast_to(commit, (b.factor_per_dev,)))
         new[key] = settle_factor_rows(
             ops.update_running_avg(local, factors_local[key], factor_decay),
             factors_local[key], take, guard, commit)
@@ -604,15 +624,17 @@ def local_invs(plan, decomp, axis_name, comm_mode):
 
 
 def _local_trace_avgs(plan, factors_local, axis_name):
-    """Per-local-slot ``trace/true_dim`` averages (flat, concat over
+    """Per-local-factor-row ``trace/true_dim`` averages (flat, concat over
     buckets in bucket_dims order) — the pi-damping inputs shared by the
     full and staggered Cholesky paths. O(D) per slot: cheap enough to
     recompute every step even when only a cohort is decomposed."""
     trace_parts, dim_parts = [], []
     for bdim in plan.bucket_dims:
         b = plan.buckets[bdim]
-        tdl = _local_table(b.true_dims.reshape(plan.num_devices, b.per_dev),
-                           axis_name)
+        tdl = _local_table(
+            b.true_dims[:b.n_factor_rows].reshape(plan.num_devices,
+                                                  b.factor_per_dev),
+            axis_name)
         trace_parts.append(ops.masked_trace(factors_local[_key(bdim)], tdl))
         dim_parts.append(tdl)
     flat_tr = jnp.concatenate(trace_parts)
@@ -700,7 +722,12 @@ def compute_decomposition(plan, factors_local, damping, method, eps,
         key = _key(bdim)
         b = plan.buckets[bdim]
         off = plan.local_flat_offsets[bdim]
-        own_avg = lax.dynamic_slice_in_dim(flat_avg, off, b.per_dev)
+        # a row that holds an inverse alone (a later member of an input
+        # group) is made of the group's one running average, damped
+        # against the member's own ``G``
+        own_avg = (lax.dynamic_slice_in_dim(flat_avg, off, b.per_dev)
+                   if b.factor_row is None else
+                   jnp.take(flat_avg, off + jnp.asarray(b.factor_row)))
         mate_avg = jnp.take(flat_avg, _local_table(b.mate_flat, axis_name))
         damp_vec = jnp.sqrt(damping * own_avg / mate_avg)
         if invs_prev_local is None:
@@ -708,10 +735,14 @@ def compute_decomposition(plan, factors_local, damping, method, eps,
             invs[key] = ops.damped_psd_inverse(
                 factors_local[key], damp_vec,
                 prev=None if stored_local is None
-                else stored_local['invs'][key], guard=guard, commit=commit)
+                else stored_local['invs'][key], guard=guard, commit=commit,
+                rows=b.factor_row)
         else:
             invs[key] = ops.warm_inverse(
-                ops.add_scaled_identity(factors_local[key], damp_vec),
+                ops.add_scaled_identity(
+                    factors_local[key] if b.factor_row is None else
+                    jnp.take(factors_local[key], b.factor_row, axis=0),
+                    damp_vec),
                 invs_prev_local[key],
                 iters=2 if warm_sweeps is None else max(int(warm_sweeps),
                                                         1),

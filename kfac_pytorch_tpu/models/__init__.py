@@ -1,7 +1,12 @@
 """Model zoo — the reference's example models rebuilt in Flax/NHWC with
 KFAC-aware layers (reference zoo: examples/cifar_resnet.py,
 cifar_vgg.py, cifar_wide_resnet.py, imagenet_resnet.py,
-imagenet_inceptionv4.py, examples/transformer/, wikitext_models.py)."""
+imagenet_inceptionv4.py, examples/transformer/, wikitext_models.py), and
+beyond it the language models the trainers build by name: ``transformer_lm``
+(LayerNorm/GELU decoder), ``sparse_decoder_lm`` (latent attention,
+sigmoid-routed experts: one chip's share) and ``mixed_decoder_lm`` (window
+and full attention mixed, grouped-query, gated, QK-normed; routed experts:
+one chip's share)."""
 
 from kfac_pytorch_tpu.models.cifar_resnet import (
     resnet20, resnet32, resnet44, resnet56, resnet110)
@@ -17,6 +22,8 @@ from kfac_pytorch_tpu.models.rnn import wikitext_lstm
 from kfac_pytorch_tpu.models.gpt import TransformerLM, transformer_lm
 from kfac_pytorch_tpu.models.sparse_decoder import (
     SparseDecoderConfig, SparseDecoderLM, sparse_decoder_lm)
+from kfac_pytorch_tpu.models.mixed_decoder import (
+    MixedDecoderConfig, MixedDecoderLM, held_layer_types, mixed_decoder_lm)
 
 
 def get_model(name, num_classes=10, **kw):

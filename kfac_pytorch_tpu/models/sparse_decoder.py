@@ -49,6 +49,14 @@ them): ``moe/dropped`` (rows that found no room in an expert's buffer,
 CUMULATIVE over the run and all layers), ``moe/rows_max`` and
 ``moe/rows_mean`` of the step (rows an expert held got: fullest expert of
 any layer, mean over experts and layers).
+
+*Shared with ``models.mixed_decoder``* (the decoder whose layers differ in
+kind: window and full attention mixed), named once, here: :class:`RMSNorm`,
+:class:`MoECounters`, ``parallel.moe.SwiGLU`` / ``RoutedExperts``, flat
+tokens, the ``L + 1``-id batch and the mean next-token loss. This block's
+alone: :class:`LatentAttention`, :func:`interleaved_rotary`,
+:class:`DecoderLayer` (two norms a block, one kind of attention) and the
+fields of :class:`SparseDecoderConfig` marked so.
 """
 
 import dataclasses
@@ -144,7 +152,11 @@ class LatentAttention(linen.Module):
 @dataclasses.dataclass(frozen=True)
 class SparseDecoderConfig:
     """Sizes as ``config.json`` publishes them (defaults: kanana-2-30b-a3b),
-    and this chip's share: ``head_ids``, ``expert_ids``, ``vocab_size``."""
+    and this chip's share: ``head_ids``, ``expert_ids``, ``vocab_size``.
+    The latent block's alone: ``kv_rank``, ``qk_nope``, ``qk_rope``,
+    ``v_dim``, ``head_ids`` and ``num_layers`` (every layer one kind); the
+    other fields ``models.mixed_decoder.MixedDecoderConfig`` has too, under
+    the same names and meanings."""
     vocab_size: int = 128256
     hidden_size: int = 2048
     num_layers: int = 48

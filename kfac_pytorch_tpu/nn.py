@@ -75,7 +75,7 @@ class Dense(linen.Module, _KFACLayerMixin):
                 name='/'.join(self.path), path=tuple(self.path), kind='dense',
                 use_bias=self.use_bias,
                 in_dim=d_in + int(self.use_bias), out_dim=self.features,
-                kernel_shape=(d_in, self.features)))
+                kernel_shape=(d_in, self.features)), reads=(x,))
         self._capture_input(x)
         x, kernel = linen.dtypes.promote_dtype(x, kernel, dtype=self.dtype)
         y = lax.dot_general(x, kernel, (((x.ndim - 1,), (0,)), ((), ())))
@@ -118,7 +118,8 @@ class StackedDense(linen.Module, _KFACLayerMixin):
                     name='/'.join(self.path) + f'/{e}', path=tuple(self.path),
                     kind='stacked', use_bias=False, in_dim=d_in,
                     out_dim=self.features,
-                    kernel_shape=(d_in, self.features), index=e))
+                    kernel_shape=(d_in, self.features), index=e),
+                    reads=(x, rows, loss_rows))
             self._capture_input(x)
             self.sow(capture.ACTS, 'n', jnp.asarray(rows, jnp.float32),
                      reduce_fn=_overwrite, init_fn=lambda: ())

@@ -205,39 +205,90 @@ def settle_inverse_rows(inv, stored, guard, commit=None, first=0):
     return heal_rows(inv, bad, last_good, beside=stored)
 
 
-def damped_psd_inverse(x, damp, prev=None, guard=False, commit=None):
+def damped_psd_inverse(x, damp, prev=None, guard=False, commit=None,
+                       rows=None):
     """``(x + damp I)^-1`` of a bucket ``x [rows, D, D]``, ``damp [rows]``:
     whole, or tile by tile where :func:`inverse_tiling` says so.
+
+    ``rows`` (a static ``[n]`` array, ``n >= x.shape[0]``; None: ``x``'s
+    rows in order): result row ``i`` is made of ``x[rows[i]]`` with
+    ``damp[i]`` (layers that read one input keep one running average,
+    damped for each by its own ``G``: ``plan.Bucket.factor_row``). The
+    first ``x.shape[0]`` of them are ``x``'s rows in order, as the plan
+    lays them: the whole groups among those are sliced as a bucket without
+    ``rows`` slices them, and only the rows after are read from where they
+    lie. As many matrices are inverted as that bucket would invert.
 
     A group is ``size`` consecutive rows, damped, inverted and written
     into the result where they belong, one group after the other, so that
     only one group's damped copy and Cholesky temporaries live at a time.
-    The last group starts at ``rows - size``: it inverts a few rows of its
-    neighbour again (the same bits) instead of padding the bucket.
+    The last group is moved back to end with the bucket, and makes again
+    what the one before it made of the rows they share; with a panel
+    width the identity is solved that many columns at a time. Neither
+    changes a product's terms.
 
-    ``prev`` (tiled buckets only; the whole path takes no notice): the
-    bucket's stored inverses. The groups are written over them, so that a
-    donated state's buffer is the result's and no second bucket is held;
+    ``prev`` (the stored inverses) is what the groups are written over;
     with ``guard`` and ``commit`` each group is settled against the rows it
     is about to replace (:func:`settle_inverse_rows`), as
     ``engine.guard_decomposition`` settles a whole bucket."""
-    rows, d = x.shape[0], x.shape[-1]
-    size, width = inverse_tiling(rows, d, x.dtype.itemsize)
-    if (size, width) == (rows, d):
-        return psd_inverse(add_scaled_identity(x, damp))
+    n, d = x.shape[0], x.shape[-1]
+    if rows is not None and not np.array_equal(rows[:n], np.arange(n)):
+        raise ValueError('rows must start with x\'s own rows in order')
+    total = damp.shape[0]
+    size, width = inverse_tiling(total, d, x.dtype.itemsize)
+    if (size, width) == (total, d):
+        return psd_inverse(add_scaled_identity(
+            x if rows is None else jnp.take(x, jnp.asarray(rows), axis=0),
+            damp))
 
-    def one_group(i, out):
-        start = jnp.minimum(i * size, rows - size)
-        xs = add_scaled_identity(
-            lax.dynamic_slice_in_dim(x, start, size, axis=0),
-            lax.dynamic_slice_in_dim(damp, start, size, axis=0))
-        inv = (psd_inverse(xs) if width == d
-               else _psd_inverse_panels(xs, width))
-        inv, out = settle_inverse_rows(inv, out, guard, commit, first=start)
-        return lax.dynamic_update_slice_in_dim(out, inv, start, axis=0)
+    def groups(count, first, read, out):
+        """``count`` result rows from ``first`` on, ``read(start, k)``
+        giving rows ``start`` to ``start + k`` of them undamped."""
+        k = min(size, count)
 
-    return lax.fori_loop(0, -(-rows // size), one_group,
-                         jnp.zeros_like(x) if prev is None else prev)
+        def one_group(i, out):
+            start = jnp.minimum(i * k, count - k)
+            xs = add_scaled_identity(read(start, k), lax.dynamic_slice_in_dim(
+                damp, first + start, k, axis=0))
+            inv = (psd_inverse(xs) if width == d
+                   else _psd_inverse_panels(xs, width))
+            inv, out = settle_inverse_rows(inv, out, guard, commit,
+                                           first=first + start)
+            return lax.dynamic_update_slice_in_dim(out, inv, first + start,
+                                                   axis=0)
+
+        return lax.fori_loop(0, -(-count // k), one_group, out)
+
+    def sliced(start, k):
+        return lax.dynamic_slice_in_dim(x, start, k, axis=0)
+
+    out = jnp.zeros((total, d, d), x.dtype) if prev is None else prev
+    if rows is None:
+        return groups(n, 0, sliced, out)
+    # ``x``'s own rows as far as they fill whole groups, then the rest of
+    # them with the rows made of another's factor: a last group moved back
+    # once, as above, not once for each kind of row (that cost the sparse
+    # decoders 8 more matrices of 2,048 an update, 17 ms: PERF.md, PR 43)
+    whole = n // size * size
+    if whole:
+        out = groups(whole, 0, sliced, out)
+    table = jnp.asarray(rows[whole:], jnp.int32)
+
+    def by_row(start, k):
+        # a row at a time, each copied from where it lies: a gather of
+        # whole matrices took 4 ms a group of 8 x 2,048^2 on the chip, and
+        # rows read inside the damping's own fusion made the compiler copy
+        # all of ``x`` into another layout (PERF.md, PR 43)
+        at = lax.dynamic_slice_in_dim(table, start, k)
+
+        def one_row(j, buf):
+            return lax.dynamic_update_slice_in_dim(
+                buf, lax.dynamic_slice_in_dim(x, at[j], 1, axis=0), j,
+                axis=0)
+
+        return lax.fori_loop(0, k, one_row, jnp.zeros((k, d, d), x.dtype))
+
+    return groups(total - whole, whole, by_row, out)
 
 
 #: batched matmul at HIGHEST internal precision — the warm-path kernels

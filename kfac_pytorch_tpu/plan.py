@@ -15,7 +15,14 @@ XLA wants one uniform program, so the plan instead fixes a *layout*:
 - within a device's rows of a bucket, slots lie by (pred group, side,
   layer): each group's rows are one contiguous run, so the apply reads the
   stored decompositions where they lie (``PredGroup.run_starts``) instead
-  of gathering a copy of them every step.
+  of gathering a copy of them every step;
+- dense layers that read one input (``LayerMeta.input_group``: ``q`` / ``k``
+  / ``v``, a SwiGLU's ``gate`` / ``up``) keep ONE running-average ``A``
+  between them, the first member's; every member still has a row of its
+  own for the INVERSE of that ``A``, because the trace-split damping adds
+  to it a multiple of the identity that goes by the member's own ``G``.
+  Such rows hold no factor and lie last in their bucket
+  (``Bucket.n_factor_rows``, ``Bucket.factor_row``).
 
 Identity padding is numerically exact (see ops/linalg.py). The stacked
 sharded-eigh layout is the TPU-idiomatic form of tcmm's multiBcast fused
@@ -93,6 +100,10 @@ class Slot:
     side: str        # 'A' | 'G'
     dim: int         # true (unpadded) dim
     owner: int
+    # an ``A`` slot of a layer that reads another layer's input: the layer
+    # whose running average it is inverted from (the slot holds an inverse
+    # and no factor); None for a slot with a factor of its own
+    factor_of: Optional[int] = None
 
 
 @dataclasses.dataclass
@@ -104,13 +115,30 @@ class Bucket:
     slot_of_row: List[Optional[Slot]]       # None → dummy pad row
     true_dims: np.ndarray                   # [n_rows]; dummies get dim
     valid: np.ndarray                       # [n_rows] bool
+    # the first ``n_factor_rows`` rows hold a running average and its
+    # inverse; the rest (input groups, one device) an inverse alone, made
+    # of the running average in row ``factor_row[r]``. The factor state is
+    # ``[n_factor_rows, dim, dim]``, the decomposition ``[n_rows, ...]``.
+    n_factor_rows: int
+    factor_row: Optional[np.ndarray] = None  # [n_rows]; None: arange
     # pi-damping mate maps (cholesky variants; rank_a == rank_g layouts):
     # for each row: flat local index (concat over buckets, per device) of
     # the other factor of the same layer, plus dims and side sign.
+    # (flat index: over the FACTOR rows of a device, buckets in order)
     mate_flat: Optional[np.ndarray] = None  # [P, per_dev]
     own_dim: Optional[np.ndarray] = None    # [P, per_dev]
     mate_dim: Optional[np.ndarray] = None   # [P, per_dev]
     side_is_a: Optional[np.ndarray] = None  # [P, per_dev] bool
+
+    @property
+    def factor_per_dev(self):
+        """Factor rows a device (rows that hold an inverse alone are one
+        device's)."""
+        return self.per_dev - (self.n_rows - self.n_factor_rows)
+
+    @property
+    def factor_slots(self):
+        return self.slot_of_row[:self.n_factor_rows]
 
 
 @dataclasses.dataclass
@@ -119,7 +147,7 @@ class PredGroup:
     dg: int
     da: int
     layer_idx: np.ndarray       # [M] global layer indices (static order)
-    row_a: np.ndarray           # [M] global row in bucket da
+    row_a: np.ndarray           # [M] global row in bucket da (inverse's)
     row_g: np.ndarray           # [M] global row in bucket dg
     # comm_pred (owner-computes) maps:
     k_per_dev: int = 0
@@ -162,7 +190,9 @@ class FactorPlan:
     num_devices: int
     comm_mode: str                      # 'inverse' | 'pred'
     buckets: Dict[int, Bucket]
-    # per layer: (bucket_a, row_a_global, bucket_g, row_g_global, owner)
+    # per layer: (bucket_a, row_a_global, bucket_g, row_g_global, owner);
+    # row_a is the row of the layer's ``A`` FACTOR: in an input group the
+    # first member's, for every member
     layer_rows: List[Tuple[int, int, int, int, int]]
     pred_groups: List[PredGroup]
     bucket_dims: List[int]              # sorted bucket keys (stable order)
@@ -172,10 +202,29 @@ class FactorPlan:
     # comm_volume can honestly price the OTHER comm mode's layout
     # (a pred plan re-derives whole-layer ownership from the same rule)
     assignment: str = 'round_robin'
+    # per layer: the row of bucket_a that holds the inverse of its damped
+    # ``A`` (``layer_rows``' row_a but for the later members of an input
+    # group)
+    inv_row_a: List[int] = dataclasses.field(default_factory=list)
 
     @property
     def num_layers(self):
         return len(self.metas)
+
+    def a_leaders(self):
+        """``[L]``: for every layer the first layer that reads its ``A``
+        factor (itself, where it reads its input alone)."""
+        first = {}
+        return [first.setdefault((ba, ra), i)
+                for i, (ba, ra, _, _, _) in enumerate(self.layer_rows)]
+
+    def a_groups(self):
+        """``[[layer index, ...], ...]``: the layers of every ``A``
+        factor that more than one layer reads."""
+        by_leader = {}
+        for i, lead in enumerate(self.a_leaders()):
+            by_leader.setdefault(lead, []).append(i)
+        return [v for v in by_leader.values() if len(v) > 1]
 
     def comm_volume(self, *, stats_reduce, method, comm_precision='fp32',
                     comm_mode=None, decomp_shard=None):
@@ -303,7 +352,9 @@ def pred_layout_record(plan: 'FactorPlan'):
     - ``decomp_groups``: ``{str(bucket dim): [groups of rows, panels of
       columns]}`` for the buckets the Cholesky decomposition inverts tile
       by tile (``ops.inverse_tiling``), empty where every bucket goes
-      whole.
+      whole;
+    - ``a_groups`` / ``a_rows_saved``: ``A`` factors that more than one
+      layer reads (input groups), and the factor rows that saves.
     """
     from kfac_pytorch_tpu.ops.linalg import inverse_tiling
     local = plan.comm_mode == 'pred'
@@ -322,11 +373,14 @@ def pred_layout_record(plan: 'FactorPlan'):
         size, width = inverse_tiling(rows, bdim)
         if (size, width) != (rows, bdim):
             groups[str(bdim)] = [-(-rows // size), bdim // width]
+    shared = plan.a_groups()
     return {'pred_operand_slices': sum(reads),
             'pred_operand_takes': len(reads) - sum(reads),
             'pad_flop_share': round(padded / true, 4),
             'stacked_layers': sum(m.kind == 'stacked' for m in plan.metas),
-            'decomp_groups': groups}
+            'decomp_groups': groups,
+            'a_groups': len(shared),
+            'a_rows_saved': sum(len(g) - 1 for g in shared)}
 
 
 def _slot_cost(dim):
@@ -687,13 +741,15 @@ def same_row_layout(plan_a: 'FactorPlan', plan_b: 'FactorPlan') -> bool:
         return False
     for bdim in plan_a.bucket_dims:
         a, b = plan_a.buckets[bdim], plan_b.buckets[bdim]
-        if (a.per_dev, a.n_rows) != (b.per_dev, b.n_rows):
+        if (a.per_dev, a.n_rows, a.n_factor_rows) != (
+                b.per_dev, b.n_rows, b.n_factor_rows):
             return False
         if not np.array_equal(a.valid, b.valid):
             return False
         if not np.array_equal(a.true_dims, b.true_dims):
             return False
-    return plan_a.layer_rows == plan_b.layer_rows
+    return (plan_a.layer_rows == plan_b.layer_rows
+            and plan_a.inv_row_a == plan_b.inv_row_a)
 
 
 def build_plan(metas: Dict[str, LayerMeta], num_devices: int, comm_mode: str,
@@ -710,10 +766,29 @@ def build_plan(metas: Dict[str, LayerMeta], num_devices: int, comm_mode: str,
     ``bucket_fn=None`` is the default layout: :func:`default_bucket_fn`'s
     tile rounding, then :func:`fold_buckets`. A caller's own ``bucket_fn``
     states the buckets it wants and is taken as it is.
+
+    Layers of one ``LayerMeta.input_group`` keep ONE ``A`` factor (in the
+    slot of the first of them in ``metas``); the others' ``A`` slots hold
+    the inverse alone (``Slot.factor_of``) and lie last in their bucket.
+    On one device only: with more, ownership goes by layer and a group's
+    slots would have to move together. Which configurations keep an ``A``
+    a layer instead is ``KFAC.setup``'s to say (``KFAC._plan_metas``, the
+    one place that strips the groups: :func:`without_input_groups`); here
+    such metas are refused.
     """
     meta_list = list(metas.values())
+    if (num_devices > 1 or distribute_layer_factors) and any(
+            m.input_group is not None for m in meta_list):
+        raise ValueError(
+            'layers that read one input share an A factor on one device '
+            'only: build this plan from without_input_groups(metas)')
     L = len(meta_list)
     P = num_devices
+    # leader[i]: the layer whose ``A`` factor layer i reads (itself, alone)
+    first_of: Dict[str, int] = {}
+    leader = [i if m.input_group is None
+              else first_of.setdefault(m.input_group, i)
+              for i, m in enumerate(meta_list)]
     if comm_mode == 'pred' and distribute_layer_factors:
         raise ValueError(
             'factor-wise distribution requires communicating inverses '
@@ -747,7 +822,8 @@ def build_plan(metas: Dict[str, LayerMeta], num_devices: int, comm_mode: str,
     slots: List[Slot] = []
     for i, m in enumerate(meta_list):
         oa, og = slot_owner[i]
-        slots.append(Slot(i, 'A', m.in_dim, oa))
+        slots.append(Slot(i, 'A', m.in_dim, oa,
+                          None if leader[i] == i else leader[i]))
         slots.append(Slot(i, 'G', m.out_dim, og))
 
     if bucket_fn is None:
@@ -758,9 +834,11 @@ def build_plan(metas: Dict[str, LayerMeta], num_devices: int, comm_mode: str,
         def bucket_fn(dim):
             return joins[tiled[dim]]
 
-    # pred groups: layers sharing (G-bucket, A-bucket), in key order
-    group_key = [(bucket_fn(m.out_dim), bucket_fn(m.in_dim))
-                 for m in meta_list]
+    # pred groups: layers sharing (G-bucket, A-bucket), in key order; a
+    # layer whose ``A`` row holds an inverse alone (it lies with its like
+    # at the bucket's end) in a group of such layers
+    group_key = [(bucket_fn(m.out_dim), bucket_fn(m.in_dim),
+                  leader[i] != i) for i, m in enumerate(meta_list)]
     group_idx = {k: g for g, k in enumerate(sorted(set(group_key)))}
 
     by_bucket: Dict[int, List[Slot]] = {}
@@ -770,7 +848,8 @@ def build_plan(metas: Dict[str, LayerMeta], num_devices: int, comm_mode: str,
     # group's rows are then one contiguous run, in the group's own member
     # order, and the apply can read them in place
     for members in by_bucket.values():
-        members.sort(key=lambda s: (group_idx[group_key[s.layer_idx]],
+        members.sort(key=lambda s: (s.factor_of is not None,
+                                    group_idx[group_key[s.layer_idx]],
                                     s.side, s.layer_idx))
 
     buckets: Dict[int, Bucket] = {}
@@ -792,9 +871,16 @@ def build_plan(metas: Dict[str, LayerMeta], num_devices: int, comm_mode: str,
                 true_dims[r] = s.dim
                 valid[r] = True
                 slot_row[(s.layer_idx, s.side)] = (bdim, r)
-        buckets[bdim] = Bucket(dim=bdim, per_dev=per_dev, n_rows=n_rows,
-                               slot_of_row=slot_of_row, true_dims=true_dims,
-                               valid=valid)
+        buckets[bdim] = Bucket(
+            dim=bdim, per_dev=per_dev, n_rows=n_rows,
+            slot_of_row=slot_of_row, true_dims=true_dims, valid=valid,
+            n_factor_rows=n_rows - sum(s.factor_of is not None
+                                       for s in members))
+    for b in buckets.values():
+        if b.n_factor_rows != b.n_rows:
+            b.factor_row = np.asarray(
+                [r if s.factor_of is None else slot_row[(s.factor_of, 'A')][1]
+                 for r, s in enumerate(b.slot_of_row)], np.int32)
 
     bucket_dims = sorted(buckets)
     # flat local-slot indexing: per device, concat of its local rows over
@@ -803,7 +889,7 @@ def build_plan(metas: Dict[str, LayerMeta], num_devices: int, comm_mode: str,
     off = 0
     for bdim in bucket_dims:
         local_flat_offsets[bdim] = off
-        off += buckets[bdim].per_dev
+        off += buckets[bdim].factor_per_dev
 
     # --- pi-damping mate maps (only meaningful when rank_a == rank_g) ---
     if not distribute_layer_factors:
@@ -821,8 +907,10 @@ def build_plan(metas: Dict[str, LayerMeta], num_devices: int, comm_mode: str,
                     if s is None:
                         mate_flat[d, k] = self_flat  # dummy: pi = 1
                         continue
-                    mate_side = 'G' if s.side == 'A' else 'A'
-                    mb, mr = slot_row[(s.layer_idx, mate_side)]
+                    # a layer's ``G`` is damped against the ``A`` factor
+                    # it reads, an ``A`` inverse against the layer's ``G``
+                    mb, mr = (slot_row[(s.layer_idx, 'G')] if s.side == 'A'
+                              else slot_row[(leader[s.layer_idx], 'A')])
                     md = mr // buckets[mb].per_dev
                     assert md == d, 'mate slot must be co-located'
                     mate_flat[d, k] = (local_flat_offsets[mb]
@@ -836,19 +924,21 @@ def build_plan(metas: Dict[str, LayerMeta], num_devices: int, comm_mode: str,
     # --- per-layer row lookup ------------------------------------------
     layer_rows = []
     for i, m in enumerate(meta_list):
-        ba, ra = slot_row[(i, 'A')]
+        ba, ra = slot_row[(leader[i], 'A')]
         bg, rg = slot_row[(i, 'G')]
         layer_rows.append((ba, ra, bg, rg, layer_owner[i]))
+    inv_row_a = [slot_row[(i, 'A')][1] for i in range(L)]
 
     # --- pred groups ----------------------------------------------------
-    groups: Dict[Tuple[int, int], List[int]] = {}
+    groups: Dict[Tuple[int, int, bool], List[int]] = {}
     for i, key in enumerate(group_key):
         groups.setdefault(key, []).append(i)
 
     pred_groups = []
-    for (dg, da), lidx in sorted(groups.items()):
+    for (dg, da, _), lidx in sorted(groups.items()):
         lidx = np.asarray(lidx, dtype=np.int32)
-        row_a = np.asarray([layer_rows[i][1] for i in lidx], dtype=np.int32)
+        row_a = np.asarray([slot_row[(i, 'A')][1] for i in lidx],
+                           dtype=np.int32)
         row_g = np.asarray([layer_rows[i][3] for i in lidx], dtype=np.int32)
         pg = PredGroup(dg=dg, da=da, layer_idx=lidx, row_a=row_a, row_g=row_g)
         if comm_mode == 'pred':
@@ -864,7 +954,8 @@ def build_plan(metas: Dict[str, LayerMeta], num_devices: int, comm_mode: str,
             for d in range(P):
                 for k, mpos in enumerate(members_by_dev[d]):
                     i = int(lidx[mpos])
-                    ba, ra, bg, rg, owner = layer_rows[i]
+                    ba, _, bg, rg, owner = layer_rows[i]
+                    ra = slot_row[(i, 'A')][1]
                     local_member[d, k] = mpos
                     local_valid[d, k] = True
                     local_row_a[d, k] = ra - d * buckets[ba].per_dev
@@ -882,4 +973,11 @@ def build_plan(metas: Dict[str, LayerMeta], num_devices: int, comm_mode: str,
                       buckets=buckets, layer_rows=layer_rows,
                       pred_groups=pred_groups, bucket_dims=bucket_dims,
                       local_flat_offsets=local_flat_offsets,
-                      assignment=assignment)
+                      assignment=assignment, inv_row_a=inv_row_a)
+
+
+def without_input_groups(metas):
+    """``metas`` (a ``{name: LayerMeta}`` dict) with no layer in an input
+    group: the plan built from it keeps an ``A`` a layer."""
+    return {k: dataclasses.replace(m, input_group=None)
+            if m.input_group is not None else m for k, m in metas.items()}

@@ -36,7 +36,8 @@ from kfac_pytorch_tpu import engine, faults
 from kfac_pytorch_tpu import health as health_lib
 from kfac_pytorch_tpu.obs import trace as obs_trace
 from kfac_pytorch_tpu.plan import (build_cohorts, build_decomp_shard,
-                                   build_plan, pred_layout_record)
+                                   build_plan, pred_layout_record,
+                                  without_input_groups)
 
 #: decomposition-implementation knob values (the autotuner's ladder
 #: restates this tuple in autotune.DECOMP_IMPLS — it must stay
@@ -557,6 +558,7 @@ class KFAC:
             # (the adopted-knobs relaunch restarts trainers there)
             distribute = (self.comm_mode != 'pred'
                           and self.num_devices > len(metas))
+        metas = self._plan_metas(metas, distribute_layer_factors=distribute)
         if self.mesh_axes is not None:
             from kfac_pytorch_tpu.meshplan.plan import build_mesh_plan
             self._mesh_plan = build_mesh_plan(
@@ -601,6 +603,35 @@ class KFAC:
                 'Cholesky variants hoist that update out (hoists_update)',
                 sorted(record['decomp_groups']))
         return self.plan
+
+    def _plan_metas(self, metas, **target):
+        """``metas`` as the plan is built from them: with their input
+        groups (layers that read one input keep ONE ``A`` factor and an
+        inverse of it each, ``plan.build_plan``) where this configuration
+        reads such rows, without them where it does not. It does on the
+        Cholesky variants' path on one device; the eigh and E-KFAC paths,
+        staggered cohorts (and ``decomp_shard`` with them), a mesh plan,
+        more than one device and factor-wise ownership take factor and
+        decomposition rows for one and the same, and keep an ``A`` a layer
+        rather than a stale copy: said once, by name, and decided here
+        alone (``plan.build_plan`` refuses grouped metas it cannot lay
+        out). ``target``: the fields a replan is about to set."""
+        if not any(m.input_group is not None for m in metas.values()):
+            return metas
+        get = lambda k: target.get(k, getattr(self, k))  # noqa: E731
+        why = [name for name, hit in (
+            ('eigh', get('method') == 'eigh'), ('E-KFAC', get('ekfac')),
+            ('stagger', get('stagger')),
+            ('mesh_axes', get('mesh_axes') is not None),
+            ('more than one device', get('num_devices') > 1),
+            ('distribute_layer_factors',
+             bool(get('distribute_layer_factors')))) if hit]
+        if not why:
+            return metas
+        logging.getLogger(__name__).info(
+            'precond.setup: layers that read one input keep an A each '
+            '(%s does not read a shared A factor)', ', '.join(why))
+        return without_input_groups(metas)
 
     def rebase_cohorts(self):
         """(Re)build the staggered cohort layout for the CURRENT
@@ -865,18 +896,22 @@ class KFAC:
             distribute = False
 
         # -- build the new layout + transported state FIRST ---------------
+        replan_metas = self._plan_metas(
+            {m.path: m for m in old_plan.metas}, method=new_method,
+            ekfac=new_ekfac, mesh_axes=new_mesh, num_devices=new_P,
+            distribute_layer_factors=distribute)
         new_mesh_plan = None
         if new_mesh is not None:
             from kfac_pytorch_tpu.meshplan.plan import build_mesh_plan
             new_mesh_plan = build_mesh_plan(
-                {m.path: m for m in old_plan.metas}, new_mesh,
+                replan_metas, new_mesh,
                 comm_mode=new_mode, assignment=self.assignment,
                 distribute_layer_factors=distribute,
                 bucket_fn=self.bucket_fn, rules=self.mesh_rules)
             new_plan = new_mesh_plan.base
         else:
             new_plan = build_plan(
-                {m.path: m for m in old_plan.metas}, num_devices=new_P,
+                replan_metas, num_devices=new_P,
                 comm_mode=new_mode, assignment=self.assignment,
                 distribute_layer_factors=distribute,
                 bucket_fn=self.bucket_fn)
@@ -1079,9 +1114,10 @@ class KFAC:
         factors, dzero = {}, {}
         for bdim in plan.bucket_dims:
             b = plan.buckets[bdim]
+            # (rows that hold an inverse alone have no factor: plan.Bucket)
             factors[str(bdim)] = jnp.broadcast_to(
                 jnp.eye(bdim, dtype=jnp.float32),
-                (b.n_rows, bdim, bdim))
+                (b.n_factor_rows, bdim, bdim))
         if self.method == 'eigh':
             decomp = {
                 'evals': {str(d): jnp.zeros(

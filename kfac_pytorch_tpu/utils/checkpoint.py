@@ -390,7 +390,10 @@ def reshard_kfac_state(pre_old, pre_new, kfac_state, carry_decomp=False):
         if carry_decomp:
             for grp in decomp:
                 dst, src = decomp[grp], old_decomp[grp]
-                dst[str(ba_n)][ra_n] = src[str(ba_o)][ra_o]
+                # (the inverse of a layer's damped A has a row of its own
+                # where several layers keep one A factor: plan.inv_row_a)
+                dst[str(ba_n)][plan_n.inv_row_a[i]] = src[str(ba_o)][
+                    plan_o.inv_row_a[i]]
                 dst[str(bg_n)][rg_n] = src[str(bg_o)][rg_o]
     import jax.numpy as jnp
     out = fresh.replace(

@@ -2,14 +2,29 @@
 runs them: the configuration files ``BENCHMARK.json`` names, the
 reduction from a trace to the ledger's metrics, and the readers of the
 program's spans, the plain K-FAC reference and the tally of a run's
-failures, and the sparse decoder's cell (``benchmarks/tests/test_configs.py``,
-``test_reduce.py``, ``test_spans.py``, ``test_reference.py``,
-``test_sparse_lm.py``). They stay where the benchmark keeps them; this
-file only puts their directories on the path and imports their cases.
+failures, and the two sparse decoders' cells
+(``benchmarks/tests/test_configs.py``, ``test_reduce.py``,
+``test_spans.py``, ``test_reference.py``, ``test_sparse_lm.py``,
+``test_mixed_lm.py``). They stay where the benchmark keeps them; this
+file only puts their directories on the path and imports their cases:
+
+- ``test_configs.py``: one case a configuration of ``BENCHMARK.json``
+  (four);
+- ``test_reference.py``: the tally of a run's failures and ``kfac_plain``
+  on a two-expert model; imported by name here and, for its own runs
+  under ``benchmarks/tests``, by ``test_configs.py`` too (the names are
+  the same objects, so the cases count once);
+- ``test_reduce.py`` and ``test_spans.py``: the trace reduction and the
+  readers of the program's spans (``test_spans`` imports ``test_reduce``);
+- ``test_sparse_lm.py``: ``kanana-2-30b-a3b-ep16``'s file, metrics and
+  rehearsal cell ``tiny-sparse-lm-freq10`` (four ``run.py`` subprocesses);
+- ``test_mixed_lm.py``: ``trinity-mini-ep16``'s file, metrics and
+  rehearsal cell ``tiny-mixed-lm-freq10`` (three ``run.py`` subprocesses:
+  correct, and both controls fail).
+
 ``test_run_cpu.py`` (19 cases, each a ``run.py`` subprocess) is not
 collected: alone on this CPU it takes 313 s, more than a tier-1 worker
-has to spare (CHANGES.md, PR 35); ``test_sparse_lm.py`` brings four such
-runs of its own tiny cell.
+has to spare (CHANGES.md, PR 35).
 """
 
 import os
@@ -30,3 +45,4 @@ from test_spans import *  # noqa: E402,F401,F403
 # they do not hang on that
 from test_reference import *  # noqa: E402,F401,F403
 from test_sparse_lm import *  # noqa: E402,F401,F403
+from test_mixed_lm import *  # noqa: E402,F401,F403
