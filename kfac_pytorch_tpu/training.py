@@ -156,6 +156,17 @@ def _warm_basis_gate(precond, seen, step, ui, ub):
     return warm
 
 
+def _host_scalar(value):
+    """A step's ``lr`` / ``damping`` as the jitted call takes it: a
+    ``jax.Array`` (a schedule computed on the device) as it is, never
+    pulled back to the host; anything else as a NumPy float32 scalar,
+    whose abstract value is ``jnp.float32()``'s (one trace a variant) and
+    whose transfer rides the call: ``jnp.float32()`` of a Python float is
+    a ``convert_element_type`` program of its own in front of every
+    dispatch."""
+    return value if isinstance(value, jax.Array) else np.float32(value)
+
+
 def build_train_step(model, tx, precond, loss_fn, axis_name=None, mesh=None,
                      extra_mutable=(), sync_extra_vars=True, donate=True,
                      dropout_seed=None, batch_specs=None, check_vma=None,
@@ -266,7 +277,8 @@ def build_train_step(model, tx, precond, loss_fn, axis_name=None, mesh=None,
       tracer: an ``obs.trace.TraceRecorder`` (or None). With or without
         it, step_fn writes its host spans into the profiler's own trace
         (``obs.trace.annotation``: ``kfac.step`` and its children
-        ``.read_step`` / ``.hooks`` / ``.select`` / ``.build/<variant>`` /
+        ``.read_step`` (only in a call that reads the counter from the
+        device, below) / ``.hooks`` / ``.select`` / ``.build/<variant>`` /
         ``.dispatch/<phases>``; an inactive check each when no
         ``jax.profiler`` session is open). When a recorder is given, the
         dispatch span has two sinks from its one call: the profiler's
@@ -283,7 +295,22 @@ def build_train_step(model, tx, precond, loss_fn, axis_name=None, mesh=None,
 
     Returns ``step_fn(state, batch, lr, damping) -> (state, metrics)``;
     dispatches between up to four compiled variants using the
-    preconditioner's host-side update frequencies. With a
+    preconditioner's host-side update frequencies.
+
+    ``step_fn`` never waits on the device: between the caller's call and
+    the step's dispatch it reads nothing back and launches no program of
+    its own, so the host runs ahead and step k+1 is queued while step k
+    runs. The step counter that picks the variant lives on the host: hand
+    back the state ``step_fn`` returned (other fields may be replaced) and
+    the counter is the last one + 1, every branch of the step adding
+    exactly 1. Any other state (the first call, a restored checkpoint, a
+    fresh ``init_train_state``, ``state.replace(step=...)``, the same
+    state passed twice) is told by its ``step`` array not being the one
+    last returned and costs one ``int(state.step)``, which waits for the
+    step that made it; ``step_fn.step_reads`` counts those reads (1 in an
+    uninterrupted run). ``lr`` / ``damping`` go in with the jitted call
+    as NumPy float32 scalars; a ``jax.Array`` (a schedule computed on the
+    device) is passed through as it is and never pulled to the host. With a
     ``KFAC(stagger=True)`` preconditioner, the first inverse update is
     still one full decomposition; afterwards every step dispatches the
     staggered variant (traced cohort index — the variant count does not
@@ -590,6 +617,10 @@ def build_train_step(model, tx, precond, loss_fn, axis_name=None, mesh=None,
 
     variants = {}
     seen_inverse = {}  # host-side: does a decomposition exist yet?
+    # host-side step counter: the ``step`` array of the state last
+    # returned and the value it will hold (the array object is kept so
+    # that no other can take its identity; donated, it holds no memory)
+    counter = {'array': None, 'value': 0}
     annotation = obs_trace.annotation
 
     def step_fn(state, batch, lr=None, damping=None):
@@ -599,8 +630,19 @@ def build_train_step(model, tx, precond, loss_fn, axis_name=None, mesh=None,
         # doing in it; with no profiler session each is an inactive check
         with contextlib.ExitStack() as spans:
             spans.enter_context(annotation('kfac.step'))
-            with annotation('kfac.step.read_step'):
-                step = int(state.step)
+            if state.step is counter['array']:
+                # the caller handed back the state this function returned
+                # (with or without other fields replaced): every branch of
+                # one_step adds exactly 1, so the host knows the counter
+                # and the previous step need not have finished
+                step = counter['value']
+            else:
+                # any other state (first call, a restored or rebuilt one,
+                # state.replace(step=...), the same state twice): read it,
+                # which waits for whatever step made it
+                with annotation('kfac.step.read_step'):
+                    step = int(state.step)
+                step_fn.step_reads += 1
             with annotation('kfac.step.hooks'):
                 # straggler governor: measure the inter-arrival of host
                 # steps (tick BEFORE the fault hooks so an injected slow
@@ -750,9 +792,9 @@ def build_train_step(model, tx, precond, loss_fn, axis_name=None, mesh=None,
                 # suffix)
                 step_fn.last_phases = variant_phases(**build)
                 hyper = KFACHyperParams(
-                    lr=jnp.float32(lr if lr is not None
-                                   else getattr(precond, 'lr', 0.0)),
-                    damping=jnp.float32(
+                    lr=_host_scalar(lr if lr is not None
+                                    else getattr(precond, 'lr', 0.0)),
+                    damping=_host_scalar(
                         damping if damping is not None
                         else getattr(precond, 'damping', 0.0)))
             if key not in variants:
@@ -793,7 +835,7 @@ def build_train_step(model, tx, precond, loss_fn, axis_name=None, mesh=None,
                                 else None),
                         consumer_step=step + 1))
             try:
-                return variants[key](state, batch, hyper)
+                new_state, mets = variants[key](state, batch, hyper)
             except Exception as e:
                 # per-call block_impl='pallas_interpret' cannot be seen
                 # by the check_vma auto-detection (it only reads
@@ -809,6 +851,8 @@ def build_train_step(model, tx, precond, loss_fn, axis_name=None, mesh=None,
                         'KFAC_ATTN_IMPL, pass check_vma=False to '
                         'build_train_step.') from e
                 raise
+            counter['array'], counter['value'] = new_state.step, step + 1
+            return new_state, mets
 
     # Warm-tracking host state, exposed for checkpoint/resume: three
     # scalars ('yes', 'last_full', 'warm_streak') that are per-process
@@ -828,6 +872,10 @@ def build_train_step(model, tx, precond, loss_fn, axis_name=None, mesh=None,
     # utils.metrics.PhaseTimers together with the step's wall time, so
     # epoch lines can attribute time per phase (runlog.kfac_phase_suffix)
     step_fn.last_phases = ()
+    # how many calls read the step counter back from the device: 1 in a
+    # loop that hands back the state it was given, one more for every
+    # state replaced from outside
+    step_fn.step_reads = 0
     # the jitted variant cache + constructor, exposed for introspection:
     # scripts/comm_count.py builds a variant via make_variant and lowers
     # it WITHOUT executing a step (AOT lower/compile only)
