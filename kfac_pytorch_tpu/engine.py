@@ -544,8 +544,8 @@ def update_factors(plan, factors_local, stats_stacked, factor_decay,
             stats = stats.at[idx].set(red)
         if stats_reduce == 'pmean':
             # only the reduce is CommunicateFactor — the EMA below is
-            # compute, so xprof attribution matches time_breakdown.py's
-            # exclude-parts subtraction
+            # compute, so a trace's attribution matches the reference's
+            # exclude-parts phases
             with jax.named_scope('kfac.CommunicateFactor'):
                 local, err = coll.pmean_scatter_ef(
                     stats, axis_name, comm_precision, err_in,
@@ -660,6 +660,17 @@ def tiled_buckets(plan):
         != (plan.buckets[bdim].per_dev, bdim))
 
 
+def bucket_scope(bdim, n):
+    """The device scope one bucket's decomposition runs under, inside
+    ``kfac.ComputeInverse*``: ``decomp.b<D>x<n>``, ``D`` the bucket dim
+    and ``n`` the matrices the bucket hands back on this device (result
+    rows, not what a moved-back last group makes twice). Both are static
+    in a compiled program, so a trace's reader takes the task from the
+    name (``plan.pred_layout_record``'s ``decomp_buckets`` says the same);
+    the stages' scopes nest inside it (``ops.psd_inverse``)."""
+    return jax.named_scope(f'decomp.b{int(bdim)}x{int(n)}')
+
+
 def compute_decomposition(plan, factors_local, damping, method, eps,
                           axis_name, basis_local=None, warm_sweeps=None,
                           invs_prev_local=None, impl=None,
@@ -707,14 +718,17 @@ def compute_decomposition(plan, factors_local, damping, method, eps,
         for bdim in plan.bucket_dims:
             key = _key(bdim)
             basis = None if basis_local is None else basis_local[key]
-            d, q = ops.sym_eig(factors_local[key], impl=impl, basis=basis,
-                               sweeps=warm_sweeps if basis is not None
-                               else None)
-            evals[key] = ops.clamp_eigvals(d, eps)
+            with bucket_scope(bdim, factors_local[key].shape[0]):
+                d, q = ops.sym_eig(factors_local[key], impl=impl,
+                                   basis=basis,
+                                   sweeps=warm_sweeps if basis is not None
+                                   else None)
+                evals[key] = ops.clamp_eigvals(d, eps)
             evecs[key] = q
         return {'evals': evals, 'evecs': evecs}
 
-    # cholesky: per-slot traces (mate maps guarantee co-location, plan.py)
+    # cholesky: per-slot traces (mate maps guarantee co-location, plan.py);
+    # they and the damping vectors stay directly under the caller's scope
     flat_avg = _local_trace_avgs(plan, factors_local, axis_name)
 
     invs = {}
@@ -730,23 +744,26 @@ def compute_decomposition(plan, factors_local, damping, method, eps,
                    jnp.take(flat_avg, off + jnp.asarray(b.factor_row)))
         mate_avg = jnp.take(flat_avg, _local_table(b.mate_flat, axis_name))
         damp_vec = jnp.sqrt(damping * own_avg / mate_avg)
-        if invs_prev_local is None:
-            # whole, or in groups of the bucket's rows where it is large
-            invs[key] = ops.damped_psd_inverse(
-                factors_local[key], damp_vec,
-                prev=None if stored_local is None
-                else stored_local['invs'][key], guard=guard, commit=commit,
-                rows=b.factor_row)
-        else:
-            invs[key] = ops.warm_inverse(
-                ops.add_scaled_identity(
-                    factors_local[key] if b.factor_row is None else
-                    jnp.take(factors_local[key], b.factor_row, axis=0),
-                    damp_vec),
-                invs_prev_local[key],
-                iters=2 if warm_sweeps is None else max(int(warm_sweeps),
-                                                        1),
-                accept_resid=NS_ACCEPT_RESID)
+        with bucket_scope(bdim, damp_vec.shape[0]):
+            if invs_prev_local is None:
+                # whole, or in groups of the bucket's rows where it is
+                # large
+                invs[key] = ops.damped_psd_inverse(
+                    factors_local[key], damp_vec,
+                    prev=None if stored_local is None
+                    else stored_local['invs'][key], guard=guard,
+                    commit=commit, rows=b.factor_row)
+            else:
+                with jax.named_scope('decomp.damp'):
+                    damped = ops.add_scaled_identity(
+                        factors_local[key] if b.factor_row is None else
+                        jnp.take(factors_local[key], b.factor_row, axis=0),
+                        damp_vec)
+                invs[key] = ops.warm_inverse(
+                    damped, invs_prev_local[key],
+                    iters=2 if warm_sweeps is None
+                    else max(int(warm_sweeps), 1),
+                    accept_resid=NS_ACCEPT_RESID)
     return {'invs': invs}
 
 
@@ -773,9 +790,10 @@ def refresh_decomposition(plan, factors_local, decomp_prev, eps, axis_name,
         key = _key(bdim)
         q = evecs_local[key]
         f = factors_local[key]
-        fq = jnp.einsum('mjk,mki->mji', f, q, precision=_PRED_PRECISION)
-        d = jnp.sum(q * fq, axis=1)
-        evals[key] = ops.clamp_eigvals(d, eps)
+        with bucket_scope(bdim, f.shape[0]):
+            fq = jnp.einsum('mjk,mki->mji', f, q, precision=_PRED_PRECISION)
+            d = jnp.sum(q * fq, axis=1)
+            evals[key] = ops.clamp_eigvals(d, eps)
     if comm_mode == 'inverse':
         if communicate:
             evals = {k: coll.all_gather_rows_compressed(v, axis_name,
@@ -836,13 +854,14 @@ def compute_cohort_decomposition(plan, cohorts, factors_local, cohort_idx,
         evals, evecs = {}, {}
         for bdim in plan.bucket_dims:
             key = _key(bdim)
-            f = jnp.take(factors_local[key], sel[bdim], axis=0)
-            basis = (None if basis_local is None
-                     else jnp.take(basis_local[key], sel[bdim], axis=0))
-            d, q = ops.sym_eig(f, impl=impl, basis=basis,
-                               sweeps=warm_sweeps if basis is not None
-                               else None)
-            evals[key] = ops.clamp_eigvals(d, eps)
+            with bucket_scope(bdim, sel[bdim].shape[0]):
+                f = jnp.take(factors_local[key], sel[bdim], axis=0)
+                basis = (None if basis_local is None
+                         else jnp.take(basis_local[key], sel[bdim], axis=0))
+                d, q = ops.sym_eig(f, impl=impl, basis=basis,
+                                   sweeps=warm_sweeps if basis is not None
+                                   else None)
+                evals[key] = ops.clamp_eigvals(d, eps)
             evecs[key] = q
         return {'evals': evals, 'evecs': evecs}
 
@@ -858,16 +877,18 @@ def compute_cohort_decomposition(plan, cohorts, factors_local, cohort_idx,
         mate_avg = jnp.take(flat_avg, _cohort_table(
             cohorts.mate_flat[bdim], cohort_idx, axis_name))
         damp_vec = jnp.sqrt(damping * own_avg / mate_avg)
-        f = jnp.take(factors_local[key], sel[bdim], axis=0)
-        damped = ops.add_scaled_identity(f, damp_vec)
-        if invs_prev is None:
-            invs[key] = ops.psd_inverse(damped)
-        else:
-            invs[key] = ops.warm_inverse(
-                damped, jnp.take(invs_prev[key], sel[bdim], axis=0),
-                iters=2 if warm_sweeps is None else max(int(warm_sweeps),
-                                                        1),
-                accept_resid=NS_ACCEPT_RESID)
+        with bucket_scope(bdim, damp_vec.shape[0]):
+            with jax.named_scope('decomp.damp'):
+                f = jnp.take(factors_local[key], sel[bdim], axis=0)
+                damped = ops.add_scaled_identity(f, damp_vec)
+            if invs_prev is None:
+                invs[key] = ops.psd_inverse(damped)
+            else:
+                invs[key] = ops.warm_inverse(
+                    damped, jnp.take(invs_prev[key], sel[bdim], axis=0),
+                    iters=2 if warm_sweeps is None
+                    else max(int(warm_sweeps), 1),
+                    accept_resid=NS_ACCEPT_RESID)
     return {'invs': invs}
 
 
@@ -943,10 +964,11 @@ def compute_shard_decomposition(plan, cohorts, shard, factors_local,
                 valid = jnp.any(q != 0, axis=(-2, -1), keepdims=True)
                 basis = jnp.where(valid, q,
                                   jnp.eye(q.shape[-1], dtype=q.dtype))
-            d, q = ops.sym_eig(mine, impl=impl, basis=basis,
-                               sweeps=warm_sweeps if basis is not None
-                               else None)
-            out_d[key] = ops.clamp_eigvals(d, eps)
+            with bucket_scope(bdim, mine.shape[0]):
+                d, q = ops.sym_eig(mine, impl=impl, basis=basis,
+                                   sweeps=warm_sweeps if basis is not None
+                                   else None)
+                out_d[key] = ops.clamp_eigvals(d, eps)
             out_q[key] = q
         else:
             seed = None
@@ -954,14 +976,15 @@ def compute_shard_decomposition(plan, cohorts, shard, factors_local,
                 rows = _cohort_table(shard.src_global[bdim], cohort_idx,
                                      axis_name)
                 seed = jnp.take(decomp_prev['invs'][key], rows, axis=0)
-            if seed is None:
-                out_i[key] = ops.psd_inverse(mine)
-            else:
-                out_i[key] = ops.warm_inverse(
-                    mine, seed,
-                    iters=2 if warm_sweeps is None
-                    else max(int(warm_sweeps), 1),
-                    accept_resid=NS_ACCEPT_RESID)
+            with bucket_scope(bdim, mine.shape[0]):
+                if seed is None:
+                    out_i[key] = ops.psd_inverse(mine)
+                else:
+                    out_i[key] = ops.warm_inverse(
+                        mine, seed,
+                        iters=2 if warm_sweeps is None
+                        else max(int(warm_sweeps), 1),
+                        accept_resid=NS_ACCEPT_RESID)
     if method == 'eigh':
         return {'evals': out_d, 'evecs': out_q}
     return {'invs': out_i}

@@ -170,10 +170,6 @@ class TraceRecorder:
         evt['s'] = scope
         return self.emit(evt)
 
-    def counter(self, name, values, cat='kfac'):
-        """Record a Chrome counter sample (``values``: {series: num})."""
-        return self.emit(self._base(name, 'C', cat, dict(values)))
-
     def clock_sync(self):
         """Paired (wall, monotonic) reading for cross-host alignment."""
         return self.instant('clock_sync', cat='meta', scope='p',

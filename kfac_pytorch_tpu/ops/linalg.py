@@ -34,12 +34,21 @@ def psd_inverse(x):
     Parity: ``mat_inv(..., method='cholesky')`` (reference:
     kfac/utils.py:11-18). Implemented as two batched triangular solves so it
     lowers to one XLA kernel per bucket.
+
+    Each of the three stages runs under a ``jax.named_scope`` of its own
+    (``decomp.cholesky``, ``decomp.solve_lower``, ``decomp.solve_upper``):
+    the operations the compiler expands them into carry the name in a
+    trace, and nothing else changes.
     """
-    chol = jnp.linalg.cholesky(x)
-    eye = jnp.broadcast_to(jnp.eye(x.shape[-1], dtype=x.dtype), x.shape)
-    y = lax.linalg.triangular_solve(chol, eye, left_side=True, lower=True)
-    return lax.linalg.triangular_solve(
-        chol, y, left_side=True, lower=True, transpose_a=True)
+    with jax.named_scope('decomp.cholesky'):
+        chol = jnp.linalg.cholesky(x)
+    with jax.named_scope('decomp.solve_lower'):
+        eye = jnp.broadcast_to(jnp.eye(x.shape[-1], dtype=x.dtype), x.shape)
+        y = lax.linalg.triangular_solve(chol, eye, left_side=True,
+                                        lower=True)
+    with jax.named_scope('decomp.solve_upper'):
+        return lax.linalg.triangular_solve(
+            chol, y, left_side=True, lower=True, transpose_a=True)
 
 
 #: What the Cholesky inverse of a bucket costs in temporaries: the compiler
@@ -73,16 +82,20 @@ def inverse_tiling(rows, dim, itemsize=4):
 def _psd_inverse_panels(x, width):
     """:func:`psd_inverse` with the identity solved ``width`` columns at a
     time (the same two triangular solves a panel, one after the other)."""
-    chol = jnp.linalg.cholesky(x)
+    with jax.named_scope('decomp.cholesky'):
+        chol = jnp.linalg.cholesky(x)
     d = x.shape[-1]
 
     def one_panel(j):
-        cols = j * width + jnp.arange(width)
-        eye = (jnp.arange(d)[:, None] == cols[None, :]).astype(x.dtype)
-        eye = jnp.broadcast_to(eye, x.shape[:-1] + (width,))
-        y = lax.linalg.triangular_solve(chol, eye, left_side=True, lower=True)
-        return lax.linalg.triangular_solve(
-            chol, y, left_side=True, lower=True, transpose_a=True)
+        with jax.named_scope('decomp.solve_lower'):
+            cols = j * width + jnp.arange(width)
+            eye = (jnp.arange(d)[:, None] == cols[None, :]).astype(x.dtype)
+            eye = jnp.broadcast_to(eye, x.shape[:-1] + (width,))
+            y = lax.linalg.triangular_solve(chol, eye, left_side=True,
+                                            lower=True)
+        with jax.named_scope('decomp.solve_upper'):
+            return lax.linalg.triangular_solve(
+                chol, y, left_side=True, lower=True, transpose_a=True)
 
     out = lax.map(one_panel, jnp.arange(d // width))    # [P, rows, D, width]
     return jnp.moveaxis(out, 0, -2).reshape(x.shape)
@@ -230,16 +243,22 @@ def damped_psd_inverse(x, damp, prev=None, guard=False, commit=None,
     ``prev`` (the stored inverses) is what the groups are written over;
     with ``guard`` and ``commit`` each group is settled against the rows it
     is about to replace (:func:`settle_inverse_rows`), as
-    ``engine.guard_decomposition`` settles a whole bucket."""
+    ``engine.guard_decomposition`` settles a whole bucket.
+
+    What is not one of :func:`psd_inverse`'s three stages has a scope too:
+    ``decomp.damp`` (the reads of the rows and the damping added to them),
+    ``decomp.settle`` and ``decomp.write`` (a group into the result)."""
     n, d = x.shape[0], x.shape[-1]
     if rows is not None and not np.array_equal(rows[:n], np.arange(n)):
         raise ValueError('rows must start with x\'s own rows in order')
     total = damp.shape[0]
     size, width = inverse_tiling(total, d, x.dtype.itemsize)
     if (size, width) == (total, d):
-        return psd_inverse(add_scaled_identity(
-            x if rows is None else jnp.take(x, jnp.asarray(rows), axis=0),
-            damp))
+        with jax.named_scope('decomp.damp'):
+            xs = add_scaled_identity(
+                x if rows is None else jnp.take(x, jnp.asarray(rows), axis=0),
+                damp)
+        return psd_inverse(xs)
 
     def groups(count, first, read, out):
         """``count`` result rows from ``first`` on, ``read(start, k)``
@@ -248,14 +267,18 @@ def damped_psd_inverse(x, damp, prev=None, guard=False, commit=None,
 
         def one_group(i, out):
             start = jnp.minimum(i * k, count - k)
-            xs = add_scaled_identity(read(start, k), lax.dynamic_slice_in_dim(
-                damp, first + start, k, axis=0))
+            with jax.named_scope('decomp.damp'):
+                xs = add_scaled_identity(
+                    read(start, k), lax.dynamic_slice_in_dim(
+                        damp, first + start, k, axis=0))
             inv = (psd_inverse(xs) if width == d
                    else _psd_inverse_panels(xs, width))
-            inv, out = settle_inverse_rows(inv, out, guard, commit,
-                                           first=first + start)
-            return lax.dynamic_update_slice_in_dim(out, inv, first + start,
-                                                   axis=0)
+            with jax.named_scope('decomp.settle'):
+                inv, out = settle_inverse_rows(inv, out, guard, commit,
+                                               first=first + start)
+            with jax.named_scope('decomp.write'):
+                return lax.dynamic_update_slice_in_dim(
+                    out, inv, first + start, axis=0)
 
         return lax.fori_loop(0, -(-count // k), one_group, out)
 
@@ -336,7 +359,8 @@ def warm_inverse(damped, seed, iters=2, accept_resid=0.05):
     guarded by an outer ``lax.cond`` so the Cholesky program only ever
     executes when some slot actually failed.
     """
-    ns, resid = newton_schulz_inverse(damped, seed, iters=iters)
+    with jax.named_scope('decomp.newton_schulz'):
+        ns, resid = newton_schulz_inverse(damped, seed, iters=iters)
     slot_ok = resid < accept_resid
     return lax.cond(
         jnp.all(slot_ok),
@@ -374,13 +398,15 @@ def sym_eig(x, impl=None, basis=None, sweeps=None):
     impl = impl or os.environ.get('KFAC_EIGH_IMPL', 'xla')
     if impl == 'auto':
         impl = 'subspace'
-    if impl == 'jacobi':
-        return jacobi_eigh(x, sweeps=sweeps, basis=basis)
-    if impl == 'subspace' and basis is not None:
-        return subspace_eigh(x, basis, steps=sweeps)
-    # QDWH: no warm-start notion ('subspace' with no basis lands here too)
-    eigvals, eigvecs = jnp.linalg.eigh(x)
-    return eigvals, eigvecs
+    with jax.named_scope('decomp.eigh'):
+        if impl == 'jacobi':
+            return jacobi_eigh(x, sweeps=sweeps, basis=basis)
+        if impl == 'subspace' and basis is not None:
+            return subspace_eigh(x, basis, steps=sweeps)
+        # QDWH: no warm-start notion ('subspace' with no basis lands here
+        # too)
+        eigvals, eigvecs = jnp.linalg.eigh(x)
+        return eigvals, eigvecs
 
 
 def _chol_qr(z, jitter=1e-6):

@@ -353,6 +353,15 @@ def pred_layout_record(plan: 'FactorPlan'):
       columns]}`` for the buckets the Cholesky decomposition inverts tile
       by tile (``ops.inverse_tiling``), empty where every bucket goes
       whole;
+    - ``decomp_buckets``: ``{str(bucket dim): [n, rows a group, columns a
+      panel]}`` for EVERY bucket, ``n`` the matrices the bucket hands back
+      an update on a device: what the decomposition's device scopes
+      ``decomp.b<D>x<n>`` say (``engine.bucket_scope``), and how each is
+      tiled (``[n, n, D]``: whole);
+    - ``decomp_task_flop``: ``sum n * D^3`` over the buckets, one
+      Cholesky factorisation, one triangular inverse and one triangular
+      product at ``D^3 / 3`` flop each: the least any Cholesky-route
+      inverse does, whatever implements it;
     - ``a_groups`` / ``a_rows_saved``: ``A`` factors that more than one
       layer reads (input groups), and the factor rows that saves.
     """
@@ -367,10 +376,11 @@ def pred_layout_record(plan: 'FactorPlan'):
         for i in pg.layer_idx:
             m = plan.metas[int(i)]
             true += m.out_dim ** 2 * m.in_dim + m.out_dim * m.in_dim ** 2
-    groups = {}
+    groups, tiles = {}, {}
     for bdim in plan.bucket_dims:
         rows = plan.buckets[bdim].per_dev
         size, width = inverse_tiling(rows, bdim)
+        tiles[str(bdim)] = [rows, size, width]
         if (size, width) != (rows, bdim):
             groups[str(bdim)] = [-(-rows // size), bdim // width]
     shared = plan.a_groups()
@@ -379,6 +389,9 @@ def pred_layout_record(plan: 'FactorPlan'):
             'pad_flop_share': round(padded / true, 4),
             'stacked_layers': sum(m.kind == 'stacked' for m in plan.metas),
             'decomp_groups': groups,
+            'decomp_buckets': tiles,
+            'decomp_task_flop': sum(n * int(d) ** 3
+                                    for d, (n, _, _) in tiles.items()),
             'a_groups': len(shared),
             'a_rows_saved': sum(len(g) - 1 for g in shared)}
 

@@ -1319,8 +1319,8 @@ class KFAC:
                                for k, v in factors.items()}
             else:
                 # named scopes mirror the reference's phase taxonomy
-                # (exclude_parts names) so xprof traces attribute time
-                # the same way scripts/time_breakdown.py does
+                # (exclude_parts names): a profiler's trace attributes
+                # device time by them (benchmarks/reducers)
                 if stats is None:
                     stats = self.layer_stats(acts, gs)
                 rowwise = engine.rowwise_buckets(plan, reduce)

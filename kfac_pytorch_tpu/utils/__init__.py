@@ -19,8 +19,7 @@ try:
         auto_resume, PreemptionGuard, StaleLineageError,
         wait_for_checkpoints, prune_checkpoints, reshard_kfac_state,
         write_world_stamp, read_world_stamp, read_world_stamp_info)
-    from kfac_pytorch_tpu.utils.profiling import (
-        trace, time_steps, exclude_parts_breakdown)
+    from kfac_pytorch_tpu.utils.profiling import trace, time_steps
 except ModuleNotFoundError as _e:  # pragma: no cover - jax-less lanes
     if _e.name not in ('jax', 'jaxlib'):
         raise
@@ -35,5 +34,5 @@ __all__ = [
     'prune_checkpoints',
     'reshard_kfac_state', 'write_world_stamp', 'read_world_stamp',
     'read_world_stamp_info',
-    'trace', 'time_steps', 'exclude_parts_breakdown',
+    'trace', 'time_steps',
 ]

@@ -121,8 +121,8 @@ class PhaseTimers:
     step's wall time. The timers bucket wall times by phase set and at
     ``epoch_flush`` derive marginal per-phase costs by subtraction
     between observed sets — the passive, in-run form of the
-    exclude-parts ablation method (utils/profiling.
-    exclude_parts_breakdown). A set with no observed strict subset
+    reference's exclude-parts ablation method. A set with no observed
+    strict subset
     reports its joint mean under a '+'-joined label (e.g. a staggered
     fac-freq-1 run, where every step runs everything, honestly reports
     one ``decomp+gather+pred+stats`` figure).

@@ -4,14 +4,14 @@ The reference's tracing story is manual wall-clock phase timers
 (IO/FW+BW/COMM/KFAC/UPDATE, examples/pytorch_cifar10_resnet.py:289-339)
 plus the --exclude-parts subtraction method (kfac_preconditioner_base.py:
 96-99, consumed by scripts/parse_logs.py:44-73). Under jit the phases fuse
-into one program, so the TPU equivalents are:
-
-- :func:`trace` — a jax.profiler context writing an XLA trace (Perfetto /
-  TensorBoard viewable) for true on-chip phase timing;
-- :func:`exclude_parts_breakdown` — the subtraction method automated:
-  time the jitted step once per ablation flag set and difference the
-  means (this is the reference's attribution method, and it works under
-  fusion because each ablation compiles to a smaller program).
+into one program, so the TPU equivalent is :func:`trace`: a jax.profiler
+context writing an XLA trace (Perfetto / TensorBoard viewable), in which
+the step's ``jax.named_scope``s (``kfac.ComputeFactor`` ...
+``kfac.ComputeInverse`` with ``decomp.b<D>x<n>`` and ``decomp.<stage>``
+inside it) name every device operation; ``benchmarks/reducers`` turn such
+a trace into per-phase device times. (The subtraction method automated,
+``exclude_parts_breakdown``, went with PR 45: it differenced the host-clock
+times of DIFFERENT programs, which says nothing of one program's phases.)
 """
 
 import contextlib
@@ -19,9 +19,6 @@ import time
 
 import jax
 import numpy as np
-
-PHASES = ('ComputeFactor', 'CommunicateFactor', 'ComputeInverse',
-          'CommunicateInverse')
 
 
 @contextlib.contextmanager
@@ -93,28 +90,3 @@ def speed_report(log, step_fn, state, batch, units_per_iter,
     log.info('SPEED: iter time %.4f +- %.4f s (%s %.1f)',
              mean, std, unit, units_per_iter / mean)
     return state
-
-
-def exclude_parts_breakdown(make_step, batch, iters=20, **kw):
-    """Attribute per-phase cost by ablation subtraction.
-
-    ``make_step(exclude_parts) -> (step_fn, fresh_state)`` builds a step
-    with the given phases excluded plus a matching fresh train state.
-    Returns ``{phase: seconds}`` with 'Total' and the subtraction-derived
-    per-phase costs (cumulative ablation, reference parse_logs.py:44-73).
-    """
-    results = {}
-    excluded = []
-    step, state = make_step('')
-    t_full, _, _ = time_steps(step, state, batch, iters=iters, **kw)
-    results['Total'] = t_full
-    prev = t_full
-    for phase in ('CommunicateInverse', 'ComputeInverse',
-                  'CommunicateFactor', 'ComputeFactor'):
-        excluded.append(phase)
-        step, state = make_step(','.join(excluded))
-        t, _, _ = time_steps(step, state, batch, iters=iters, **kw)
-        results[phase] = max(prev - t, 0.0)
-        prev = t
-    results['Rest'] = prev
-    return results

@@ -5,7 +5,8 @@ program's spans, the plain K-FAC reference and the tally of a run's
 failures, and the two sparse decoders' cells
 (``benchmarks/tests/test_configs.py``, ``test_reduce.py``,
 ``test_spans.py``, ``test_reference.py``, ``test_sparse_lm.py``,
-``test_mixed_lm.py``, ``test_step_reads.py``). They stay where the benchmark keeps them; this
+``test_mixed_lm.py``, ``test_step_reads.py``, ``test_decomp_scopes.py``).
+They stay where the benchmark keeps them; this
 file only puts their directories on the path and imports their cases:
 
 - ``test_configs.py``: one case a configuration of ``BENCHMARK.json``
@@ -22,7 +23,12 @@ file only puts their directories on the path and imports their cases:
   rehearsal cell ``tiny-mixed-lm-freq10`` (three ``run.py`` subprocesses:
   correct, and both controls fail);
 - ``test_step_reads.py``: ``step_reads_in_trace`` through its file and
-  ``span_count`` (PR 44; imports ``test_spans``' traces).
+  ``span_count`` (PR 44; imports ``test_spans``' traces);
+- ``test_decomp_scopes.py``: the ten metrics that read the inside of
+  ``kfac.ComputeInverse`` through their files, the three reducers that
+  came with them and ``tools/decomp_table.py``, on a hand-made trace, on
+  the two older recorded traces and on one recorded with the scopes
+  (PR 45).
 
 ``test_run_cpu.py`` (19 cases, each a ``run.py`` subprocess) is not
 collected: alone on this CPU it takes 313 s, more than a tier-1 worker
@@ -49,3 +55,4 @@ from test_reference import *  # noqa: E402,F401,F403
 from test_sparse_lm import *  # noqa: E402,F401,F403
 from test_mixed_lm import *  # noqa: E402,F401,F403
 from test_step_reads import *  # noqa: E402,F401,F403
+from test_decomp_scopes import *  # noqa: E402,F401,F403

@@ -55,17 +55,6 @@ def test_time_steps_returns_steady_state_stats():
     assert mean >= 0 and std >= 0
 
 
-def test_exclude_parts_breakdown_shape():
-    def make_step(excl):
-        def step(state, batch, **kw):
-            return state, jnp.float32(len(excl))
-        return step, 0
-
-    out = profiling.exclude_parts_breakdown(make_step, None, iters=2)
-    assert set(out) == {'Total', 'Rest'} | set(profiling.PHASES)
-    assert all(v >= 0 for v in out.values())
-
-
 def test_speed_report_logs_real_units(caplog):
     """speed_report must emit the canonical parseable SPEED line with the
     caller-supplied per-iteration unit count."""

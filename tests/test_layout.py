@@ -220,10 +220,24 @@ def test_layout_record_of_the_benchmark_models(model, comm_mode, record):
     # dense and conv layers only, every bucket inverted whole, every
     # layer of these lists alone on its input (tests/test_input_groups.py
     # has BERT-base's query / key / value on one)
-    assert pred_layout_record(_plan(model, 1, comm_mode)) == dict(
-        record, stacked_layers=0, decomp_groups={}, a_groups=0,
-        a_rows_saved=0)
+    got = pred_layout_record(_plan(model, 1, comm_mode))
+    # what the decomposition's device scopes are named after (PR 45):
+    # every bucket whole ([n, n, D]), and sum n * D^3
+    buckets, flop = got.pop('decomp_buckets'), got.pop('decomp_task_flop')
+    assert got == dict(record, stacked_layers=0, decomp_groups={},
+                       a_groups=0, a_rows_saved=0)
     assert record['pad_flop_share'] <= 1.18
+    assert buckets == {
+        'resnet50': {'128': [24, 24, 128], '256': [27, 27, 256],
+                     '512': [19, 19, 512], '640': [3, 3, 640],
+                     '1024': [15, 15, 1024], '1152': [4, 4, 1152],
+                     '2048': [6, 6, 2048], '2304': [7, 7, 2304],
+                     '4608': [3, 3, 4608]},
+        'bert-base': {'128': [1, 1, 128], '768': [60, 60, 768],
+                      '896': [61, 61, 896], '3072': [12, 12, 3072],
+                      '3200': [12, 12, 3200]}}[model]
+    assert flop == {'resnet50': 456749219840,
+                    'bert-base': 812168249344}[model]
 
 
 def test_layout_record_on_the_old_ladder_and_on_a_mesh():

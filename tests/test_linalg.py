@@ -304,3 +304,179 @@ def test_warm_inverse_per_slot_gate():
     out2 = np.asarray(ops.warm_inverse(jnp.asarray(a1), jnp.asarray(good)))
     ns2, _ = ops.newton_schulz_inverse(jnp.asarray(a1), jnp.asarray(good))
     np.testing.assert_array_equal(out2, np.asarray(ns2))
+
+
+# ---------------------------------------------------------------------------
+# The decomposition's device scopes (PR 45): ``decomp.b<D>x<n>`` a bucket
+# (``engine.bucket_scope``) round ``decomp.<stage>`` (this module's
+# functions). Metadata on the compiled operations and nothing else.
+# ---------------------------------------------------------------------------
+
+import contextlib  # noqa: E402
+import re  # noqa: E402
+
+from kfac_pytorch_tpu import engine  # noqa: E402
+from kfac_pytorch_tpu.ops import linalg  # noqa: E402
+from kfac_pytorch_tpu.plan import (LayerMeta, build_plan,  # noqa: E402
+                                   pred_layout_record)
+
+SOLVE = {'decomp.cholesky', 'decomp.solve_lower', 'decomp.solve_upper'}
+ROWS = np.asarray([0, 1, 2, 3, 4, 1, 1, 3, 0], np.int32)
+
+
+def _op_names(fn, *args):
+    """Every ``op_name`` of the compiled program: what a profiler's trace
+    shows as an operation's ``tf_op``."""
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return set(re.findall(r'op_name="([^"]*)"', text))
+
+
+def _stages(names):
+    return {s for n in names for s in re.findall(r'decomp\.[a-z_]+\b', n)}
+
+
+def _tile_small(monkeypatch):
+    """Tiling as the large buckets get it, at toy sizes: a bucket whose
+    estimate ``rows * D^3 / 64`` passes 256 bytes goes in groups under
+    256, and one matrix over that in panels of columns."""
+    monkeypatch.setattr(linalg, 'WHOLE_INVERSE_TEMP_BYTES', 256)
+    monkeypatch.setattr(linalg, 'INVERSE_GROUP_TEMP_BYTES', 256)
+
+
+@pytest.mark.parametrize('path, tiling, want', [
+    ('psd_inverse', None, SOLVE),
+    ('panels', None, SOLVE),
+    ('damped_whole', (9, 8), SOLVE | {'decomp.damp'}),
+    ('damped_grouped', (4, 16),
+     SOLVE | {'decomp.damp', 'decomp.settle', 'decomp.write'}),
+    ('damped_panelled', (1, 16),
+     SOLVE | {'decomp.damp', 'decomp.settle', 'decomp.write'}),
+    ('damped_rows', (4, 16),
+     SOLVE | {'decomp.damp', 'decomp.settle', 'decomp.write'}),
+    ('warm_inverse', None, SOLVE | {'decomp.newton_schulz'}),
+    ('sym_eig', None, {'decomp.eigh'}),
+])
+def test_stage_scopes_reach_the_compiled_operations_and_no_instruction(
+        path, tiling, want, monkeypatch):
+    _tile_small(monkeypatch)
+    dim = {'damped_whole': 8, 'damped_panelled': 32}.get(path, 16)
+    n = 5 if path == 'damped_rows' else 9
+    x = jnp.asarray(_spd(np.random.RandomState(0), n, dim, dim))
+    damp = jnp.linspace(0.01, 0.1, 9)
+    stored = jnp.zeros((9, dim, dim))
+    commit = jnp.asarray(True)
+    fn, args = {
+        'psd_inverse': (ops.psd_inverse, (x,)),
+        'panels': (lambda a: linalg._psd_inverse_panels(a, 4), (x,)),
+        'warm_inverse': (lambda a, s: ops.warm_inverse(a, s), (x, stored)),
+        'sym_eig': (lambda a: ops.sym_eig(a, impl='xla'), (x,)),
+    }.get(path, (lambda a, d, p, c: ops.damped_psd_inverse(
+        a, d, prev=p, guard=True, commit=c,
+        rows=ROWS if path == 'damped_rows' else None),
+        (x, damp, stored, commit)))
+    if tiling is not None:
+        assert ops.inverse_tiling(9, dim) == tiling
+    assert _stages(_op_names(fn, *args)) == want
+    # the lowered text (which prints no names) is the text without scopes
+    named = jax.jit(lambda *a: fn(*a)).lower(*args).as_text()
+    monkeypatch.setattr(jax, 'named_scope',
+                        lambda name: contextlib.nullcontext())
+    plain = jax.jit(lambda *a: fn(*a)).lower(*args).as_text()
+    assert named == plain and 'decomp.' not in named
+
+
+def _grouped_metas(dims, groups=()):
+    """``dims``: (in, out) a layer; ``groups``: tuples of layer indices
+    that read one input (the first leads)."""
+    lead = {i: f'l{g[0]}' for g in groups for i in g}
+    return {f'l{i}': LayerMeta(
+        name=f'l{i}', path=(f'l{i}',), kind='dense', use_bias=False,
+        in_dim=a, out_dim=g, kernel_shape=(a, g), input_group=lead.get(i))
+        for i, (a, g) in enumerate(dims)}
+
+
+def _bucket8(dim):
+    return -(-dim // 8) * 8
+
+
+#: the four benchmark configurations' shapes at toy size: a conv net's
+#: many small buckets; an encoder whose query / key / value read one
+#: input; two sparse decoders whose largest buckets go tile by tile, one
+#: of them with input groups in the tiled bucket
+TOY_PLANS = {
+    'resnet': ([(27, 8), (72, 8), (8, 16), (72, 16), (16, 16), (17, 10)],
+               ()),
+    'bert': ([(16, 16)] * 3 + [(16, 32), (32, 16)] + [(16, 16)] * 3
+             + [(16, 2)], ((0, 1, 2), (5, 6, 7))),
+    'kanana': ([(16, 8)] * 9 + [(32, 16)] * 3, ()),
+    'trinity': ([(16, 8)] * 9 + [(16, 16)] * 2 + [(32, 16)] * 3,
+                ((0, 1), (2, 3), (9, 10))),
+}
+TOY_RECORDS = {
+    # {D: [n, rows a group, columns a panel]}, sum n * D^3
+    'resnet': ({'8': [3, 3, 8], '16': [5, 4, 16], '24': [1, 1, 24],
+                '32': [1, 1, 16], '72': [2, 1, 3]}, 815104),
+    'bert': ({'8': [1, 1, 8], '16': [15, 4, 16], '32': [2, 1, 16]}, 127488),
+    'kanana': ({'8': [9, 9, 8], '16': [12, 4, 16], '32': [3, 1, 16]},
+               9 * 8 ** 3 + 12 * 16 ** 3 + 3 * 32 ** 3),
+    'trinity': ({'8': [9, 9, 8], '16': [16, 4, 16], '32': [3, 1, 16]},
+                9 * 8 ** 3 + 16 * 16 ** 3 + 3 * 32 ** 3),
+}
+
+
+def _toy_plan(name):
+    dims, groups = TOY_PLANS[name]
+    return build_plan(_grouped_metas(dims, groups), 1, 'pred',
+                      bucket_fn=_bucket8)
+
+
+@pytest.mark.parametrize('name', sorted(TOY_PLANS))
+def test_setup_record_says_what_the_bucket_scopes_say(name, monkeypatch):
+    _tile_small(monkeypatch)
+    plan = _toy_plan(name)
+    record = pred_layout_record(plan)
+    buckets, flop = TOY_RECORDS[name]
+    assert record['decomp_buckets'] == buckets
+    assert record['decomp_task_flop'] == sum(
+        n * int(d) ** 3 for d, (n, _, _) in buckets.items())
+    assert record['decomp_task_flop'] == flop
+    # every bucket is there, tiled or whole; decomp_groups keeps to the
+    # tiled ones, counted in groups and panels
+    assert set(record['decomp_buckets']) == {str(d) for d in plan.buckets}
+    assert record['decomp_groups'] == {
+        d: [-(-n // size), int(d) // width]
+        for d, (n, size, width) in buckets.items()
+        if (size, width) != (n, int(d))}
+
+
+@pytest.mark.parametrize('name, method', [
+    ('trinity', 'cholesky'), ('bert', 'cholesky'), ('resnet', 'eigh')])
+def test_compute_decomposition_puts_every_bucket_under_its_scope(
+        name, method, monkeypatch):
+    """``decomp.b<D>x<n>``: ``n`` the RESULT rows, also where some rows
+    are made of another's factor (``factor_row``: the factor bucket is
+    shorter) and where a last group is moved back and makes rows twice."""
+    _tile_small(monkeypatch)
+    plan = _toy_plan(name)
+    rng = np.random.RandomState(3)
+    factors = {str(d): jnp.asarray(_spd(rng, b.n_factor_rows, d, d))
+               for d, b in plan.buckets.items()}
+    names = _op_names(lambda f: engine.compute_decomposition(
+        plan, f, 0.003, method, 1e-10, None, guard=True), factors)
+    found = {(int(d), int(n)) for text in names
+             for d, n in re.findall(r'decomp\.b(\d+)x(\d+)', text)}
+    assert found == {(d, b.per_dev) for d, b in plan.buckets.items()}
+    if name == 'trinity':
+        b = plan.buckets[16]
+        assert b.factor_row is not None and b.n_factor_rows < b.per_dev
+        assert ops.inverse_tiling(b.per_dev, 16) == (4, 16)
+    staged = [t for t in names if _stages([t])]
+    assert staged and all('decomp.b' in t.split('decomp.')[1] or
+                          re.search(r'decomp\.b\d+x\d+/.*decomp\.[a-z]', t)
+                          for t in staged)
+    # one bucket, one stage an operation
+    assert all(len(re.findall(r'decomp\.b\d+x', t)) <= 1
+               and len(_stages([t])) <= 1 for t in names)
+    if method == 'cholesky':
+        # the trace averages and the damping vectors stay outside
+        assert any('decomp.' not in t and 'sqrt' in t for t in names)

@@ -78,7 +78,6 @@ def test_trace_jsonl_is_valid_perfetto_schema(tmp_path):
                   phases=['ComputeFactor', 'Precondition']):
         pass
     rec.instant('watchdog_trip', deadline_s=1.5)
-    rec.counter('steps', {'n': 1})
     rec.complete('bench.iter', 0.01, cat='bench', i=0)
     rec.flush()
     lines = [l for l in open(path).read().splitlines() if l]
@@ -86,7 +85,7 @@ def test_trace_jsonl_is_valid_perfetto_schema(tmp_path):
     for line in lines:
         evt = json.loads(line)  # every line independently parseable
         assert isinstance(evt['name'], str) and evt['name']
-        assert evt['ph'] in ('X', 'i', 'C', 'M')
+        assert evt['ph'] in ('X', 'i', 'M')
         assert isinstance(evt['pid'], int) and evt['pid'] == 3
         assert isinstance(evt['tid'], int)
         assert isinstance(evt['ts'], (int, float)) and evt['ts'] >= 0
