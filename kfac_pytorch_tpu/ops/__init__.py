@@ -28,6 +28,8 @@ from kfac_pytorch_tpu.ops.linalg import (
     rows_finite,
     tile_diagonal,
     inverse_tiling,
+    inverse_route,
+    inverse_route_flop,
     sym_eig,
     jacobi_eigh,
     subspace_eigh,
@@ -45,7 +47,8 @@ __all__ = [
     'layer_rows_conv', 'ekfac_scales', 'update_running_avg',
     'psd_inverse', 'damped_psd_inverse', 'settle_inverse_rows',
     'inverse_rows_finite', 'diagonal_finite', 'heal_rows', 'rows_finite', 'tile_diagonal',
-    'inverse_tiling', 'sym_eig', 'jacobi_eigh', 'subspace_eigh',
+    'inverse_tiling', 'inverse_route', 'inverse_route_flop',
+    'sym_eig', 'jacobi_eigh', 'subspace_eigh',
     'newton_schulz_inverse', 'warm_inverse',
     'clamp_eigvals', 'add_scaled_identity',
     'masked_trace', 'identity_pad',

@@ -28,30 +28,167 @@ import numpy as np
 from jax import lax
 
 
+#: batched matmul at HIGHEST internal precision: the structured inverse's
+#: products (the precision the triangular solves have) and the warm-path
+#: kernels (Newton-Schulz, subspace tracking), accuracy-sensitive
+#: contractions all
+_mm = functools.partial(jnp.einsum, precision=lax.Precision.HIGHEST)
+
+#: A bucket whose dim is at or over STRUCTURED_INVERSE_DIM takes the two
+#: stages after the Cholesky factorisation by blocks of
+#: STRUCTURED_INVERSE_BLOCK rows (:func:`_triangular_inverse`,
+#: :func:`_triangular_product`); a smaller one solves against a dense
+#: identity, as every bucket did until PR 46. Set from one chip A/B over
+#: the benchmark's buckets (PERF.md section 5, PR 46; the whole inverse,
+#: two solves over structured): 1.17 at 15 x 1,024, 1.11 at 4 x 1,152,
+#: 1.23 at 12 x 1,536, 1.41 at 6 x 2,048, 2.1 at 3 x 4,608; blocks of 256
+#: as fast as 128 and faster than 512. Under 1,024 the chain of 128-block
+#: calls sets a bucket's pace and the two stages have little to give.
+STRUCTURED_INVERSE_DIM = 1024
+STRUCTURED_INVERSE_BLOCK = 256
+
+
+def inverse_route(dim):
+    """``'structured'`` or ``'solves'``: how :func:`psd_inverse` takes a
+    matrix of that dim from its Cholesky factor to its inverse."""
+    return 'structured' if dim >= STRUCTURED_INVERSE_DIM else 'solves'
+
+
+def _blocks(dim, block):
+    """``(lo, hi)`` of every block of rows; the last may be shorter."""
+    edges = list(range(0, dim, block)) + [dim]
+    return list(zip(edges, edges[1:]))
+
+
+def _halfway(lo, block):
+    """Where :func:`_triangular_inverse` splits the ``lo`` columns of the
+    triangle so far: a whole number of blocks, 0 for no split."""
+    return lo // block // 2 * block
+
+
+def _structured_products(dim, block):
+    """``(m, k, n)`` of every product the structured route makes for one
+    matrix: :func:`_triangular_inverse`'s, then
+    :func:`_triangular_product`'s."""
+    out = []
+    for lo, hi in _blocks(dim, block)[1:]:
+        c = _halfway(lo, block)
+        out += [(hi - lo, lo, c)] * bool(c) + [
+            (hi - lo, lo - c, lo - c), (hi - lo, hi - lo, lo)]
+    return out + [(hi - lo, dim - lo, hi) for lo, hi in _blocks(dim, block)]
+
+
+def inverse_route_flop(rows, dim):
+    """Floating-point operations :func:`psd_inverse` spends on ``rows``
+    matrices of ``dim``, by the route their shape takes: the factorisation's
+    ``D^3 / 3`` and two dense solves at ``D^3`` each, or the factorisation,
+    the diagonal blocks' own solves and two for every multiply-add of the
+    structured route's products (the task itself is ``D^3``:
+    ``plan.pred_layout_record``'s ``decomp_task_flop``)."""
+    if inverse_route(dim) == 'solves':
+        return rows * (7 * dim ** 3 // 3)
+    block = STRUCTURED_INVERSE_BLOCK
+    leaves = sum((hi - lo) ** 3 for lo, hi in _blocks(dim, block))
+    products = sum(2 * m * k * n
+                   for m, k, n in _structured_products(dim, block))
+    return rows * (dim ** 3 // 3 + leaves + products)
+
+
+def _solve_identity(chol):
+    """``L^-1`` by the dense solve ``L Y = I``."""
+    eye = jnp.broadcast_to(jnp.eye(chol.shape[-1], dtype=chol.dtype),
+                           chol.shape)
+    return lax.linalg.triangular_solve(chol, eye, left_side=True, lower=True)
+
+
+def _triangular_inverse(chol, block):
+    """``L^-1`` of the lower-triangular ``chol [..., D, D]`` by block rows:
+    ``Y[i, i] = L[i, i]^-1`` (the dense solve, on a block; the whole blocks
+    in one batched call) and ``Y[i, :i] = -Y[i, i] (L[i, :i] Y[:i, :i])``,
+    each block row written where it lies in a result that starts as zeros:
+    the strict upper triangle is those zeros, never a product's result.
+    The triangle so far is multiplied in two halves of its columns, the
+    right half without the zero quarter above it (the chip's A/B, PERF.md
+    section 5, PR 46: a tenth to a sixth faster than in one product)."""
+    d = chol.shape[-1]
+    blocks = _blocks(d, block)
+    whole = [(lo, hi) for lo, hi in blocks if hi - lo == block]
+    diag = _solve_identity(jnp.stack(
+        [chol[..., lo:hi, lo:hi] for lo, hi in whole], axis=-3))
+    diag = [diag[..., i, :, :] for i in range(len(whole))]
+    diag += [_solve_identity(chol[..., lo:hi, lo:hi])
+             for lo, hi in blocks[len(whole):]]
+    mm = functools.partial(_mm, '...ij,...jk->...ik')
+    y = jnp.zeros_like(chol)
+    for (lo, hi), yii in zip(blocks, diag):
+        row = yii
+        if lo:
+            c = _halfway(lo, block)
+            t = mm(chol[..., lo:hi, c:lo], y[..., c:lo, c:lo])
+            if c:
+                t = jnp.concatenate(
+                    [mm(chol[..., lo:hi, :lo], y[..., :lo, :c]), t], axis=-1)
+            row = jnp.concatenate([-mm(yii, t), yii], axis=-1)
+        y = lax.dynamic_update_slice(y, row, (0,) * (y.ndim - 2) + (lo, 0))
+    return y
+
+
+def _triangular_product(y, block):
+    """``Y' Y`` of the lower-triangular ``y [..., D, D]``: block row ``i``
+    of the lower half is ``Y[i:, i]' Y[i:, :i+1]`` (no operand reaches
+    into the zeros above the diagonal blocks), the upper half the mirror
+    of the lower: the result is symmetric to the last bit."""
+    d = y.shape[-1]
+    rows = []
+    for lo, hi in _blocks(d, block):
+        r = _mm('...ki,...kj->...ij', y[..., lo:, lo:hi], y[..., lo:, :hi])
+        rows.append(jnp.pad(r, [(0, 0)] * (r.ndim - 1) + [(0, d - hi)]))
+    low = jnp.concatenate(rows, axis=-2)
+    i = jnp.arange(d)
+    return jnp.where(i[:, None] >= i[None, :], low,
+                     jnp.swapaxes(low, -1, -2))
+
+
 def psd_inverse(x):
     """Cholesky-based inverse of an SPD matrix (batched).
 
     Parity: ``mat_inv(..., method='cholesky')`` (reference:
-    kfac/utils.py:11-18). Implemented as two batched triangular solves so it
-    lowers to one XLA kernel per bucket.
+    kfac/utils.py:11-18). LAPACK's ``potri`` on the factor ``L``: the
+    triangular inverse ``Y = L^-1``, then the triangular product
+    ``Y' Y``. By shape (:func:`inverse_route`): a matrix under
+    ``STRUCTURED_INVERSE_DIM`` solves ``L Y = I`` and ``L' X = Y`` against
+    the dense identity (one XLA kernel chain a bucket); a larger one makes
+    the same two results by blocks, from GEMMs that leave most of the
+    zero triangle alone (about half of the solves' multiply-adds:
+    :func:`inverse_route_flop`).
 
     Each of the three stages runs under a ``jax.named_scope`` of its own
-    (``decomp.cholesky``, ``decomp.solve_lower``, ``decomp.solve_upper``):
-    the operations the compiler expands them into carry the name in a
-    trace, and nothing else changes.
+    (``decomp.cholesky``, ``decomp.solve_lower``: ``L^-1``,
+    ``decomp.solve_upper``: ``L^-T L^-1``, on either route): the
+    operations the compiler expands them into carry the name in a trace,
+    and nothing else changes.
     """
     with jax.named_scope('decomp.cholesky'):
         chol = jnp.linalg.cholesky(x)
+    return _inverse_of_factor(chol)
+
+
+def _inverse_of_factor(chol):
+    """``L^-T L^-1`` of the Cholesky factor ``chol``: :func:`psd_inverse`'s
+    two stages after the factorisation, by the route the shape takes."""
+    block = STRUCTURED_INVERSE_BLOCK
+    structured = inverse_route(chol.shape[-1]) == 'structured'
     with jax.named_scope('decomp.solve_lower'):
-        eye = jnp.broadcast_to(jnp.eye(x.shape[-1], dtype=x.dtype), x.shape)
-        y = lax.linalg.triangular_solve(chol, eye, left_side=True,
-                                        lower=True)
+        y = (_triangular_inverse(chol, block) if structured
+             else _solve_identity(chol))
     with jax.named_scope('decomp.solve_upper'):
+        if structured:
+            return _triangular_product(y, block)
         return lax.linalg.triangular_solve(
             chol, y, left_side=True, lower=True, transpose_a=True)
 
 
-#: What the Cholesky inverse of a bucket costs in temporaries: the compiler
+#: What the route of two solves costs a bucket in temporaries: the compiler
 #: unrolls each triangular solve into D / 128 panel steps and keeps their
 #: shrinking right-hand sides, D / 256 times the right-hand side's bytes
 #: (sandbox compiles for a v5e, PR 39: 12 x 3,200^2 6.0 GB, 3 x 6,144^2
@@ -59,46 +196,28 @@ def psd_inverse(x):
 #: the bucket's bytes once). A bucket whose estimate stays under
 #: WHOLE_INVERSE_TEMP_BYTES is inverted whole: at or above the largest the
 #: benchmark's dense cells invert (BERT-base's 12 x 3,200^2, 6.1 GB), so
-#: their programs are what they were.
+#: their buckets go whole as they did. Since PR 46 the buckets of
+#: STRUCTURED_INVERSE_DIM and more no longer take that route and hold a few
+#: copies of a group instead (sandbox compiles: PERF.md section 6, PR 46);
+#: the estimate is kept for them as the rule that sizes their groups, which
+#: are what they were.
 WHOLE_INVERSE_TEMP_BYTES = 6 * 2 ** 30
-#: ... a larger one in groups of rows, and where one matrix alone passes
-#: it in panels of the identity's columns, each within this estimate
+#: ... a larger one in groups of rows, each within this estimate; a matrix
+#: that passes it alone goes alone
 INVERSE_GROUP_TEMP_BYTES = 2 ** 30
 
 
 def inverse_tiling(rows, dim, itemsize=4):
     """How a ``[rows, dim, dim]`` bucket is inverted, from its shape alone:
-    ``(rows a group, columns a panel)``. ``(rows, dim)`` is whole."""
+    ``(rows a group, columns a panel)``. ``(rows, dim)`` is whole. A panel
+    is ``dim`` columns wide since PR 46: a matrix too large for the
+    two-solve route's estimate is one of the structured route's, whose
+    temporaries are a few copies of the matrix, not ``dim / 256``
+    right-hand sides."""
     one = dim ** 3 * itemsize // 256    # a matrix's estimated temporaries
     if rows * one <= WHOLE_INVERSE_TEMP_BYTES:
         return rows, dim
-    if one <= INVERSE_GROUP_TEMP_BYTES:
-        return min(rows, INVERSE_GROUP_TEMP_BYTES // one), dim
-    panels = next(p for p in range(1, dim + 1) if dim % p == 0
-                  and one // p <= INVERSE_GROUP_TEMP_BYTES)
-    return 1, dim // panels
-
-
-def _psd_inverse_panels(x, width):
-    """:func:`psd_inverse` with the identity solved ``width`` columns at a
-    time (the same two triangular solves a panel, one after the other)."""
-    with jax.named_scope('decomp.cholesky'):
-        chol = jnp.linalg.cholesky(x)
-    d = x.shape[-1]
-
-    def one_panel(j):
-        with jax.named_scope('decomp.solve_lower'):
-            cols = j * width + jnp.arange(width)
-            eye = (jnp.arange(d)[:, None] == cols[None, :]).astype(x.dtype)
-            eye = jnp.broadcast_to(eye, x.shape[:-1] + (width,))
-            y = lax.linalg.triangular_solve(chol, eye, left_side=True,
-                                            lower=True)
-        with jax.named_scope('decomp.solve_upper'):
-            return lax.linalg.triangular_solve(
-                chol, y, left_side=True, lower=True, transpose_a=True)
-
-    out = lax.map(one_panel, jnp.arange(d // width))    # [P, rows, D, width]
-    return jnp.moveaxis(out, 0, -2).reshape(x.shape)
+    return max(1, min(rows, INVERSE_GROUP_TEMP_BYTES // one)), dim
 
 
 def rows_finite(x):
@@ -145,23 +264,34 @@ def diagonal_finite(x, tile=DIAGONAL_TILE):
 def inverse_rows_finite(inv):
     """``[rows]`` bool: does the Cholesky inverse ``inv [rows, D, D]``
     (:func:`psd_inverse`) hold no NaN and no Inf, read from its diagonal
-    alone. Exact for that operand, whatever went wrong on the way to it:
+    alone. Exact for that operand on both of its routes, whatever went
+    wrong on the way to it:
 
     * a NaN or Inf anywhere in the (symmetrised) input, or a pivot that is
       not positive, leaves a NaN in the Cholesky factor ``L`` at that
       pivot, and every later pivot is computed from it: the last one,
-      ``L[D-1, D-1]``, is NaN, and ``inv[D-1, D-1] = 1 / L[D-1, D-1]^2``;
-    * with ``L`` finite, column ``j`` of the inverse solves ``L L' x =
-      e_j`` by a forward and a backward substitution. An entry ``k >= j``
-      of the forward result that is not finite enters every entry above it
-      in the backward one (``Inf * 0`` is NaN, not 0), down to ``x[j]``,
-      the diagonal entry;
+      ``L[D-1, D-1]``, is NaN, and ``inv[D-1, D-1] = 1 / L[D-1, D-1]^2``
+      on either route (the last row of ``Y = L^-1`` is the last step of
+      the last diagonal block's substitution, and the last diagonal entry
+      of ``Y' Y`` is its square);
+    * with ``L`` finite, the route of two solves (``inverse_route``) makes
+      column ``j`` of the inverse from ``L L' x = e_j`` by a forward and a
+      backward substitution. An entry ``k >= j`` of the forward result
+      that is not finite enters every entry above it in the backward one
+      (``Inf * 0`` is NaN, not 0), down to ``x[j]``, the diagonal entry.
+      The structured route makes ``inv[j, j] = sum_{k >= j} Y[k, j]^2`` in
+      one product (the block row's own diagonal block: every term is
+      there, none is skipped), so an entry of ``Y`` that is not finite
+      reaches the diagonal entry of its column;
     * what is left is a finite ``L^-1`` whose products overflow:
-      ``|inv[i, j]| = |sum_k y[k, i] y[k, j]| <= max(inv[i, i], inv[j, j])``.
+      ``|inv[i, j]| = |sum_k y[k, i] y[k, j]| <= max(inv[i, i], inv[j, j])``,
+      on either route; the structured one's upper half is a copy of its
+      lower half and adds nothing to read.
 
     ``tests/test_health.py`` poisons one off-diagonal element and sees the
-    row caught. NOT exact for an eigendecomposition or for an operand
-    corrupted after it was formed: those keep a read of every element."""
+    row caught, on both routes. NOT exact for an eigendecomposition or for
+    an operand corrupted after it was formed: those keep a read of every
+    element."""
     return diagonal_finite(inv)
 
 
@@ -236,9 +366,8 @@ def damped_psd_inverse(x, damp, prev=None, guard=False, commit=None,
     into the result where they belong, one group after the other, so that
     only one group's damped copy and Cholesky temporaries live at a time.
     The last group is moved back to end with the bucket, and makes again
-    what the one before it made of the rows they share; with a panel
-    width the identity is solved that many columns at a time. Neither
-    changes a product's terms.
+    what the one before it made of the rows they share. That changes no
+    product's terms.
 
     ``prev`` (the stored inverses) is what the groups are written over;
     with ``guard`` and ``commit`` each group is settled against the rows it
@@ -252,8 +381,8 @@ def damped_psd_inverse(x, damp, prev=None, guard=False, commit=None,
     if rows is not None and not np.array_equal(rows[:n], np.arange(n)):
         raise ValueError('rows must start with x\'s own rows in order')
     total = damp.shape[0]
-    size, width = inverse_tiling(total, d, x.dtype.itemsize)
-    if (size, width) == (total, d):
+    size, _ = inverse_tiling(total, d, x.dtype.itemsize)
+    if size == total:
         with jax.named_scope('decomp.damp'):
             xs = add_scaled_identity(
                 x if rows is None else jnp.take(x, jnp.asarray(rows), axis=0),
@@ -271,8 +400,7 @@ def damped_psd_inverse(x, damp, prev=None, guard=False, commit=None,
                 xs = add_scaled_identity(
                     read(start, k), lax.dynamic_slice_in_dim(
                         damp, first + start, k, axis=0))
-            inv = (psd_inverse(xs) if width == d
-                   else _psd_inverse_panels(xs, width))
+            inv = psd_inverse(xs)
             with jax.named_scope('decomp.settle'):
                 inv, out = settle_inverse_rows(inv, out, guard, commit,
                                                first=first + start)
@@ -312,11 +440,6 @@ def damped_psd_inverse(x, damp, prev=None, guard=False, commit=None,
         return lax.fori_loop(0, k, one_row, jnp.zeros((k, d, d), x.dtype))
 
     return groups(total - whole, whole, by_row, out)
-
-
-#: batched matmul at HIGHEST internal precision — the warm-path kernels
-#: (Newton-Schulz, subspace tracking) are accuracy-sensitive contractions
-_mm = functools.partial(jnp.einsum, precision=lax.Precision.HIGHEST)
 
 
 def newton_schulz_inverse(a, x0, iters=2):

@@ -362,10 +362,19 @@ def pred_layout_record(plan: 'FactorPlan'):
       Cholesky factorisation, one triangular inverse and one triangular
       product at ``D^3 / 3`` flop each: the least any Cholesky-route
       inverse does, whatever implements it;
+    - ``decomp_route``: ``{str(bucket dim): 'structured' | 'solves'}`` for
+      every bucket: how the Cholesky decomposition gets from the factor to
+      the inverse (``ops.inverse_route``: blocked triangular inverse and
+      triangular product from a threshold dim on, two solves against a
+      dense identity under it);
+    - ``decomp_route_flop``: what those routes spend on the same buckets
+      (``ops.inverse_route_flop``), to set beside ``decomp_task_flop``;
     - ``a_groups`` / ``a_rows_saved``: ``A`` factors that more than one
       layer reads (input groups), and the factor rows that saves.
     """
-    from kfac_pytorch_tpu.ops.linalg import inverse_tiling
+    from kfac_pytorch_tpu.ops.linalg import (inverse_route,
+                                             inverse_route_flop,
+                                             inverse_tiling)
     local = plan.comm_mode == 'pred'
     reads = [pg.run_starts(side, local) is not None
              for pg in plan.pred_groups for side in 'ag']
@@ -392,6 +401,9 @@ def pred_layout_record(plan: 'FactorPlan'):
             'decomp_buckets': tiles,
             'decomp_task_flop': sum(n * int(d) ** 3
                                     for d, (n, _, _) in tiles.items()),
+            'decomp_route': {d: inverse_route(int(d)) for d in tiles},
+            'decomp_route_flop': sum(inverse_route_flop(n, int(d))
+                                     for d, (n, _, _) in tiles.items()),
             'a_groups': len(shared),
             'a_rows_saved': sum(len(g) - 1 for g in shared)}
 

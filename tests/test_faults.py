@@ -240,21 +240,32 @@ def _metas():
     return capture.collect_layer_meta(model, {'params': params}, x)
 
 
+@pytest.mark.parametrize('route', ['solves', 'structured'])
 @pytest.mark.parametrize('value,stored_yet', [
     (np.nan, True), (np.inf, True), (-np.inf, True), (np.nan, False)])
 def test_one_poisoned_factor_element_keeps_the_last_good_inverse(
-        value, stored_yet):
+        value, stored_yet, route, monkeypatch):
     """One OFF-diagonal element of one damped factor not finite: its
     Cholesky inverse is not finite, the guard reads that from the
     inverse's DIAGONAL alone (``ops.inverse_rows_finite``), and the row
     falls back to the stored inverse (the identity where none is stored
     yet) while every other row is the fresh inverse to the bit: what the
-    whole-bucket guard gave with a read of every element of both."""
+    whole-bucket guard gave with a read of every element of both. On both
+    routes from the factor to the inverse (``ops.inverse_route``): two
+    dense solves, and the blocked triangular inverse and product (here by
+    blocks of 64, the last one partial)."""
+    from kfac_pytorch_tpu.ops import linalg
     rows, d = 5, 160            # two diagonal tiles, the second partial
+    monkeypatch.setattr(linalg, 'STRUCTURED_INVERSE_DIM',
+                        128 if route == 'structured' else 10 ** 6)
+    monkeypatch.setattr(linalg, 'STRUCTURED_INVERSE_BLOCK', 64)
+    assert ops.inverse_route(d) == route
     x = ops.add_scaled_identity(_spd(jax.random.PRNGKey(0), rows, d), 0.05)
-    stored = (ops.psd_inverse(x * 1.5) if stored_yet
+    # (a fresh function a jit: its cache does not see a patched constant)
+    stored = (jax.jit(lambda a: ops.psd_inverse(a))(x * 1.5) if stored_yet
               else jnp.zeros_like(x))
-    fresh = jax.jit(ops.psd_inverse)(x.at[2, 7, 140].set(value))
+    fresh = jax.jit(lambda a: ops.psd_inverse(a))(
+        x.at[2, 7, 140].set(value))
     assert not np.isfinite(np.asarray(fresh[2])).all()
     got = jax.jit(lambda n, p: engine.guard_decomposition(
         {'invs': {'160': n}}, {'invs': {'160': p}}, 'cholesky'))(

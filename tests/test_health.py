@@ -220,6 +220,41 @@ def _one_element(tree, leaf, hit):
     return jax.tree.unflatten(treedef, leaves)
 
 
+@pytest.mark.parametrize('route', ['solves', 'structured'])
+@pytest.mark.parametrize('fault', ['nan', 'inf', 'tiny_pivot', 'none'])
+def test_inverse_flag_is_read_from_the_diagonal_on_both_routes(
+        fault, route, monkeypatch):
+    """One OFF-diagonal element of one Cholesky FACTOR not finite (or one
+    pivot so small that ``L^-1`` is finite and its squares are not): the
+    inverse made of it has a row that is not finite, and
+    ``ops.inverse_rows_finite`` says so from the inverse's diagonal alone,
+    for that row and no other. On the two dense solves and on the blocked
+    triangular inverse and product (blocks of 16 over 72 rows: the last
+    one partial, the poisoned element in the rows of another block than
+    its column)."""
+    from kfac_pytorch_tpu.ops import linalg
+    monkeypatch.setattr(linalg, 'STRUCTURED_INVERSE_DIM',
+                        32 if route == 'structured' else 10 ** 6)
+    monkeypatch.setattr(linalg, 'STRUCTURED_INVERSE_BLOCK', 16)
+    rows, d = 4, 72
+    assert ops.inverse_route(d) == route
+    a = jax.random.normal(jax.random.PRNGKey(3), (rows, d, d), jnp.float32)
+    chol = jnp.linalg.cholesky(
+        jnp.einsum('nij,nkj->nik', a, a) / d + 0.1 * jnp.eye(d))
+    chol = {'nan': chol.at[2, 50, 9].set(jnp.nan),
+            'inf': chol.at[2, 50, 9].set(jnp.inf),
+            'tiny_pivot': chol.at[2, 37, 37].set(1e-30),
+            'none': chol}[fault]
+    inv = np.asarray(jax.jit(lambda c: linalg._inverse_of_factor(c))(chol))
+    every = np.isfinite(inv).all(axis=(1, 2))
+    assert list(every) == [True, True, fault == 'none', True]
+    flag = np.asarray(ops.inverse_rows_finite(jnp.asarray(inv)))
+    np.testing.assert_array_equal(flag, every)
+    if fault != 'none':
+        # the witness is on the diagonal; the poison was not
+        assert not np.isfinite(np.diagonal(inv[2])).all()
+
+
 @pytest.fixture(scope='module')
 def healthy_inverse_dp():
     """Four batches through ``inverse_dp`` with the guard on: the control

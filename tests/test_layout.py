@@ -22,7 +22,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-from kfac_pytorch_tpu import engine
+from kfac_pytorch_tpu import engine, ops
 from kfac_pytorch_tpu import plan as plan_lib
 from kfac_pytorch_tpu.capture import LayerMeta
 from kfac_pytorch_tpu.obs import trace as obs_trace
@@ -224,6 +224,9 @@ def test_layout_record_of_the_benchmark_models(model, comm_mode, record):
     # what the decomposition's device scopes are named after (PR 45):
     # every bucket whole ([n, n, D]), and sum n * D^3
     buckets, flop = got.pop('decomp_buckets'), got.pop('decomp_task_flop')
+    # how each bucket gets from its Cholesky factor to its inverse (PR
+    # 46): by blocks from 1,024 on, two dense solves under it
+    route, spent = got.pop('decomp_route'), got.pop('decomp_route_flop')
     assert got == dict(record, stacked_layers=0, decomp_groups={},
                        a_groups=0, a_rows_saved=0)
     assert record['pad_flop_share'] <= 1.18
@@ -238,6 +241,16 @@ def test_layout_record_of_the_benchmark_models(model, comm_mode, record):
                       '3200': [12, 12, 3200]}}[model]
     assert flop == {'resnet50': 456749219840,
                     'bert-base': 812168249344}[model]
+    assert route == {d: 'structured' if int(d) >= 1024 else 'solves'
+                     for d in buckets}
+    assert sorted(d for d in route if route[d] == 'structured') == {
+        'resnet50': ['1024', '1152', '2048', '2304', '4608'],
+        'bert-base': ['3072', '3200']}[model]
+    assert spent == sum(ops.inverse_route_flop(n, int(d))
+                        for d, (n, _, _) in buckets.items())
+    # the structured buckets are most of the task: the routes spend well
+    # under the 7/3 of the task that two solves a bucket did
+    assert flop < spent < 0.7 * (7 * flop // 3)
 
 
 def test_layout_record_on_the_old_ladder_and_on_a_mesh():
@@ -275,6 +288,10 @@ def test_setup_records_the_layout_once(model, caplog):
     assert len(lines) == 1
     assert 'pred_operand_takes 0' in lines[0]
     assert f"pad_flop_share {want['pad_flop_share']}" in lines[0]
+    # ... and the route of every bucket with what the routes spend
+    assert f"decomp_route {want['decomp_route']}" in lines[0]
+    assert f"decomp_route_flop {want['decomp_route_flop']}" in lines[0]
+    assert 'structured' in want['decomp_route'].values()
 
 
 # ---------------------------------------------------------------------------

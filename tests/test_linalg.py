@@ -338,41 +338,60 @@ def _stages(names):
 def _tile_small(monkeypatch):
     """Tiling as the large buckets get it, at toy sizes: a bucket whose
     estimate ``rows * D^3 / 64`` passes 256 bytes goes in groups under
-    256, and one matrix over that in panels of columns."""
+    256, and a matrix over that alone."""
     monkeypatch.setattr(linalg, 'WHOLE_INVERSE_TEMP_BYTES', 256)
     monkeypatch.setattr(linalg, 'INVERSE_GROUP_TEMP_BYTES', 256)
 
 
+def _structure_small(monkeypatch, dim, block):
+    """The structured route as the large buckets get it, at toy sizes:
+    from ``dim`` on, by blocks of ``block`` rows."""
+    monkeypatch.setattr(linalg, 'STRUCTURED_INVERSE_DIM', dim)
+    monkeypatch.setattr(linalg, 'STRUCTURED_INVERSE_BLOCK', block)
+
+
+TILED = SOLVE | {'decomp.damp', 'decomp.settle', 'decomp.write'}
+
+
 @pytest.mark.parametrize('path, tiling, want', [
     ('psd_inverse', None, SOLVE),
-    ('panels', None, SOLVE),
+    ('structured', None, SOLVE),
     ('damped_whole', (9, 8), SOLVE | {'decomp.damp'}),
-    ('damped_grouped', (4, 16),
-     SOLVE | {'decomp.damp', 'decomp.settle', 'decomp.write'}),
-    ('damped_panelled', (1, 16),
-     SOLVE | {'decomp.damp', 'decomp.settle', 'decomp.write'}),
-    ('damped_rows', (4, 16),
-     SOLVE | {'decomp.damp', 'decomp.settle', 'decomp.write'}),
+    ('structured_whole', (9, 8), SOLVE | {'decomp.damp'}),
+    ('damped_grouped', (4, 16), TILED),
+    ('structured_grouped', (4, 16), TILED),
+    ('damped_single', (1, 32), TILED),
+    ('structured_single', (1, 32), TILED),
+    ('damped_rows', (4, 16), TILED),
+    ('structured_rows', (4, 16), TILED),
     ('warm_inverse', None, SOLVE | {'decomp.newton_schulz'}),
     ('sym_eig', None, {'decomp.eigh'}),
 ])
 def test_stage_scopes_reach_the_compiled_operations_and_no_instruction(
         path, tiling, want, monkeypatch):
+    """Every way a bucket is inverted, on both routes: whole, in groups
+    of rows, the rows read through a table, one matrix at a time (the
+    6,144-wide one, which went in panels of columns until PR 46)."""
     _tile_small(monkeypatch)
-    dim = {'damped_whole': 8, 'damped_panelled': 32}.get(path, 16)
-    n = 5 if path == 'damped_rows' else 9
+    kind = path.split('_')[-1]
+    dim = {'whole': 8, 'single': 32}.get(kind, 16)
+    if path.startswith('structured'):
+        _structure_small(monkeypatch, dim, 4)
+    assert ops.inverse_route(dim) == (
+        'structured' if path.startswith('structured') else 'solves')
+    n = 5 if kind == 'rows' else 9
     x = jnp.asarray(_spd(np.random.RandomState(0), n, dim, dim))
     damp = jnp.linspace(0.01, 0.1, 9)
     stored = jnp.zeros((9, dim, dim))
     commit = jnp.asarray(True)
     fn, args = {
         'psd_inverse': (ops.psd_inverse, (x,)),
-        'panels': (lambda a: linalg._psd_inverse_panels(a, 4), (x,)),
+        'structured': (ops.psd_inverse, (x,)),
         'warm_inverse': (lambda a, s: ops.warm_inverse(a, s), (x, stored)),
         'sym_eig': (lambda a: ops.sym_eig(a, impl='xla'), (x,)),
     }.get(path, (lambda a, d, p, c: ops.damped_psd_inverse(
         a, d, prev=p, guard=True, commit=c,
-        rows=ROWS if path == 'damped_rows' else None),
+        rows=ROWS if kind == 'rows' else None),
         (x, damp, stored, commit)))
     if tiling is not None:
         assert ops.inverse_tiling(9, dim) == tiling
@@ -383,6 +402,119 @@ def test_stage_scopes_reach_the_compiled_operations_and_no_instruction(
                         lambda name: contextlib.nullcontext())
     plain = jax.jit(lambda *a: fn(*a)).lower(*args).as_text()
     assert named == plain and 'decomp.' not in named
+
+
+def _two_solves(x):
+    """The route every bucket took until PR 46, spelt out."""
+    chol = jnp.linalg.cholesky(x)
+    eye = jnp.broadcast_to(jnp.eye(x.shape[-1], dtype=x.dtype), x.shape)
+    y = jax.lax.linalg.triangular_solve(chol, eye, left_side=True,
+                                        lower=True)
+    return jax.lax.linalg.triangular_solve(chol, y, left_side=True,
+                                           lower=True, transpose_a=True)
+
+
+@pytest.mark.parametrize('case, shape', [
+    ('single', (72, 72)),           # 9 blocks: 2,304 = 9 x 256
+    ('batch', (3, 72, 72)),
+    ('batch', (2, 144, 144)),       # 18 blocks: 4,608 = 18 x 256
+    ('ragged', (3, 100, 100)),      # 12 blocks and a half: 3,200
+    ('padded', (3, 72, 72)),        # blockdiag(A, I), as a bucket pads
+    ('one_block', (2, 8, 8)),
+])
+def test_structured_inverse_is_the_inverse_and_exactly_symmetric(
+        case, shape, monkeypatch):
+    """The blocked triangular inverse and triangular product against
+    ``np.linalg.inv`` in float64 and against the two dense solves, at
+    ``test_psd_inverse_batched``'s tolerance; symmetric to the last bit,
+    which the solves' result is not."""
+    _structure_small(monkeypatch, 8, 8)
+    d = shape[-1]
+    rng = np.random.RandomState(d + len(shape))
+    if case == 'padded':
+        true = d - 13
+        x = np.asarray(ops.identity_pad(
+            jnp.asarray(_spd(rng, *shape[:-2], true, true)), d))
+    else:
+        x = _spd(rng, *shape)
+    assert ops.inverse_route(d) == 'structured'
+    inv = np.asarray(jax.jit(lambda a: ops.psd_inverse(a))(jnp.asarray(x)))
+    assert inv.shape == x.shape and inv.dtype == np.float32
+    np.testing.assert_allclose(inv, np.linalg.inv(x.astype(np.float64)),
+                               rtol=1e-3, atol=1e-4)
+    np.testing.assert_allclose(inv, np.asarray(_two_solves(jnp.asarray(x))),
+                               rtol=1e-3, atol=1e-4)
+    np.testing.assert_array_equal(inv, np.swapaxes(inv, -1, -2))
+    if case == 'padded':
+        # blockdiag(A, I)^-1 = blockdiag(A^-1, I), to the bit in the pad
+        np.testing.assert_array_equal(inv[..., true:, true:],
+                                      np.broadcast_to(np.eye(13), shape[:-2]
+                                                      + (13, 13)))
+        assert not inv[..., true:, :true].any()
+    # under the threshold the same call is the two solves, to the bit
+    monkeypatch.setattr(linalg, 'STRUCTURED_INVERSE_DIM', d + 1)
+    np.testing.assert_array_equal(
+        np.asarray(ops.psd_inverse(jnp.asarray(x))),
+        np.asarray(_two_solves(jnp.asarray(x))))
+
+
+def _dots(text):
+    """``(lhs shape, rhs shape, flop, precision)`` of every
+    ``dot_general`` of a lowered text."""
+    out = []
+    for line in text.splitlines():
+        if 'stablehlo.dot_general' not in line:
+            continue
+        lhs, rhs, res = (tuple(int(n) for n in t.split('x')[:-1])
+                         for t in re.findall(r'tensor<([\dx]+f32)>', line)[-3:])
+        contract = [int(i) for i in re.search(
+            r'contracting_dims = \[([\d, ]+)\] x', line).group(1).split(',')]
+        flop = 2 * int(np.prod(res)) * int(np.prod([lhs[i] for i in contract]))
+        out.append((lhs, rhs, flop, re.search(
+            r'precision = \[(\w+), (\w+)\]', line).groups()))
+    return out
+
+
+@pytest.mark.parametrize('rows, dim, block', [
+    (3, 72, 8), (2, 144, 8), (3, 100, 8), (2, 64, 16)])
+def test_structured_route_multiplies_no_zero_triangle(rows, dim, block,
+                                                      monkeypatch):
+    """The two stages' lowered text: GEMMs at float32 ``HIGHEST`` and one
+    solve a diagonal block, no product of two full ``[D, D]`` operands,
+    no triangular solve wider than a block, and as many multiply-adds as
+    ``inverse_route_flop`` (``decomp_route_flop`` of the set-up record)
+    states for the shape: 0.9-1.2 ``D^3`` where two dense solves spend
+    ``2 D^3`` (a block row still multiplies three quarters of the square
+    that holds the triangle so far, so not the task's ``2/3 D^3``)."""
+    _structure_small(monkeypatch, 16, block)
+    chol = jnp.zeros((rows, dim, dim), jnp.float32)
+    # (a fresh function a lowering: jit's cache does not see a patched
+    # module constant)
+    text = jax.jit(lambda c: linalg._inverse_of_factor(c)).lower(
+        chol).as_text()
+    dots = _dots(text)
+    assert dots and all(p == ('HIGHEST', 'HIGHEST') for *_, p in dots)
+    assert not any(lhs[-2:] == (dim, dim) and rhs[-2:] == (dim, dim)
+                   for lhs, rhs, _, _ in dots)
+    # (the CPU lowers a triangular solve to LAPACK's trsm, the TPU to
+    # ``stablehlo.triangular_solve``)
+    solved = [int(n) for line in text.splitlines()
+              if 'triangular_solve' in line or 'trsm' in line
+              for n in re.findall(r'tensor<(?:\d+x)*(\d+)xf32>', line)]
+    assert solved and max(solved) <= block
+    leaves = sum(min(block, dim - lo) ** 3 for lo in range(0, dim, block))
+    stated = ops.inverse_route_flop(rows, dim)
+    assert stated == rows * _structured_flop(dim, block)
+    assert sum(f for _, _, f, _ in dots) == stated - rows * (
+        dim ** 3 // 3 + leaves)
+    # the two stages: between the task's 2/3 D^3 and 3/4 of the solves'
+    assert (rows * 2 * dim ** 3 // 3 < stated - rows * (dim ** 3 // 3)
+            < rows * 3 * dim ** 3 // 2)
+    # the two solves of the other route, counted the same way
+    monkeypatch.setattr(linalg, 'STRUCTURED_INVERSE_DIM', dim + 1)
+    assert ops.inverse_route_flop(rows, dim) == rows * (7 * dim ** 3 // 3)
+    assert not _dots(jax.jit(
+        lambda c: linalg._inverse_of_factor(c)).lower(chol).as_text())
 
 
 def _grouped_metas(dims, groups=()):
@@ -413,15 +545,33 @@ TOY_PLANS = {
                 ((0, 1), (2, 3), (9, 10))),
 }
 TOY_RECORDS = {
-    # {D: [n, rows a group, columns a panel]}, sum n * D^3
+    # {D: [n, rows a group, columns a panel]}, sum n * D^3; a panel is
+    # the whole matrix since PR 46 (the matrices that went in panels of
+    # 16 and 3 columns go one at a time, whole)
     'resnet': ({'8': [3, 3, 8], '16': [5, 4, 16], '24': [1, 1, 24],
-                '32': [1, 1, 16], '72': [2, 1, 3]}, 815104),
-    'bert': ({'8': [1, 1, 8], '16': [15, 4, 16], '32': [2, 1, 16]}, 127488),
-    'kanana': ({'8': [9, 9, 8], '16': [12, 4, 16], '32': [3, 1, 16]},
+                '32': [1, 1, 32], '72': [2, 1, 72]}, 815104),
+    'bert': ({'8': [1, 1, 8], '16': [15, 4, 16], '32': [2, 1, 32]}, 127488),
+    'kanana': ({'8': [9, 9, 8], '16': [12, 4, 16], '32': [3, 1, 32]},
                9 * 8 ** 3 + 12 * 16 ** 3 + 3 * 32 ** 3),
-    'trinity': ({'8': [9, 9, 8], '16': [16, 4, 16], '32': [3, 1, 16]},
+    'trinity': ({'8': [9, 9, 8], '16': [16, 4, 16], '32': [3, 1, 32]},
                 9 * 8 ** 3 + 16 * 16 ** 3 + 3 * 32 ** 3),
 }
+
+
+def _structured_flop(dim, block):
+    """What the structured route spends on one matrix, counted from the
+    algorithm and not from ``linalg._structured_products``: the
+    factorisation, a dense solve a diagonal block, and two flop a
+    multiply-add of ``L[i, :i] Y[:i, :i]`` (less the zero quarter the
+    split into two column halves leaves out), ``Y[i, i] (...)`` and
+    ``Y[i:, i]' Y[i:, :i+1]`` for every block row ``i``."""
+    flop = dim ** 3 // 3
+    for i, lo in enumerate(range(0, dim, block)):
+        b = min(block, dim - lo)
+        half = i // 2 * block
+        flop += b ** 3 + 2 * b * (lo * lo - half * (lo - half)) + 2 * b * b * lo
+        flop += 2 * b * (dim - lo) * (lo + b)
+    return flop
 
 
 def _toy_plan(name):
@@ -433,6 +583,7 @@ def _toy_plan(name):
 @pytest.mark.parametrize('name', sorted(TOY_PLANS))
 def test_setup_record_says_what_the_bucket_scopes_say(name, monkeypatch):
     _tile_small(monkeypatch)
+    _structure_small(monkeypatch, 24, 8)
     plan = _toy_plan(name)
     record = pred_layout_record(plan)
     buckets, flop = TOY_RECORDS[name]
@@ -447,16 +598,30 @@ def test_setup_record_says_what_the_bucket_scopes_say(name, monkeypatch):
         d: [-(-n // size), int(d) // width]
         for d, (n, size, width) in buckets.items()
         if (size, width) != (n, int(d))}
+    # the route of every bucket, structured exactly from the threshold
+    # on, and what the routes spend beside what the task needs
+    assert record['decomp_route'] == {
+        d: 'structured' if int(d) >= 24 else 'solves' for d in buckets}
+    assert record['decomp_route_flop'] == sum(
+        n * (_structured_flop(int(d), 8) if int(d) >= 24
+             else 7 * int(d) ** 3 // 3) for d, (n, _, _) in buckets.items())
+    assert (record['decomp_task_flop'] < record['decomp_route_flop']
+            <= 7 * record['decomp_task_flop'] // 3)
 
 
-@pytest.mark.parametrize('name, method', [
-    ('trinity', 'cholesky'), ('bert', 'cholesky'), ('resnet', 'eigh')])
+@pytest.mark.parametrize('name, method, structured', [
+    ('trinity', 'cholesky', False), ('bert', 'cholesky', False),
+    ('resnet', 'eigh', False), ('trinity', 'cholesky', True),
+    ('resnet', 'cholesky', True)])
 def test_compute_decomposition_puts_every_bucket_under_its_scope(
-        name, method, monkeypatch):
+        name, method, structured, monkeypatch):
     """``decomp.b<D>x<n>``: ``n`` the RESULT rows, also where some rows
     are made of another's factor (``factor_row``: the factor bucket is
-    shorter) and where a last group is moved back and makes rows twice."""
+    shorter) and where a last group is moved back and makes rows twice;
+    also where the buckets of 16 and more take the structured route."""
     _tile_small(monkeypatch)
+    if structured:
+        _structure_small(monkeypatch, 16, 8)
     plan = _toy_plan(name)
     rng = np.random.RandomState(3)
     factors = {str(d): jnp.asarray(_spd(rng, b.n_factor_rows, d, d))

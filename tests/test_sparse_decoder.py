@@ -415,18 +415,26 @@ def test_dropped_rows_are_counted_and_add_up_over_steps(roomy_run):
     assert dropped[0] >= tight[0]['moe/rows_max'] - 2 > 0
 
 
+@pytest.mark.parametrize('route', ['solves', 'structured'])
 def test_training_with_tiled_buckets_is_training_with_whole_ones(
-        roomy_run, monkeypatch):
+        roomy_run, route, monkeypatch):
     """The buckets too large to invert whole take other code (groups of
     rows written over the stored inverses, running averages a row at a
     time): made to apply to this tiny model's one bucket, three steps give
-    what they give with the bucket whole."""
+    what they give with the bucket whole. Also where the groups' matrices
+    take the structured route from the Cholesky factor to the inverse (by
+    blocks of 32 here), as the benchmark's tiled buckets do."""
     from kfac_pytorch_tpu.ops import linalg
     _, whole, params = roomy_run
     one = 128 ** 3 * 4 // 256
     monkeypatch.setattr(linalg, 'WHOLE_INVERSE_TEMP_BYTES', 10 * one)
     monkeypatch.setattr(linalg, 'INVERSE_GROUP_TEMP_BYTES', 4 * one)
+    if route == 'structured':
+        monkeypatch.setattr(linalg, 'STRUCTURED_INVERSE_DIM', 128)
+        monkeypatch.setattr(linalg, 'STRUCTURED_INVERSE_BLOCK', 32)
     pre, tiled = _train(20)
+    assert kfac.plan.pred_layout_record(pre.plan)['decomp_route'] == {
+        '128': route}
     assert engine.tiled_buckets(pre.plan) == ('128',)
     assert engine.rowwise_buckets(pre.plan, 'local') == ('128',)
     record = kfac.plan.pred_layout_record(pre.plan)
@@ -485,14 +493,26 @@ def test_tiled_decomposition_is_the_whole_bucket(monkeypatch):
         whole, ops.psd_inverse(ops.add_scaled_identity(x, damp)))
     one = 16 ** 3 * 4 // 256
     # 7 rows in groups of 3 (the last group overlaps its neighbour), then
-    # one row a group in 4 panels of columns
-    for group_bytes, tiling in ((3 * one, (3, 16)), (one // 4, (1, 4))):
+    # one row a group (each whole: until PR 46 in 4 panels of columns),
+    # on the route of two solves and on the structured one
+    for group_bytes, tiling, structured in (
+            (3 * one, (3, 16), False), (one // 4, (1, 16), False),
+            (3 * one, (3, 16), True), (one // 4, (1, 16), True)):
         monkeypatch.setattr(linalg, 'WHOLE_INVERSE_TEMP_BYTES', 4 * one)
         monkeypatch.setattr(linalg, 'INVERSE_GROUP_TEMP_BYTES', group_bytes)
+        monkeypatch.setattr(linalg, 'STRUCTURED_INVERSE_DIM',
+                            16 if structured else 2048)
+        monkeypatch.setattr(linalg, 'STRUCTURED_INVERSE_BLOCK', 4)
+        assert ops.inverse_route(16) == (
+            'structured' if structured else 'solves')
         assert ops.inverse_tiling(7, 16) == tiling
         assert ops.inverse_tiling(4, 16) == (4, 16)
-        tiled = jax.jit(ops.damped_psd_inverse)(x, damp)
+        # (a fresh function a jit: its cache does not see a patched
+        # constant)
+        tiled = jax.jit(lambda a, d: ops.damped_psd_inverse(a, d))(x, damp)
         np.testing.assert_allclose(tiled, whole, rtol=1e-5, atol=1e-6)
+        if structured:
+            np.testing.assert_array_equal(tiled, jnp.swapaxes(tiled, 1, 2))
 
 
 def test_tiled_decomposition_writes_over_the_stored_rows_and_screens_them(
@@ -530,10 +550,17 @@ def test_tiling_comes_from_the_buckets_shape():
                       (7, 2304), (1, 4608)):
         assert ops.inverse_tiling(rows, dim) == (rows, dim)
     # ... the sparse decoder's 2,048 bucket in 16 groups of 8 rows, and
-    # its three 6,144s one at a time in 4 panels of columns
+    # its three 6,144s one at a time, each whole (in 4 panels of columns
+    # until PR 46: the structured route holds a few copies of a matrix
+    # where a solve held 24 right-hand sides)
     assert ops.inverse_tiling(126, 2048) == (8, 2048)
-    assert ops.inverse_tiling(3, 6144) == (1, 1536)
+    assert ops.inverse_tiling(136, 2048) == (8, 2048)
+    assert ops.inverse_tiling(3, 6144) == (1, 6144)
     assert ops.inverse_tiling(101, 768) == (101, 768)
+    # and those two are buckets of the structured route, as every bucket
+    # of 1,024 and more
+    assert [ops.inverse_route(d) for d in (768, 896, 1024, 2048, 6144)] == [
+        'solves', 'solves', 'structured', 'structured', 'structured']
 
 
 def test_bucket_under_the_threshold_lowers_as_before():
