@@ -43,6 +43,25 @@ heads they read (8 query heads a key/value head), the rest as above:
   python examples/longcontext_lm.py --model mixed-decoder --kfac-name \
       inverse_dp --seq-len 4096 --batch-size 1 --n-layer 5 --n-head 8 \
       --d-model 2048 --experts-held 8 --synthetic-vocab 25024 --epochs 1
+
+``--model hybrid-decoder`` trains ``models.hybrid_decoder_lm``: one chip's
+share of a decoder whose layers are a recurrence or a softmax (Kimi Delta
+Attention, a gated delta-rule linear attention run as a chunked scan, and
+latent attention without positions, 3:1; sigmoid-routed top-8 experts of
+256 with one shared: Kimi-Linear-48B-A3B's published widths). ``--n-layer``
+layers held (one leading dense KDA layer, then whole periods of three KDA
+layers and one latent), ``--n-head`` heads held of each attention,
+``--kda-chunk`` tokens a chunk of the scan, ``--ffn-block`` the width of a
+block of the dense layer's K-FAC factors. K-FAC's split of a
+KDA layer: Kronecker-factored are its nine projections, all outside the
+scan (``q_proj`` / ``k_proj`` / ``v_proj`` / ``f_a_proj`` / ``g_a_proj`` /
+``b_proj`` share one ``A``; ``f_b_proj``, ``g_b_proj`` with its bias,
+``o_proj``); first-order are the three short convolutions, ``A_log``,
+``dt_bias`` and the output norm. The log line adds ``kda/log_decay_min`` and
+``kda/state_absmax`` beside the router's counters:
+  python examples/longcontext_lm.py --model hybrid-decoder --kfac-name \
+      inverse_dp --seq-len 1024 --batch-size 1 --n-layer 5 --n-head 8 \
+      --d-model 2304 --experts-held 8 --synthetic-vocab 20480 --epochs 1
 """
 
 import argparse
@@ -74,21 +93,31 @@ def parse_args():
     p.add_argument('--epochs', type=int, default=3)
     p.add_argument('--steps-per-epoch', type=int, default=100)
     p.add_argument('--model', choices=['transformer', 'sparse-decoder',
-                                       'mixed-decoder'],
+                                       'mixed-decoder', 'hybrid-decoder'],
                    default='transformer',
                    help='transformer: models.transformer_lm; '
                         'sparse-decoder: models.sparse_decoder_lm (latent '
                         'attention, routed experts); mixed-decoder: '
                         'models.mixed_decoder_lm (window and full '
                         'attention mixed, grouped-query, gated; routed '
+                        'experts); hybrid-decoder: models.hybrid_decoder_lm '
+                        '(a gated delta-rule recurrence and latent '
+                        'attention without positions mixed; routed '
                         'experts)')
     p.add_argument('--experts-held', type=int, default=8,
-                   help='sparse-decoder, mixed-decoder: routed experts '
-                        'this chip holds (ids 0..n-1 of the published 128)')
+                   help='sparse-, mixed-, hybrid-decoder: routed experts '
+                        'this chip holds (ids 0..n-1 of the published 128; '
+                        'hybrid-decoder: of 256)')
     p.add_argument('--expert-capacity', type=int, default=None,
-                   help='sparse-decoder, mixed-decoder: rows of a held '
+                   help='sparse-, mixed-, hybrid-decoder: rows of a held '
                         'expert\'s buffer (default: four times the '
                         'expected load)')
+    p.add_argument('--kda-chunk', type=int, default=64,
+                   help='hybrid-decoder: tokens a chunk of the gated '
+                        'delta rule\'s scan (16-64)')
+    p.add_argument('--ffn-block', type=int, default=2304,
+                   help='hybrid-decoder: width of a block of the dense '
+                        'SwiGLU\'s K-FAC factors; has to divide its 9,216')
     p.add_argument('--n-layer', type=int, default=4)
     p.add_argument('--n-head', type=int, default=8)
     p.add_argument('--d-model', type=int, default=256)
@@ -330,6 +359,20 @@ def main():
             expert_ids=tuple(range(args.experts_held)),
             expert_capacity=capacity, dtype=jnp.bfloat16)
         step_kw = dict(extra_mutable=(capture.COUNTERS,))
+    elif args.model == 'hybrid-decoder':
+        assert ndev == 1, 'the hybrid decoder trains on one device here'
+        tokens = args.batch_size * args.seq_len
+        capacity = args.expert_capacity or -(-4 * tokens * 8 // 256)
+        model = twin = models.hybrid_decoder_lm(
+            vocab_size=vocab, hidden_size=args.d_model,
+            layer_kinds=models.held_layer_kinds(args.n_layer),
+            first_k_dense=1, ffn_block=args.ffn_block,
+            kda_chunk=args.kda_chunk,
+            kda_head_ids=tuple(range(args.n_head)),
+            head_ids=tuple(range(args.n_head)),
+            expert_ids=tuple(range(args.experts_held)),
+            expert_capacity=capacity, dtype=jnp.bfloat16)
+        step_kw = dict(extra_mutable=(capture.COUNTERS,))
     else:
         model = models.transformer_lm(
             vocab_size=vocab, n_layer=args.n_layer, n_head=args.n_head,
@@ -473,7 +516,7 @@ def main():
         # one registry call renders the health/resilience suffixes
         # byte-identically to the old hand-plumbed health_suffix
         moe = ''.join(f' {k} {float(v):g}' for k, v in m.items()
-                      if k.startswith('moe/'))
+                      if k.startswith(('moe/', 'kda/')))
         log.info('epoch %d: train_ppl %.2f val_ppl %.2f (%.1fs)%s%s', epoch,
                  ppl, vppl, time.perf_counter() - t0,
                  reg.epoch_suffixes(), moe)

@@ -4,9 +4,11 @@ cifar_vgg.py, cifar_wide_resnet.py, imagenet_resnet.py,
 imagenet_inceptionv4.py, examples/transformer/, wikitext_models.py), and
 beyond it the language models the trainers build by name: ``transformer_lm``
 (LayerNorm/GELU decoder), ``sparse_decoder_lm`` (latent attention,
-sigmoid-routed experts: one chip's share) and ``mixed_decoder_lm`` (window
+sigmoid-routed experts: one chip's share), ``mixed_decoder_lm`` (window
 and full attention mixed, grouped-query, gated, QK-normed; routed experts:
-one chip's share)."""
+one chip's share) and ``hybrid_decoder_lm`` (a gated delta-rule recurrence
+and latent attention without positions mixed; routed experts: one chip's
+share)."""
 
 from kfac_pytorch_tpu.models.cifar_resnet import (
     resnet20, resnet32, resnet44, resnet56, resnet110)
@@ -24,6 +26,9 @@ from kfac_pytorch_tpu.models.sparse_decoder import (
     SparseDecoderConfig, SparseDecoderLM, sparse_decoder_lm)
 from kfac_pytorch_tpu.models.mixed_decoder import (
     MixedDecoderConfig, MixedDecoderLM, held_layer_types, mixed_decoder_lm)
+from kfac_pytorch_tpu.models.hybrid_decoder import (
+    HybridDecoderConfig, HybridDecoderLM, held_layer_kinds,
+    hybrid_decoder_lm)
 
 
 def get_model(name, num_classes=10, **kw):

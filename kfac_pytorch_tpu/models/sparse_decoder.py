@@ -17,9 +17,11 @@ Per layer, ``x`` the residual stream, RMS norms in float32, no biases:
   (128) | ``q_rope`` (64); ``u W_kva -> [T, 576]`` = ``c`` (512) |
   ``k_rope`` (64, one for all heads); ``kv = norm_kv(c) W_kvb -> [T, h,
   256]`` = ``k_nope`` (128) | ``v`` (128); rotary on ``q_rope`` and
-  ``k_rope`` (interleaved pairs, positions within the sequence); causal
-  ``softmax(q k' / sqrt(192)) v`` with the softmax in float32;
-  ``x += . W_o``. No ``q_lora``.
+  ``k_rope`` (interleaved pairs, positions within the sequence;
+  ``LatentAttention(rotary=False)`` leaves both unrotated: kanana-2 rotates,
+  ``models.hybrid_decoder``'s Kimi-Linear layers, ``mla_use_nope``, do
+  not); causal ``softmax(q k' / sqrt(192)) v`` with the softmax in
+  float32; ``x += . W_o``. No ``q_lora``.
 - feed-forward, ``u = norm2(x)``: the first ``first_k_dense`` layers a
   SwiGLU of ``intermediate_size``; the others ``parallel.moe.RoutedExperts``.
 - final norm, untied head.
@@ -51,12 +53,14 @@ CUMULATIVE over the run and all layers), ``moe/rows_max`` and
 any layer, mean over experts and layers).
 
 *Shared with ``models.mixed_decoder``* (the decoder whose layers differ in
-kind: window and full attention mixed), named once, here: :class:`RMSNorm`,
+kind: window and full attention mixed) and ``models.hybrid_decoder`` (a
+recurrence and latent attention mixed), named once, here: :class:`RMSNorm`,
 :class:`MoECounters`, ``parallel.moe.SwiGLU`` / ``RoutedExperts``, flat
-tokens, the ``L + 1``-id batch and the mean next-token loss. This block's
-alone: :class:`LatentAttention`, :func:`interleaved_rotary`,
-:class:`DecoderLayer` (two norms a block, one kind of attention) and the
-fields of :class:`SparseDecoderConfig` marked so.
+tokens, the ``L + 1``-id batch and the mean next-token loss; with the
+hybrid decoder also :class:`LatentAttention`. This block's alone:
+:func:`interleaved_rotary`, :class:`DecoderLayer` (two norms a block, one
+kind of attention) and the fields of :class:`SparseDecoderConfig` marked
+so.
 """
 
 import dataclasses
@@ -101,7 +105,10 @@ def interleaved_rotary(x, positions, theta):
 
 class LatentAttention(linen.Module):
     """Multi-head latent attention without ``q_lora``, over the heads this
-    chip holds. ``u [T, d]`` (``T = batch * length``) -> ``[T, d]``."""
+    chip holds. ``u [T, d]`` (``T = batch * length``) -> ``[T, d]``.
+    ``rotary=False``: no positions (``mla_use_nope``), the ``qk_rope``
+    dimensions of ``q`` and the shared ones of ``k`` enter the score as
+    they are."""
     head_ids: Tuple[int, ...]
     qk_nope: int = 128
     qk_rope: int = 64
@@ -110,6 +117,7 @@ class LatentAttention(linen.Module):
     rope_theta: float = 1e6
     eps: float = 1e-6
     dtype: Optional[Any] = None
+    rotary: bool = True
 
     @linen.compact
     def __call__(self, u, batch, length):
@@ -126,7 +134,7 @@ class LatentAttention(linen.Module):
         kv = kv.reshape(batch, length, h, nope + vd)
         k_rope = ckv[:, self.kv_rank:].reshape(batch, length, rope)
         scale = 1.0 / np.sqrt(nope + rope)
-        theta = self.rope_theta
+        theta, rotary = self.rope_theta, self.rotary
 
         # the [B, h, L, L] scores are computed again in the backward pass,
         # not kept
@@ -134,8 +142,10 @@ class LatentAttention(linen.Module):
         def attend(q, kv, k_rope):
             with jax.named_scope('mla.attend'):
                 pos = jnp.arange(length)
-                q_rope = interleaved_rotary(q[..., nope:], pos, theta)
-                k_rope = interleaved_rotary(k_rope, pos, theta)
+                q_rope = q[..., nope:]
+                if rotary:
+                    q_rope = interleaved_rotary(q_rope, pos, theta)
+                    k_rope = interleaved_rotary(k_rope, pos, theta)
                 s = (jnp.einsum('blhd,bmhd->bhlm', q[..., :nope],
                                 kv[..., :nope])
                      + jnp.einsum('blhd,bmd->bhlm', q_rope, k_rope))

@@ -156,6 +156,33 @@ class SwiGLU(linen.Module):
         return dense(x.shape[-1], 'down')(h)
 
 
+class BlockedSwiGLU(linen.Module):
+    """:class:`SwiGLU` of ``width`` whose K-FAC factors are blocks of
+    ``block``: ``gate_j`` / ``up_j`` the column blocks ``d -> block`` (all
+    read ``x``: one input group, one ``A``; their ``G`` block-diagonal),
+    ``down_j`` the row blocks ``block -> d`` over the slices of its input,
+    summed (its ``A`` block-diagonal). It computes what the unsplit layer
+    computes with the blocks' weights side by side; no factor is wider
+    than ``max(d, block)``."""
+    width: int
+    block: int
+    dtype: Optional[Any] = None
+
+    @linen.compact
+    def __call__(self, x):
+        block = self.block
+        if self.width % block:
+            raise ValueError(f'{self.width} wide in blocks of {block}')
+
+        def dense(n, name):
+            return knn.Dense(n, use_bias=False, dtype=self.dtype, name=name)
+        blocks = range(self.width // block)
+        gate = [dense(block, f'gate_{j}')(x) for j in blocks]
+        up = [dense(block, f'up_{j}')(x) for j in blocks]
+        return sum(dense(x.shape[-1], f'down_{j}')(
+            jax.nn.silu(gate[j]) * up[j]) for j in blocks)
+
+
 class SwiGLUStack(linen.Module):
     """``E`` SwiGLU experts as three stacked leaves ``[E, d_in, d_out]``:
     one grouped product a projection over the row buffers ``[E, C, d]``
